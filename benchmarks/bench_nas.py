@@ -407,6 +407,29 @@ def _remote_safe(name: str):
     return getattr(mod, name)
 
 
+def _child_env(extra_env: dict | None = None) -> dict:
+    """Environment for a child interpreter that imports this repo.  A
+    chip belongs to one process: a parent that already holds an
+    accelerator must not start children that would need it."""
+    import os
+
+    from repro.search.executors import (ONE_PROCESS_PER_CHIP,
+                                        OneProcessPerChipError,
+                                        held_accelerator)
+
+    platform = held_accelerator()
+    if platform is not None:
+        raise OneProcessPerChipError(
+            f"{ONE_PROCESS_PER_CHIP}: this benchmark process holds the "
+            f"{platform} backend; run subprocess-isolated groups from a "
+            f"parent that has not touched JAX")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, **(extra_env or {})}
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(repo, "src"), repo] + env.get("PYTHONPATH", "").split(os.pathsep))
+    return env
+
+
 def _run_config_subprocess(name: str, cache_dir: str | None = None,
                            extra_env: dict | None = None) -> dict:
     """Run one configuration in an isolated interpreter and parse its
@@ -416,10 +439,7 @@ def _run_config_subprocess(name: str, cache_dir: str | None = None,
     import subprocess
     import sys
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ, **(extra_env or {})}
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(repo, "src"), repo] + env.get("PYTHONPATH", "").split(os.pathsep))
+    env = _child_env(extra_env)
     cmd = [sys.executable, os.path.abspath(__file__), "--parallel-config", name]
     if cache_dir:
         cmd.append(cache_dir)
@@ -508,10 +528,7 @@ def _spawn_worker_daemon(cache_dir: str):
     import subprocess
     import sys
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ}
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(repo, "src"), repo] + env.get("PYTHONPATH", "").split(os.pathsep))
+    env = _child_env()
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.worker", "--port", "0",
          "--cache-dir", cache_dir, "--no-warmup"],
@@ -914,10 +931,7 @@ def _run_cascade_subprocess(name: str, trials: int) -> dict:
     import subprocess
     import sys
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ}
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(repo, "src"), repo] + env.get("PYTHONPATH", "").split(os.pathsep))
+    env = _child_env()
     cmd = [sys.executable, os.path.abspath(__file__), "--cascade-config",
            name, str(trials)]
     r = subprocess.run(cmd, capture_output=True, text=True, env=env, timeout=1800)
@@ -1131,10 +1145,7 @@ def _run_kernel_tune_subprocess(cache_dir: str) -> dict:
     import subprocess
     import sys
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ}
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(repo, "src"), repo] + env.get("PYTHONPATH", "").split(os.pathsep))
+    env = _child_env()
     cmd = [sys.executable, os.path.abspath(__file__), "--kernel-tune-config",
            cache_dir]
     r = subprocess.run(cmd, capture_output=True, text=True, env=env, timeout=1800)
@@ -1290,10 +1301,7 @@ def _run_serve_boot(report_path: str) -> dict:
     import subprocess
     import sys
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ}
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(repo, "src"), repo] + env.get("PYTHONPATH", "").split(os.pathsep))
+    env = _child_env()
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve",
          "--from-report", report_path],
@@ -1364,10 +1372,7 @@ def _run_serve_subprocess(cache_dir: str, report_dir: str, trials: int) -> dict:
     import subprocess
     import sys
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ}
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(repo, "src"), repo] + env.get("PYTHONPATH", "").split(os.pathsep))
+    env = _child_env()
     cmd = [sys.executable, os.path.abspath(__file__), "--serve-explore",
            cache_dir, report_dir, str(trials)]
     r = subprocess.run(cmd, capture_output=True, text=True, env=env, timeout=1800)

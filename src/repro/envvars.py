@@ -257,10 +257,11 @@ register_env(EnvVar(
     parse=_flag,
     expected="a flag (`0`/`false` disables, anything else enables)",
     description=(
-        "Force Pallas kernels into interpreter mode (`0`/`false` "
-        "disables it even off-TPU).  Interpret mode is how non-TPU "
-        "hosts — CI, this container — validate the TPU kernels."),
-    default="enabled unless running on a TPU backend",
+        "Pallas interpreter mode off-TPU (`0`/`false` disables it).  "
+        "Interpret mode is how non-TPU hosts (CI, CPU-only machines) "
+        "validate the TPU kernels.  Ignored on a TPU backend, where the "
+        "kernels always compile."),
+    default="enabled off-TPU; a TPU backend never interprets",
     malformed="not applicable — every non-blank value parses as a flag",
     consulted_by="`repro/kernels/ops.py`",
 ))

@@ -16,7 +16,8 @@ Content key
 -----------
 An entry's identity is the estimator program key — ``(name, mesh_scope,
 batch, full architecture signature[, effective kernel schedules])`` —
-wrapped with the **toolchain salt** (jax/jaxlib versions, the same salt
+wrapped with the **toolchain salt** (jax/jaxlib versions plus the
+backend platform and ``device_kind``, the same salt
 :func:`repro.evaluation.disk_cache.canonical_key` applies).  Every part
 is load-bearing:
 
@@ -28,8 +29,9 @@ is load-bearing:
   * the *effective* (shape-clamped) kernel-schedule signature — two
     requested schedules that clamp to the same launch share one entry,
     two that clamp apart never collide;
-  * the toolchain salt — a jax/jaxlib upgrade structurally misses
-    instead of deserializing an executable built by a different compiler.
+  * the toolchain salt — a jax/jaxlib upgrade, or a store written on
+    another platform, structurally misses instead of deserializing an
+    executable built by a different compiler for a different device.
 
 Layout
 ------

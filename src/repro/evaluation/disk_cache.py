@@ -17,13 +17,16 @@ architecture at most once per host:
   * keys are the cache's own tuples — estimator name, target, batch,
     full architecture signature (layers AND pre-processing) — wrapped
     together with a **toolchain salt** (the jax/jaxlib versions, see
-    :func:`toolchain_versions`) and canonicalized to a JSON string, so a
-    changed architecture, target, batch size, or XLA toolchain can never
-    alias an old entry.  **Invalidation** is therefore structural:
-    entries never go stale as long as signatures capture the program,
-    and a jax/jaxlib upgrade (which can change compiled latency and
-    memory results) simply stops matching the old records instead of
-    serving them;
+    :func:`repro.toolchain.toolchain_versions`, plus the backend
+    platform and ``device_kind``, see
+    :func:`repro.toolchain.device_identity`) and canonicalized to a
+    JSON string, so a changed architecture, target, batch size, XLA
+    toolchain or device can never alias an old entry.  **Invalidation**
+    is therefore structural: entries never go stale as long as
+    signatures capture the program, and a jax/jaxlib upgrade or a move
+    from the CPU to a TPU (either can change compiled latency, memory
+    and tuned schedules) simply stops matching the old records instead
+    of serving them;
   * compiled executables are not persistable — non-JSON values are
     silently skipped and live only in the memory tier;
   * concurrency: appends take an ``flock`` around a single ``write`` (the
@@ -86,6 +89,7 @@ from typing import Any, Dict, Hashable, List, Optional, Tuple
 from repro import faults
 from repro.envvars import read_env
 from repro.ioutils import lock_file, locked_append, unlock_file
+from repro.toolchain import device_identity, toolchain_versions
 
 DEFAULT_DIR = os.path.join("results", "cache")
 
@@ -112,40 +116,22 @@ def jsonable(value: Any) -> bool:
     return False
 
 
-def toolchain_versions() -> Dict[str, str]:
-    """jax/jaxlib versions, or "unavailable" when not importable — the
-    compiled-value salt: two toolchains may compile the same program to
-    different latency/memory, so their values must never alias."""
-    try:
-        import jax
-
-        jax_version = str(getattr(jax, "__version__", "unknown"))
-    except Exception:
-        jax_version = "unavailable"
-    try:
-        import jaxlib.version
-
-        jaxlib_version = str(jaxlib.version.__version__)
-    except Exception:
-        jaxlib_version = "unavailable"
-    return {"jax": jax_version, "jaxlib": jaxlib_version}
-
-
 _TOOLCHAIN: Optional[Dict[str, str]] = None
 
 
 def _toolchain_salt() -> Dict[str, str]:
     global _TOOLCHAIN
     if _TOOLCHAIN is None:
-        _TOOLCHAIN = toolchain_versions()
+        _TOOLCHAIN = {**toolchain_versions(), **device_identity()}
     return _TOOLCHAIN
 
 
 def canonical_key(key: Hashable) -> Optional[str]:
     """Stable string form of a cache key salted with the jax/jaxlib
-    versions (an XLA upgrade invalidates structurally instead of serving
-    stale compiled values), or None when the key contains non-JSON parts
-    (those entries stay memory-only)."""
+    versions and the device (an XLA upgrade or a value made on another
+    platform invalidates structurally instead of being served), or None
+    when the key contains non-JSON parts (those entries stay
+    memory-only)."""
     if not jsonable(key):
         return None
     return json.dumps({"key": key, "toolchain": _toolchain_salt()},

@@ -150,6 +150,9 @@ def main(argv: Optional[List[str]] = None) -> int:
 
         print(list_components_text(), end="")
         return 0
+    from repro.compile_cache import place_compile_cache
+
+    place_compile_cache()
     if argv and argv[0] == "sweep":
         return _run_sweep(argv[1:])
     return _run_experiment(argv)

@@ -600,7 +600,7 @@ class Explorer:
         return {"dir": store.path, "entries": len(store)}
 
     def _build_report(self, wall_clock: float) -> ExplorationReport:
-        from repro.evaluation.disk_cache import toolchain_versions
+        from repro.toolchain import toolchain_versions
 
         spec, study = self.spec, self.study
         states: Dict[str, int] = {}

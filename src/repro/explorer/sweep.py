@@ -415,7 +415,7 @@ def merge_reports(spec: SweepSpec, summaries: List[Dict[str, Any]],
     deterministic: same summaries in, same report out (asserted in
     ``tests/test_sweep.py``), so a resumed sweep merges identically to an
     uninterrupted one."""
-    from repro.evaluation.disk_cache import toolchain_versions
+    from repro.toolchain import toolchain_versions
 
     base = spec.base
     directions = _criteria_directions(base)

@@ -10,7 +10,10 @@ the shared compile admission gate, and memoizes the winner in the
 (optionally disk-backed) evaluation cache keyed by
 ``(kernel, shape_bucket, mesh_scope)`` — so a warm restart re-tunes
 nothing, and same-topology targets share tuned schedules exactly like
-they share compiled artifacts.
+they share compiled artifacts.  The disk tier salts every key with the
+platform and ``device_kind`` the process runs on, so a winner timed on
+the CPU (the Pallas interpreter) never answers on a TPU; each record
+also names the device it was timed on.
 
 Shape buckets round every dimension up to the next power of two and fold
 in the masking flags, so nearby shapes (which want the same blocking)
@@ -32,10 +35,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.envvars import read_env
-from repro.hwgen.generator import compile_gate
+from repro.hwgen.generator import compile_gate, target_devices
 from repro.kernels import ops as kops
 from repro.kernels import schedule as ksched
 from repro.kernels.schedule import KernelSchedule
+from repro.toolchain import device_identity
 
 # the documented default of REPRO_TUNE_BUDGET (covers every built-in grid)
 DEFAULT_BUDGET = 8
@@ -134,7 +138,13 @@ class ScheduleTuner:
 
         def sweep() -> Dict[str, Any]:
             swept.append(True)
-            return self._sweep(kernel, shapes, meta, bucket)
+            # time on the device the target measures on (a platform-pinned
+            # target never reads another platform's clock)
+            device = target_devices(self.target)[0]
+            with jax.default_device(device):
+                record = self._sweep(kernel, shapes, meta, bucket)
+            record["device"] = device_identity(device)
+            return record
 
         if self.cache is not None:
             key = ("kernel_schedule", kernel, bucket, self.target.mesh_scope)

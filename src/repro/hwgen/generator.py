@@ -11,9 +11,10 @@ Two usage modes, mirroring the paper:
   2. hardware-in-the-loop: a cost estimator generates + benchmarks every
      candidate and feeds the measurement back into the study.
 
-``HardwareManager.benchmark`` measures wall-clock on the host backend and
-returns the roofline-modelled step time for TPU targets (this container
-has no TPU; on real hardware the same call times the executable).
+``HardwareManager.benchmark`` measures wall-clock on a ``wallclock``
+target's own platform (``host_cpu`` places on and times CPU devices,
+even in a process whose default backend is a TPU) and returns the
+roofline-modelled step time for roofline targets.
 """
 from __future__ import annotations
 
@@ -27,7 +28,6 @@ import jax
 import numpy as np
 
 from repro import faults
-from repro.compat import cost_analysis_dict
 from repro.envvars import read_env
 from repro.hwgen.hlo_analysis import parse_collectives, total_collective_bytes
 from repro.hwgen.roofline import RooflineReport, roofline_terms
@@ -105,6 +105,19 @@ def compile_gate() -> threading.BoundedSemaphore:
     return _gate
 
 
+def target_devices(target: TargetSpec):
+    """Devices a target compiles for: its named platform's (a wallclock
+    target never measures one platform under another's name), else the
+    default backend's."""
+    try:
+        return jax.devices(target.platform) if target.platform else jax.devices()
+    except RuntimeError as e:
+        raise GeneratorError(
+            f"target {target.name} measures on platform "
+            f"{target.platform!r}, which this process cannot reach: {e}"
+        ) from e
+
+
 class XLAGenerator:
     """Translates model instances into target-specific XLA executables."""
 
@@ -128,8 +141,10 @@ class XLAGenerator:
     # -- generation -----------------------------------------------------------
 
     def _mesh(self):
+        devices = target_devices(self.target)
         try:
-            return make_mesh(self.target.mesh_shape, self.target.mesh_axes)
+            return make_mesh(self.target.mesh_shape, self.target.mesh_axes,
+                             devices=devices)
         except RuntimeError as e:
             raise GeneratorError(
                 f"target {self.target.name} needs {self.target.n_chips} devices: {e}"
@@ -175,7 +190,7 @@ class XLAGenerator:
         # HardwareManager.benchmark).
         kernel_calls: Dict[Tuple[str, str], Dict[str, Any]] = {}
         with compile_gate():
-            with mesh:
+            with mesh, jax.default_device(mesh.devices.flat[0]):
                 jitted = jax.jit(
                     fn,
                     in_shardings=in_shardings,
@@ -189,7 +204,7 @@ class XLAGenerator:
                         ksched.record_kernel_calls(kernel_calls):
                     lowered = jitted.lower(*example_args)
                 compiled = lowered.compile()
-            ca = cost_analysis_dict(compiled)
+            ca = compiled.cost_analysis() or {}
             flops = float(ca.get("flops", 0.0))
             bytes_accessed = float(ca.get("bytes accessed", 0.0))
             coll = total_collective_bytes(parse_collectives(compiled.as_text()))
@@ -262,6 +277,8 @@ class HardwareManager:
                 )
                 for a in artifact.example_args
             )
+        if artifact.target.platform:
+            args = jax.device_put(args, target_devices(artifact.target)[0])
         fn = artifact.compiled
         # Wall-clock measurement must not overlap sibling workers' XLA
         # compiles (or other measurements) — a timing taken during a

@@ -63,6 +63,17 @@ class TargetSpec:
     supported_ops: frozenset = frozenset()
     supports_pallas: bool = False
     measurement: str = "roofline"  # "roofline" | "wallclock"
+    # wallclock targets time a real device: the JAX platform whose
+    # devices they place on and whose clock they read ("cpu" for
+    # host_cpu).  Roofline targets model their chip and compile on the
+    # default backend.
+    platform: Optional[str] = None
+
+    def __post_init__(self):
+        if self.measurement == "wallclock" and not self.platform:
+            raise ValueError(
+                f"target {self.name!r}: a wallclock target must name the "
+                f"platform it measures on")
 
     @property
     def n_chips(self) -> int:
@@ -98,6 +109,7 @@ class TargetSpec:
             "supported_ops": sorted(self.supported_ops),
             "supports_pallas": self.supports_pallas,
             "measurement": self.measurement,
+            "platform": self.platform,
         }
 
 
@@ -132,7 +144,7 @@ TARGETS: Dict[str, TargetSpec] = {
         name="host_cpu", chip=HOST_CPU,
         mesh_shape=(1, 1), mesh_axes=("data", "model"),
         supported_ops=_COMMON_OPS, supports_pallas=False,
-        measurement="wallclock",
+        measurement="wallclock", platform="cpu",
     ),
     # single-chip edge deployment tier: same mesh topology as host_cpu
     # (so sweeps reuse its compiles) but roofline-measured against the

@@ -1,8 +1,12 @@
 """Jit-ready wrappers around the Pallas kernels.
 
-Handle layout transposes, group expansion, sequence padding to block
-multiples, and interpret-mode selection (Pallas TPU kernels execute via
-the interpreter on non-TPU backends — how this container validates them).
+Handle layout transposes (model layout (B, L, H, ·) to the kernels'
+head-major layout), sequence padding to block multiples, and
+interpret-mode selection.  On a TPU the kernels always compile: neither
+``REPRO_PALLAS_INTERPRET`` nor a schedule's ``interpret`` field can put
+them in the interpreter there.  Elsewhere the interpreter is the only
+way to run them (how CPU hosts validate the kernels), and those two
+knobs may override it.
 
 Each public op is a plain-Python *resolver* over an inner jitted impl:
 schedule resolution, shape clamping, and call recording all happen
@@ -31,15 +35,28 @@ import jax.numpy as jnp
 from repro.envvars import read_env
 from repro.kernels import schedule as ksched
 from repro.kernels.flash_attention import flash_attention_bhsd
-from repro.kernels.mlstm_scan import mlstm_scan_blhp
+from repro.kernels.mlstm_scan import mlstm_scan_bhlp
 from repro.kernels.schedule import KernelSchedule
-from repro.kernels.ssm_scan import ssm_scan_blhp
+from repro.kernels.ssm_scan import ssm_scan_bhlp
 
 
-def _interpret() -> bool:
+def _platform() -> str:
+    """Platform the call being traced will run on: a ``jax.default_device``
+    in force (a device or a platform name) wins over the default backend."""
+    device = jax.config.jax_default_device
+    if device is None:
+        return jax.default_backend()
+    return device if isinstance(device, str) else device.platform
+
+
+def _interpret(requested) -> bool:
+    if _platform() == "tpu":
+        return False
+    if requested is not None:
+        return bool(requested)
     # REPRO_PALLAS_INTERPRET is declared in repro.envvars (the shared
-    # REPRO_* registry); unset falls back to backend detection
-    return read_env("REPRO_PALLAS_INTERPRET", jax.default_backend() != "tpu")
+    # REPRO_* registry)
+    return read_env("REPRO_PALLAS_INTERPRET", True)
 
 
 def _pad_seq(x, block, axis):
@@ -71,10 +88,8 @@ def _resolve(kernel, schedule, legacy):
 def _finish(requested, effective):
     """Pin the interpret decision into the effective schedule so the
     recorded metadata says how the kernel actually ran."""
-    interp = requested.interpret
-    if interp is None:
-        interp = _interpret()
-    return dataclasses.replace(effective, interpret=bool(interp))
+    return dataclasses.replace(effective,
+                               interpret=_interpret(requested.interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -125,18 +140,17 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _ssm_scan_impl(x, dt, a, b_grouped, c_grouped, *, chunk, interpret):
-    h = x.shape[2]
-    g = b_grouped.shape[2]
-    rep = h // g
-    b_mat = jnp.repeat(b_grouped, rep, axis=2)
-    c_mat = jnp.repeat(c_grouped, rep, axis=2)
-    return ssm_scan_blhp(x, dt, a, b_mat, c_mat, chunk=chunk,
-                         interpret=interpret)
+    y, state = ssm_scan_bhlp(
+        x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), a,
+        b_grouped.transpose(0, 2, 1, 3), c_grouped.transpose(0, 2, 1, 3),
+        chunk=chunk, interpret=interpret)
+    return y.transpose(0, 2, 1, 3), state
 
 
 def ssm_scan(x, dt, a, b_grouped, c_grouped, *, chunk=None, schedule=None):
     """Mamba2 SSD scan.  x: (B,L,H,P); dt: (B,L,H); a: (H,);
-    b/c: (B,L,G,N) group layout (expanded here).  Returns (y, state)."""
+    b/c: (B,L,G,N) group layout (the kernel indexes each head's group).
+    Returns (y, state)."""
     requested = _resolve("ssm_scan", schedule, {"chunk": chunk})
     eff = _finish(requested, ksched.effective_schedule(
         "ssm_scan", requested, seq_len=x.shape[1]))
@@ -151,8 +165,10 @@ def ssm_scan(x, dt, a, b_grouped, c_grouped, *, chunk=None, schedule=None):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _mlstm_scan_impl(q, k, v, i_log, f_log, *, chunk, interpret):
-    return mlstm_scan_blhp(q, k, v, i_log, f_log, chunk=chunk,
-                           interpret=interpret)
+    seq = [t.transpose(0, 2, 1, 3) for t in (q, k, v)]
+    gates = [g.transpose(0, 2, 1) for g in (i_log, f_log)]
+    h = mlstm_scan_bhlp(*seq, *gates, chunk=chunk, interpret=interpret)
+    return h.transpose(0, 2, 1, 3)
 
 
 def mlstm_scan(q, k, v, i_log, f_log, *, chunk=None, schedule=None):

@@ -26,7 +26,7 @@ def flash_attention_ref(q, k, v, *, causal=True, window=None, scale=None):
 
 
 def ssm_scan_ref(x, dt, a, b_mat, c_mat, *, chunk=128):
-    """Same shapes as ssm_scan_blhp (b/c pre-expanded to per-head)."""
+    """x: (B, L, H, P), b/c pre-expanded to per-head (B, L, H, N)."""
     return ssd_chunked(x, dt, a, b_mat, c_mat, chunk)
 
 

@@ -7,7 +7,7 @@ could not see.  This module makes the mapping explicit:
   * :class:`KernelSchedule` — a frozen (hashable, jit-static) record of
     the tunable launch parameters: ``block_q``/``block_kv`` for flash
     attention, ``chunk`` for the scan kernels, plus an ``interpret``
-    override for forcing the Pallas interpreter;
+    override for the Pallas interpreter off-TPU;
   * :func:`validate_schedule` — per-kernel legal-range / power-of-two
     checks whose errors name the offending field;
   * :func:`effective_schedule` — the shape-clamped values a call will
@@ -70,8 +70,8 @@ class KernelSchedule:
     block_q: Optional[int] = None
     block_kv: Optional[int] = None
     chunk: Optional[int] = None
-    # tri-state: None = backend detection (REPRO_PALLAS_INTERPRET),
-    # True/False = force
+    # tri-state off-TPU: None = REPRO_PALLAS_INTERPRET, True/False =
+    # force.  Ignored on a TPU backend, where kernels always compile.
     interpret: Optional[bool] = None
 
     def to_dict(self) -> Dict[str, Any]:

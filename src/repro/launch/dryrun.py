@@ -69,9 +69,7 @@ def _mem_stats(compiled):
 
 def _cost_stats(compiled):
     try:
-        from repro.compat import cost_analysis_dict
-
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         return {
             "flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

@@ -13,8 +13,7 @@ import math
 from typing import Optional, Sequence
 
 import jax
-
-from repro.compat import mesh_axis_kwargs
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -28,7 +27,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "(the dry-run launcher sets this automatically)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n], **mesh_axis_kwargs(len(shape)))
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
@@ -37,9 +37,11 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
     devices = devices if devices is not None else jax.devices()
     if len(devices) < n:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
-    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices[:n], **mesh_axis_kwargs(len(shape)))
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh():
     """1-device mesh for smoke tests and host-backend NAS measurement."""
-    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1], **mesh_axis_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1],
+                         axis_types=(AxisType.Auto,) * 2)

@@ -106,6 +106,18 @@ class ServingEngine:
         self.completed: List[Dict[str, Any]] = []
         self.iterations = 0
         self.prefills = 0
+        # prefills / decode steps whose logits held a NaN or inf: a
+        # token sampled from those is garbage however valid its id looks
+        self.nonfinite_logits = 0
+
+    def _pick(self, logits):
+        """Greedy token ids from ``logits`` (..., vocab), fetched in one
+        transfer with a finiteness flag that feeds ``nonfinite_logits``."""
+        jnp = self.jnp
+        ids, finite = self.jax.device_get(
+            (jnp.argmax(logits, axis=-1), jnp.isfinite(logits).all()))
+        self.nonfinite_logits += int(not finite)
+        return np.asarray(ids)
 
     def _batch_axes(self) -> List[int]:
         """Per-cache-leaf distance of the batch axis from the right (the
@@ -142,7 +154,7 @@ class ServingEngine:
                                            jnp.asarray(prompt))
         self._merge_slot(single, slot)
         self.prefills += 1
-        first = int(jnp.argmax(logits[0, -1]))
+        first = int(self._pick(logits[0, -1]))
         self.slots[slot] = {"req": req, "pos": req.prompt_len,
                             "token": first, "out": [first]}
 
@@ -157,7 +169,7 @@ class ServingEngine:
                 pos[i] = s["pos"]
         logits, self.cache = self.decode(self.params, self.cache,
                                          jnp.asarray(tokens), jnp.asarray(pos))
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+        nxt = self._pick(logits[:, 0])
         for i, s in enumerate(self.slots):
             if s is None:
                 continue
@@ -195,10 +207,14 @@ class ServingEngine:
             "iterations": self.iterations,
             "prefills": self.prefills,
             "tokens_generated": sum(len(r["tokens"]) for r in self.completed),
+            "nonfinite_logits": self.nonfinite_logits,
         }
 
 
-def _serve_lm(args) -> Dict[str, Any]:
+def build_lm_engine(args):
+    """(engine, traffic) for LM mode: the named architecture with random
+    f32 weights from a fixed seed, sized to the traffic's longest
+    request."""
     import jax
     import jax.numpy as jnp
 
@@ -217,11 +233,16 @@ def _serve_lm(args) -> Dict[str, Any]:
         queue_limit=args.queue_limit,
         max_context=min(traffic.max_context + 1, spec.max_position),
         tick_s=args.tick_ms / 1e3)
+    return engine, traffic
+
+
+def _serve_lm(args) -> Dict[str, Any]:
+    engine, traffic = build_lm_engine(args)
     t0 = time.time()
     summary = engine.run(traffic.requests())
     wall = time.time() - t0
     summary.update({
-        "mode": "lm", "arch": spec.name,
+        "mode": "lm", "arch": engine.model.spec.name,
         "traffic": traffic.to_dict(),
         "max_batch": args.max_batch, "queue_limit": args.queue_limit,
         "wall_s": round(wall, 3),
@@ -379,7 +400,7 @@ def _traffic_from_args(args):
     return TrafficSpec.from_raw(raw)
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     mode = p.add_mutually_exclusive_group()
     mode.add_argument("--arch", default=None,
@@ -408,13 +429,20 @@ def main(argv=None) -> int:
                    help="exit nonzero if the boot performed more XLA "
                         "compiles than this (report mode)")
     args = p.parse_args(argv)
+    if args.arch is None and args.from_report is None:
+        args.arch = "qwen3-1.7b"
+        args.smoke = True
+    return args
 
+
+def main(argv=None) -> int:
+    from repro.compile_cache import place_compile_cache
+
+    args = parse_args(argv)
+    place_compile_cache()
     if args.from_report:
         result = _serve_report(args)
     else:
-        if args.arch is None:
-            args.arch = "qwen3-1.7b"
-            args.smoke = True
         result = _serve_lm(args)
     print(json.dumps(result))
     if args.expect_compiles is not None and args.from_report:

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.checkpoint.checkpointer import Checkpointer
+from repro.compile_cache import place_compile_cache
 from repro.configs import get_arch
 from repro.data.pipeline import Prefetcher, SyntheticLMData
 from repro.distributed.compression import GradientCompressor
@@ -36,7 +37,7 @@ from repro.train.optimizer import Optimizer, OptimizerConfig, cosine_schedule
 from repro.train.step import make_train_step
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen3-1.7b")
     p.add_argument("--smoke", action="store_true", help="reduced config (CPU-sized)")
@@ -50,8 +51,11 @@ def main(argv=None) -> int:
     p.add_argument("--ckpt-every", type=int, default=50)
     p.add_argument("--compression", action="store_true")
     p.add_argument("--log-every", type=int, default=10)
-    args = p.parse_args(argv)
+    return p.parse_args(argv)
 
+
+def run(args: argparse.Namespace) -> dict:
+    """Train per ``args``; returns the final-step summary."""
     arch = get_arch(args.arch)
     spec = arch.smoke_spec_fn() if args.smoke else arch.spec()
     model = LM(spec)
@@ -118,9 +122,15 @@ def main(argv=None) -> int:
     if ckpt is not None:
         ckpt.wait()
     prefetch.close()
-    final = {"final_loss": float(metrics.get("loss", float("nan"))),
-             "straggler_flags": straggler.flags}
-    print(json.dumps(final))
+    return {"final_loss": float(metrics.get("loss", float("nan"))),
+            "straggler_flags": straggler.flags,
+            "platform": mesh.devices.flat[0].platform}
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    place_compile_cache()
+    print(json.dumps(run(args)))
     return 0
 
 

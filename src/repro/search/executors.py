@@ -22,6 +22,10 @@ executor owns *where* objective calls run:
     (picklable) pruner, every submission also carries a
     :class:`~repro.search.detached.PrunerContext` snapshot and a report
     channel, so doomed trials terminate *inside* the worker.
+    An accelerator belongs to one process at a time, so this backend
+    refuses to start workers while the parent holds one, and a worker
+    that cannot reach its device fails the study loudly
+    (:class:`OneProcessPerChipError`) instead of being quarantined.
 
 The primary surface is **streaming**: ``submit(study, objective, trial,
 catch)`` schedules one evaluation, ``next_completed()`` blocks for the
@@ -41,6 +45,7 @@ import os
 import pickle
 import queue as queue_module
 import shutil
+import sys
 import tempfile
 import threading
 import traceback
@@ -89,6 +94,45 @@ class WorkerResult:
     # worker process holds (see PrunerContext) — lets the parent truncate
     pruner_ack: Optional[Tuple[str, int, int]] = None
     error: Optional[BaseException] = None
+
+
+class OneProcessPerChipError(RuntimeError):
+    """A worker process would need an accelerator it cannot have."""
+
+
+ONE_PROCESS_PER_CHIP = (
+    "one process per chip: an accelerator belongs to one process at a "
+    "time, and every process-backend worker needs it")
+
+
+def held_accelerator() -> Optional[str]:
+    """Platform of the accelerator this process holds, found without
+    initializing a backend: None when JAX is not imported, no backend is
+    up yet, or the backend is the CPU."""
+    if "jax" not in sys.modules:
+        return None
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    import jax
+
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
+
+
+def _unreachable_device() -> Optional[str]:
+    """Why this process cannot reach a JAX backend, or None (also when
+    JAX was never imported here)."""
+    if "jax" not in sys.modules:
+        return None
+    import jax
+
+    try:
+        jax.devices()
+    except RuntimeError as e:
+        return str(e)
+    return None
 
 
 def _record_values(values: Any) -> Optional[Tuple[float, ...]]:
@@ -159,6 +203,14 @@ def run_detached_trial(objective: Callable, number: int, plan: DetachedSampler,
         trial.set_user_attr("error", repr(e))
         values, state = None, TrialState.FAIL
         error = _portable_exception(e)
+    if state == TrialState.FAIL:
+        # a trial that failed because this process cannot have the
+        # device is not the trial's fault: fail the study, naming why
+        why = _unreachable_device()
+        if why is not None:
+            error = OneProcessPerChipError(
+                f"{ONE_PROCESS_PER_CHIP}; worker pid {os.getpid()} could "
+                f"not reach its device: {why}")
     return WorkerResult(
         number=number, values=values, state=state, params=trial.params,
         distributions=trial.distributions, user_attrs=trial.user_attrs,
@@ -541,9 +593,19 @@ class ProcessExecutor(BaseExecutor):
         # + next_completed's collect thunks), acks keyed by worker pid
         self._delta = PrunerDeltaLog()
 
+    @staticmethod
+    def _refuse_if_parent_holds_chip() -> None:
+        platform = held_accelerator()
+        if platform is not None:
+            raise OneProcessPerChipError(
+                f"{ONE_PROCESS_PER_CHIP}: this process already holds the "
+                f"{platform} backend, so a worker would fail or hang.  Use "
+                f"the serial or thread backend, or keep the parent off JAX.")
+
     def start(self, n_workers):
         if self._pool is not None:
             return
+        self._refuse_if_parent_holds_chip()
         self._pool = self._make_pool(n_workers)
         self._n_workers = n_workers
         if self._start_dir is None:
@@ -696,6 +758,9 @@ class ProcessExecutor(BaseExecutor):
         return (res.values, res.state)
 
     def submit(self, study, objective, trial, catch):
+        # workers spawn lazily: the parent may have taken the chip since
+        # start() (the serial first trial, in-parent screening)
+        self._refuse_if_parent_holds_chip()
         with study._lock:
             plan = study.sampler.detached(study, trial)
             pruner_ctx = self._pruner_context(study)

@@ -23,8 +23,8 @@ Handshake — first frame each way, before anything else:
 
 * client → ``hello`` with ``{"protocol": PROTOCOL_VERSION, "toolchain":
   {...}}`` (the jax/jaxlib versions from
-  :func:`repro.evaluation.disk_cache.toolchain_versions` — the same
-  salt the disk cache keys by);
+  :func:`repro.toolchain.toolchain_versions` — the versions the disk
+  cache keys by);
 * worker → ``hello_ok`` with its worker id, or ``hello_reject`` with a
   reason.  A protocol mismatch means incompatible framing/semantics; a
   toolchain mismatch means the worker would compute latency/memory
@@ -231,7 +231,7 @@ def local_toolchain() -> Dict[str, str]:
     """The jax/jaxlib salt both handshake sides compare — identical to
     the disk cache's key salt, so two hosts that shake hands also agree
     on cache-entry compatibility."""
-    from repro.evaluation.disk_cache import toolchain_versions
+    from repro.toolchain import toolchain_versions
 
     return toolchain_versions()
 

@@ -47,6 +47,7 @@ import threading
 import uuid
 from typing import Any, Dict, Optional, Set, Tuple
 
+from repro.compile_cache import place_compile_cache
 from repro.envvars import read_env
 from repro.search.detached import apply_pruner_deltas
 from repro.search.executors import _portable_exception, run_detached_trial
@@ -314,6 +315,7 @@ def main(argv: Optional[list] = None) -> int:
                         help="skip the jax import/backend warmup at startup")
     args = parser.parse_args(argv)
 
+    place_compile_cache()
     if args.cache_dir:
         import os
 

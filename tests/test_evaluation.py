@@ -118,3 +118,30 @@ def test_multiobjective_evaluation():
         OptimizationCriteria(b, kind="objective"),
     ])
     assert runner.evaluate_multi(_model()) == (1.0, 2.0)
+
+
+def test_wallclock_target_places_on_its_platform(monkeypatch):
+    """host_cpu times CPU devices only: it asks for them by platform, and
+    a process that cannot reach that platform gets an error, never
+    another platform's clock."""
+    from repro.hwgen import generator
+    from repro.hwgen.targets import HOST_CPU, TargetSpec, get_target
+
+    host = get_target("host_cpu")
+    assert host.measurement == "wallclock" and host.platform == "cpu"
+    assert all(d.platform == "cpu" for d in generator.target_devices(host))
+
+    asked = []
+
+    def no_such_platform(platform=None):
+        asked.append(platform)
+        raise RuntimeError(f"Unknown backend {platform}")
+
+    monkeypatch.setattr(generator.jax, "devices", no_such_platform)
+    with pytest.raises(generator.GeneratorError, match="measures on platform 'cpu'"):
+        generator.target_devices(host)
+    assert asked == ["cpu"]
+
+    with pytest.raises(ValueError, match="must name the platform"):
+        TargetSpec(name="board", chip=HOST_CPU, mesh_shape=(1, 1),
+                   mesh_axes=("data", "model"), measurement="wallclock")

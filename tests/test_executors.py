@@ -207,3 +207,34 @@ def test_executor_instance_reusable_across_optimize_calls():
     ref = Study(sampler=RandomSampler(seed=1))
     ref.optimize(_quadratic, 8)
     assert _fingerprint(s) == _fingerprint(ref)
+
+
+# ---------------------------------------------------------------------------
+# one process per chip
+# ---------------------------------------------------------------------------
+
+def test_process_backend_refuses_while_parent_holds_chip(monkeypatch):
+    from repro.search import executors
+
+    monkeypatch.setattr(executors, "held_accelerator", lambda: "tpu")
+    s = ParallelStudy(sampler=RandomSampler(seed=0), n_workers=2, backend="process")
+    with pytest.raises(executors.OneProcessPerChipError, match="one process per chip"):
+        s.optimize(_quadratic, 4)
+
+
+def test_worker_without_device_fails_study_not_trial(monkeypatch):
+    """A worker whose trial failed because it cannot reach its device
+    reports the rule, even when ``catch`` would have swallowed the error
+    into a FAILed trial."""
+    from repro.search import executors
+
+    monkeypatch.setattr(executors, "_unreachable_device",
+                        lambda: "TPU already in use by pid 1")
+    study = Study(sampler=RandomSampler(seed=0))
+    trial = study.ask()
+    plan = study.sampler.detached(study, trial)
+    number = 1  # _catchable_obj raises KeyError on odd trial numbers
+    res = executors.run_detached_trial(_catchable_obj, number, plan, (KeyError,))
+    assert res.state == TrialState.FAIL
+    assert isinstance(res.error, executors.OneProcessPerChipError)
+    assert "TPU already in use" in str(res.error)

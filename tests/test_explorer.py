@@ -373,6 +373,34 @@ def test_canonical_key_salted_with_toolchain_versions(tmp_path):
         dc._TOOLCHAIN = old
 
 
+def test_canonical_key_salted_with_device(tmp_path):
+    """A value made on one platform never answers on another: the salt
+    carries the backend platform and device_kind, and the artifact store
+    keys through the same salt."""
+    import jax
+
+    from repro.evaluation import DiskEvaluationCache
+    from repro.evaluation import disk_cache as dc
+    from repro.evaluation.artifact_store import ArtifactStore
+
+    device = jax.devices()[0]
+    salt = json.loads(dc.canonical_key(("k",)))["toolchain"]
+    assert salt["platform"] == device.platform
+    assert salt["device_kind"] == device.device_kind
+
+    store = DiskEvaluationCache(str(tmp_path / "store"))
+    assert store.store(("k",), 1.5)
+    cpu_artifact_key = ArtifactStore.canonical(("k",))
+    old = dc._TOOLCHAIN
+    try:
+        dc._TOOLCHAIN = {**old, "platform": "tpu", "device_kind": "TPU v5 lite"}
+        assert DiskEvaluationCache(str(tmp_path / "store")).lookup(("k",)) \
+            == (False, None)
+        assert ArtifactStore.canonical(("k",)) != cpu_artifact_key
+    finally:
+        dc._TOOLCHAIN = old
+
+
 # ---------------------------------------------------------------------------
 # schedule spec: validation + wiring into the study
 # ---------------------------------------------------------------------------

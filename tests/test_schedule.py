@@ -336,3 +336,17 @@ def test_kernel_tuning_spec_rejects_bad_sections():
         KernelTuningSpec.from_raw({"kernels": {"warp_drive": {"chunk": 64}}})
     with pytest.raises(ExperimentError, match="'chunk'=7"):
         KernelTuningSpec.from_raw({"kernels": {"ssm_scan": {"chunk": 7}}})
+
+
+def test_tpu_backend_never_interprets(monkeypatch):
+    """On a TPU backend neither REPRO_PALLAS_INTERPRET nor a schedule's
+    interpret field can put a kernel in the interpreter."""
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(ops, "_platform", lambda: "tpu")
+    assert ops._interpret(None) is False
+    assert ops._interpret(True) is False
+    monkeypatch.setattr(ops, "_platform", lambda: "cpu")
+    assert ops._interpret(None) is True
+    assert ops._interpret(False) is False

@@ -136,9 +136,33 @@ def test_dryrun_single_cell_small_mesh():
         "step, args, in_sh, out_sh, mesh, meta = build_cell('qwen3-1.7b', 'train_4k', False, cost_variant=True, n_units=2, overrides={'remat': False})\n"
         "lowered = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh).lower(*args)\n"
         "c = lowered.compile()\n"
-        "from repro.compat import cost_analysis_dict\n"
-        "print('flops', cost_analysis_dict(c).get('flops'))\n"
+        "print('flops', c.cost_analysis().get('flops'))\n"
     )
     r = _run([sys.executable, "-c", code], timeout=1200)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "flops" in r.stdout
+
+
+def test_compile_cache_placement(monkeypatch):
+    """Entry points keep JAX's persistent cache where
+    JAX_COMPILATION_CACHE_DIR says, else at one fixed path inside the
+    checkout."""
+    import jax
+
+    from repro import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV, "/elsewhere/cache")
+        assert compile_cache.place_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before  # left alone
+
+        monkeypatch.delenv(compile_cache.ENV)
+        placed = compile_cache.place_compile_cache()
+        checkout = os.path.dirname(os.path.abspath(SRC))
+        assert placed == os.path.join(checkout, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == placed
+        assert os.environ[compile_cache.ENV] == placed  # inherited by workers
+        assert compile_cache.place_compile_cache() == placed  # fixed path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -1,0 +1,101 @@
+"""The main-path Pallas kernels compile for a TPU v5e at real widths.
+
+Interpret mode (every other kernel test) cannot see Mosaic's tiling and
+VMEM rules; the TPU compiler can, for a chip that is only described
+(``v5e:2x2``), with nothing attached.  Each case lowers the kernel's
+jitted wrapper with ``interpret=False`` for one described chip and
+checks that the program holds a TPU custom call.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library at a time, and test
+collection runs in every worker.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import ops
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+# (kernel, widths, dtype): qwen3-1.7b attention (16 q / 8 kv heads,
+# d=128), zamba2-2.7b Mamba2 (H=80, P=N=64, one group), xlstm-1.3b mLSTM
+# (H=4, P=1024), at a 2048-token sequence
+CASES = [
+    ("flash", dict(b=1, s=2048, h=16, kh=8, d=128, window=None), F32),
+    ("flash", dict(b=1, s=2048, h=16, kh=8, d=128, window=None), BF16),
+    ("flash", dict(b=1, s=2048, h=16, kh=8, d=128, window=512), BF16),
+    ("ssm", dict(b=1, l=2048, h=80, p=64, n=64, g=1, chunk=128), F32),
+    ("ssm", dict(b=1, l=2048, h=80, p=64, n=64, g=1, chunk=32), BF16),
+    ("mlstm", dict(b=1, l=2048, h=4, p=1024, chunk=128), F32),
+    ("mlstm", dict(b=1, l=2048, h=4, p=1024, chunk=512), BF16),
+    # needs the kernel's raised scoped-VMEM limit
+    ("mlstm", dict(b=1, l=2048, h=4, p=1024, chunk=512), F32),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep such entries out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _lower(kernel, w, dtype, chip):
+    def arg(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    if kernel == "flash":
+        b, s, h, kh, d = w["b"], w["s"], w["h"], w["kh"], w["d"]
+        fn = jax.jit(lambda q, k, v: ops._flash_attention_impl(
+            q, k, v, causal=True, window=w["window"], scale=None,
+            block_q=128, block_kv=128, interpret=False))
+        return fn.lower(arg((b, s, h, d)), arg((b, s, kh, d)), arg((b, s, kh, d)))
+    if kernel == "ssm":
+        b, l, h, p, n, g = w["b"], w["l"], w["h"], w["p"], w["n"], w["g"]
+        fn = jax.jit(lambda *a: ops._ssm_scan_impl(
+            *a, chunk=w["chunk"], interpret=False))
+        return fn.lower(arg((b, l, h, p)), arg((b, l, h), F32), arg((h,), F32),
+                        arg((b, l, g, n)), arg((b, l, g, n)))
+    b, l, h, p = w["b"], w["l"], w["h"], w["p"]
+    fn = jax.jit(lambda *a: ops._mlstm_scan_impl(
+        *a, chunk=w["chunk"], interpret=False))
+    return fn.lower(arg((b, l, h, p)), arg((b, l, h, p)), arg((b, l, h, p)),
+                    arg((b, l, h), F32), arg((b, l, h), F32))
+
+
+@pytest.mark.parametrize("kernel,widths,dtype", CASES,
+                         ids=[f"{k}-{jnp.dtype(d).name}-{i}"
+                              for i, (k, _, d) in enumerate(CASES)])
+def test_kernel_compiles_for_v5e(kernel, widths, dtype, one_chip,
+                                 no_persistent_cache):
+    compiled = _lower(kernel, widths, dtype, one_chip).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis() is not None
