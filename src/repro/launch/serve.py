@@ -24,6 +24,31 @@ Report mode — serve the winning candidate of an exploration::
   artifact store the exploration populated — a warm boot performs
   **zero** XLA compiles (reported as ``compiles`` in the JSON summary,
   enforceable with ``--expect-compiles 0``).
+
+Tracing a live LM server: under ``jax.profiler.start_trace`` the engine
+writes ``TraceAnnotation`` spans into the profiler's trace, on the clock
+of the device's events; with no profiler active each costs about a
+microsecond.  Children nest in their parent on the calling thread.
+
+* ``serve.join`` (``request_id``, ``prompt_len``): one admission, with
+  ``serve.join.alloc`` (the batch-1 cache), ``serve.join.prefill`` (the
+  prompt to the device and the prefill call), ``serve.join.merge`` (the
+  slot merge) and ``serve.join.pick`` (the first token's host fetch),
+  each carrying the same ``request_id``;
+* ``serve.step`` (``step``, ``active``): one decode step, with
+  ``serve.step.inputs`` (host token and position arrays to the device),
+  ``serve.step.dispatch`` (the decode call), ``serve.step.pick`` (the
+  host waits for the logits) and ``serve.step.bookkeep`` (slot updates
+  and completions).
+
+The device ops of ``LM.prefill`` and ``LM.decode`` carry the name scope
+of their sub-block kind (``attention``, ``mlp``, ``moe``, ``mamba2``,
+...), ``embed`` or ``head`` in their HLO metadata.  Counters, plain
+numbers that grow for the engine's life (read differences over a
+window): ``queue.waits_s`` (queue wait of each request taken),
+``steps``, ``slot_steps`` (active slots summed over steps),
+``valid_positions`` (each active slot's valid cache positions summed
+over steps) and ``capacity_positions`` (``max_batch * max_context``).
 """
 from __future__ import annotations
 
@@ -41,22 +66,31 @@ import numpy as np
 # ---------------------------------------------------------------------------
 
 class RequestQueue:
-    """Bounded admission queue: arrivals beyond ``limit`` are shed."""
+    """Bounded admission queue: arrivals beyond ``limit`` are shed.
+
+    ``waits_s`` holds, for each request taken, the seconds it spent in
+    the queue on the host's monotonic clock."""
 
     def __init__(self, limit: int):
         self.limit = int(limit)
         self.items: List[Any] = []
+        self.offered_at: List[float] = []   # parallel to ``items``
         self.shed: List[Any] = []
+        self.waits_s: List[float] = []
 
     def offer(self, request) -> bool:
         if len(self.items) >= self.limit:
             self.shed.append(request)
             return False
         self.items.append(request)
+        self.offered_at.append(time.perf_counter())
         return True
 
     def take(self):
-        return self.items.pop(0) if self.items else None
+        if not self.items:
+            return None
+        self.waits_s.append(time.perf_counter() - self.offered_at.pop(0))
+        return self.items.pop(0)
 
     def __len__(self):
         return len(self.items)
@@ -106,6 +140,13 @@ class ServingEngine:
         self.completed: List[Dict[str, Any]] = []
         self.iterations = 0
         self.prefills = 0
+        # decode steps; active slots and their valid cache positions
+        # (``pos + 1``) summed over steps; positions the cache reserves
+        self.steps = 0
+        self.slot_steps = 0
+        self.valid_positions = 0
+        self.capacity_positions = self.max_batch * self.max_context
+        self._span = jax.profiler.TraceAnnotation
         # prefills / decode steps whose logits held a NaN or inf: a
         # token sampled from those is garbage however valid its id looks
         self.nonfinite_logits = 0
@@ -145,45 +186,58 @@ class ServingEngine:
 
     def _join(self, req) -> None:
         """Prefill one request (full-sequence kernel) into a free slot."""
-        jnp = self.jnp
-        slot = self.slots.index(None)
-        prompt = req.prompt_tokens(self.model.spec.vocab)[None]  # (1, S)
-        single = self.model.init_cache(self.params, 1, self.max_context,
-                                       dtype=jnp.float32)
-        logits, single = self._prefill_jit(self.params, single,
-                                           jnp.asarray(prompt))
-        self._merge_slot(single, slot)
-        self.prefills += 1
-        first = int(self._pick(logits[0, -1]))
-        self.slots[slot] = {"req": req, "pos": req.prompt_len,
-                            "token": first, "out": [first]}
+        jnp, span, rid = self.jnp, self._span, req.id
+        with span("serve.join", request_id=rid, prompt_len=req.prompt_len):
+            slot = self.slots.index(None)
+            prompt = req.prompt_tokens(self.model.spec.vocab)[None]  # (1, S)
+            with span("serve.join.alloc", request_id=rid):
+                single = self.model.init_cache(self.params, 1, self.max_context,
+                                               dtype=jnp.float32)
+            with span("serve.join.prefill", request_id=rid):
+                logits, single = self._prefill_jit(self.params, single,
+                                                   jnp.asarray(prompt))
+            with span("serve.join.merge", request_id=rid):
+                self._merge_slot(single, slot)
+            self.prefills += 1
+            with span("serve.join.pick", request_id=rid):
+                first = int(self._pick(logits[0, -1]))
+            self.slots[slot] = {"req": req, "pos": req.prompt_len,
+                                "token": first, "out": [first]}
 
     def _decode_step(self) -> None:
         """One engine iteration: every active slot decodes one token."""
-        jnp = self.jnp
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        pos = np.zeros((self.max_batch,), np.int32)
-        for i, s in enumerate(self.slots):
-            if s is not None:
-                tokens[i, 0] = s["token"]
-                pos[i] = s["pos"]
-        logits, self.cache = self.decode(self.params, self.cache,
-                                         jnp.asarray(tokens), jnp.asarray(pos))
-        nxt = self._pick(logits[:, 0])
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            s["pos"] += 1
-            s["token"] = int(nxt[i])
-            s["out"].append(int(nxt[i]))
-            if len(s["out"]) >= s["req"].gen_len or s["pos"] + 1 >= self.max_context:
-                self.completed.append({
-                    "id": s["req"].id,
-                    "prompt_len": s["req"].prompt_len,
-                    "tokens": s["out"],
-                    "finish_iter": self.iterations,
-                })
-                self.slots[i] = None
+        jnp, span = self.jnp, self._span
+        active = [i for i, s in enumerate(self.slots) if s is not None]
+        with span("serve.step", step=self.steps, active=len(active)):
+            with span("serve.step.inputs"):
+                tokens = np.zeros((self.max_batch, 1), np.int32)
+                pos = np.zeros((self.max_batch,), np.int32)
+                for i in active:
+                    tokens[i, 0] = self.slots[i]["token"]
+                    pos[i] = self.slots[i]["pos"]
+                tokens_d, pos_d = jnp.asarray(tokens), jnp.asarray(pos)
+            with span("serve.step.dispatch"):
+                logits, self.cache = self.decode(self.params, self.cache,
+                                                 tokens_d, pos_d)
+            with span("serve.step.pick"):
+                nxt = self._pick(logits[:, 0])
+            with span("serve.step.bookkeep"):
+                self.steps += 1
+                self.slot_steps += len(active)
+                self.valid_positions += int(pos[active].sum()) + len(active)
+                for i in active:
+                    s = self.slots[i]
+                    s["pos"] += 1
+                    s["token"] = int(nxt[i])
+                    s["out"].append(int(nxt[i]))
+                    if len(s["out"]) >= s["req"].gen_len or s["pos"] + 1 >= self.max_context:
+                        self.completed.append({
+                            "id": s["req"].id,
+                            "prompt_len": s["req"].prompt_len,
+                            "tokens": s["out"],
+                            "finish_iter": self.iterations,
+                        })
+                        self.slots[i] = None
 
     def run(self, requests: List[Any]) -> Dict[str, Any]:
         pending = sorted(requests, key=lambda r: (r.arrival_s, r.id))

@@ -225,9 +225,10 @@ class LM:
     def _layer_apply(self, layer: LayerSpec, params, h, *, positions, enc_out):
         for i, sub in enumerate(layer.subs):
             sp = params[f"sub_{i}"]
-            x = NORM_APPLY[self.spec.norm](sp["norm"], h)
-            y = _sub_apply(sub, sp["inner"], x, positions=positions, enc_out=enc_out)
-            h = h + y
+            with jax.named_scope(sub.kind):
+                x = NORM_APPLY[self.spec.norm](sp["norm"], h)
+                y = _sub_apply(sub, sp["inner"], x, positions=positions, enc_out=enc_out)
+                h = h + y
         return h
 
     def _run_segments(self, segments, params, h, *, positions, enc_out):
@@ -259,23 +260,25 @@ class LM:
         return h
 
     def _embed(self, params, tokens, prefix_embeds):
-        h = jnp.take(params["embed"], tokens, axis=0)
-        if self.spec.embed_scale:
-            h = h * (self.spec.d_model ** 0.5)
-        if prefix_embeds is not None:
-            npfx = prefix_embeds.shape[1]
-            h = jnp.concatenate([prefix_embeds.astype(h.dtype), h[:, npfx:]], axis=1)
+        with jax.named_scope("embed"):
+            h = jnp.take(params["embed"], tokens, axis=0)
+            if self.spec.embed_scale:
+                h = h * (self.spec.d_model ** 0.5)
+            if prefix_embeds is not None:
+                npfx = prefix_embeds.shape[1]
+                h = jnp.concatenate([prefix_embeds.astype(h.dtype), h[:, npfx:]], axis=1)
         return h
 
     def _head(self, params, h):
-        h = NORM_APPLY[self.spec.norm](params["final_norm"], h)
-        if self.spec.tie_embeddings:
-            logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])
-        else:
-            logits = jnp.einsum("bsd,dv->bsv", h, params["head"])
-        if self.spec.logit_softcap:
-            c = self.spec.logit_softcap
-            logits = jnp.tanh(logits / c) * c
+        with jax.named_scope("head"):
+            h = NORM_APPLY[self.spec.norm](params["final_norm"], h)
+            if self.spec.tie_embeddings:
+                logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])
+            else:
+                logits = jnp.einsum("bsd,dv->bsv", h, params["head"])
+            if self.spec.logit_softcap:
+                c = self.spec.logit_softcap
+                logits = jnp.tanh(logits / c) * c
         return logits
 
     def encode(self, params, frames):
@@ -396,19 +399,21 @@ class LM:
         new_cache = {}
         for i, sub in enumerate(layer.subs):
             sp = params[f"sub_{i}"]
-            x = NORM_APPLY[self.spec.norm](sp["norm"], h)
-            y, new_cache[f"sub_{i}"] = _sub_decode(sub, sp["inner"], x, cache[f"sub_{i}"], pos)
-            h = h + y
+            with jax.named_scope(sub.kind):
+                x = NORM_APPLY[self.spec.norm](sp["norm"], h)
+                y, new_cache[f"sub_{i}"] = _sub_decode(sub, sp["inner"], x, cache[f"sub_{i}"], pos)
+                h = h + y
         return h, new_cache
 
     def _layer_prefill(self, layer: LayerSpec, params, cache, h, pos_offset):
         new_cache = {}
         for i, sub in enumerate(layer.subs):
             sp = params[f"sub_{i}"]
-            x = NORM_APPLY[self.spec.norm](sp["norm"], h)
-            y, new_cache[f"sub_{i}"] = _sub_prefill(
-                sub, sp["inner"], x, cache[f"sub_{i}"], pos_offset)
-            h = h + y
+            with jax.named_scope(sub.kind):
+                x = NORM_APPLY[self.spec.norm](sp["norm"], h)
+                y, new_cache[f"sub_{i}"] = _sub_prefill(
+                    sub, sp["inner"], x, cache[f"sub_{i}"], pos_offset)
+                h = h + y
         return h, new_cache
 
     def prefill(self, params, cache, tokens, pos_offset=0):
