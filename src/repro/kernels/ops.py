@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from repro.envvars import read_env
 from repro.kernels import schedule as ksched
+from repro.kernels.decode_matmul import decode_matmul_stacked
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.mlstm_scan import mlstm_scan_bhlp
 from repro.kernels.schedule import KernelSchedule
@@ -49,8 +50,8 @@ def _platform() -> str:
     return device if isinstance(device, str) else device.platform
 
 
-def _interpret(requested) -> bool:
-    if _platform() == "tpu":
+def _interpret(requested, platform=None) -> bool:
+    if (platform or _platform()) == "tpu":
         return False
     if requested is not None:
         return bool(requested)
@@ -85,11 +86,11 @@ def _resolve(kernel, schedule, legacy):
     return ksched.default_schedule(kernel)
 
 
-def _finish(requested, effective):
+def _finish(requested, effective, platform=None):
     """Pin the interpret decision into the effective schedule so the
     recorded metadata says how the kernel actually ran."""
-    return dataclasses.replace(effective,
-                               interpret=_interpret(requested.interpret))
+    return dataclasses.replace(
+        effective, interpret=_interpret(requested.interpret, platform))
 
 
 # ---------------------------------------------------------------------------
@@ -184,3 +185,26 @@ def mlstm_scan(q, k, v, i_log, f_log, *, chunk=None, schedule=None):
     h = _mlstm_scan_impl(q, k, v, i_log, f_log,
                          chunk=eff.chunk, interpret=eff.interpret)
     return h, None
+
+
+# ---------------------------------------------------------------------------
+# decode matmul
+# ---------------------------------------------------------------------------
+
+def decode_matmul(x, w, layer, *, schedule=None, platform=None):
+    """``x @ bf16(w[layer])``.
+
+    x: (M, K); w: (L, K, N) float32, K and N multiples of 128; layer:
+    int32 scalar.  Returns (M, N) float32.  ``platform`` names the
+    platform the call is lowered for where the caller knows it (a branch
+    of ``lax.platform_dependent``); by default, the platform being traced
+    for."""
+    requested = _resolve("decode_matmul", schedule, {})
+    eff = _finish(requested, ksched.effective_schedule(
+        "decode_matmul", requested, seq_len=w.shape[1], kv_len=w.shape[2]),
+        platform)
+    ksched.note_kernel_call("decode_matmul", requested, eff,
+                            shapes={"x": x.shape, "w": w.shape},
+                            meta={"dtype": str(w.dtype)})
+    return decode_matmul_stacked(x, w, layer, block_k=eff.block_k,
+                                 block_n=eff.block_n, interpret=eff.interpret)

@@ -34,3 +34,9 @@ def mlstm_scan_ref(q, k, v, i_log, f_log):
     """Recurrent oracle (per-step), the strictest reference."""
     h, _ = mlstm_recurrent(q, k, v, i_log, f_log)
     return h
+
+
+def decode_matmul_ref(x, w, layer):
+    """x: (M, K); w: (L, K, N) -> x @ w[layer], bf16 operands, f32 sums."""
+    return jnp.dot(x.astype(jnp.bfloat16), w[layer].astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)

@@ -6,7 +6,8 @@ could not see.  This module makes the mapping explicit:
 
   * :class:`KernelSchedule` — a frozen (hashable, jit-static) record of
     the tunable launch parameters: ``block_q``/``block_kv`` for flash
-    attention, ``chunk`` for the scan kernels, plus an ``interpret``
+    attention, ``chunk`` for the scan kernels, ``block_k``/``block_n``
+    for the decode weight tiles, plus an ``interpret``
     override for the Pallas interpreter off-TPU;
   * :func:`validate_schedule` — per-kernel legal-range / power-of-two
     checks whose errors name the offending field;
@@ -28,7 +29,10 @@ could not see.  This module makes the mapping explicit:
 
 The named ``default`` schedule is exactly the pre-schedule constants
 (every block/chunk = 128), and resolving it reproduces the old kernel
-path bit-for-bit (asserted in ``tests/test_schedule.py``).
+path bit-for-bit (asserted in ``tests/test_schedule.py``).  The decode
+matmul, which came later, defaults to 512x1024 f32 weight tiles: on a
+v5e, tiles from 256x1024 to 2048x1024 all read the weights at 690-730
+GB/s, and this one needs half the VMEM of 1024x1024.
 
 Import-light on purpose: stdlib only, so the spec layer can validate
 ``kernel_tuning:`` sections without touching jax.
@@ -50,6 +54,7 @@ KERNEL_FIELDS: Dict[str, Tuple[str, ...]] = {
     "flash_attention": ("block_q", "block_kv"),
     "ssm_scan": ("chunk",),
     "mlstm_scan": ("chunk",),
+    "decode_matmul": ("block_k", "block_n"),
 }
 
 # legal range for every size field: powers of two within [MIN, MAX].
@@ -58,7 +63,10 @@ KERNEL_FIELDS: Dict[str, Tuple[str, ...]] = {
 MIN_SIZE = 8
 MAX_SIZE = 1024
 
-_SIZE_FIELDS = ("block_q", "block_kv", "chunk")
+_SIZE_FIELDS = ("block_q", "block_kv", "chunk", "block_k", "block_n")
+
+# decode_matmul tiles lie on the 128-wide lane axis
+LANES = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +78,8 @@ class KernelSchedule:
     block_q: Optional[int] = None
     block_kv: Optional[int] = None
     chunk: Optional[int] = None
+    block_k: Optional[int] = None
+    block_n: Optional[int] = None
     # tri-state off-TPU: None = REPRO_PALLAS_INTERPRET, True/False =
     # force.  Ignored on a TPU backend, where kernels always compile.
     interpret: Optional[bool] = None
@@ -104,6 +114,7 @@ DEFAULT_SCHEDULES: Dict[str, KernelSchedule] = {
     "flash_attention": KernelSchedule(block_q=128, block_kv=128),
     "ssm_scan": KernelSchedule(chunk=128),
     "mlstm_scan": KernelSchedule(chunk=128),
+    "decode_matmul": KernelSchedule(block_k=512, block_n=1024),
 }
 
 
@@ -204,6 +215,12 @@ def _clamp_chunk(chunk: int, seq: int) -> int:
     return max(ck, 1)
 
 
+def _clamp_tile(block: int, dim: int) -> int:
+    # the decode-matmul clamp: the scan clamp, kept lane-aligned (``dim``
+    # is a multiple of LANES)
+    return max(_clamp_chunk(block, dim), min(LANES, dim))
+
+
 def effective_schedule(kernel: str, schedule: Optional[KernelSchedule],
                        *, seq_len: int, kv_len: Optional[int] = None
                        ) -> KernelSchedule:
@@ -211,7 +228,8 @@ def effective_schedule(kernel: str, schedule: Optional[KernelSchedule],
     these sequence lengths — the values that must reach cache keys and
     artifact metadata (a requested ``block_q=128`` on a 64-token
     sequence runs as 64; see module docstring).  ``schedule=None`` means
-    the kernel default."""
+    the kernel default.  For ``decode_matmul`` the two lengths are the
+    weight's contracted width K and its output width N."""
     _check_kernel(kernel)
     sched = (schedule or KernelSchedule()).merged_over(default_schedule(kernel))
     if kernel == "flash_attention":
@@ -220,6 +238,12 @@ def effective_schedule(kernel: str, schedule: Optional[KernelSchedule],
             block_q=_clamp_block(sched.block_q, seq_len),
             block_kv=_clamp_block(sched.block_kv,
                                   seq_len if kv_len is None else kv_len))
+    if kernel == "decode_matmul":
+        return dataclasses.replace(
+            sched,
+            block_k=_clamp_tile(sched.block_k, seq_len),
+            block_n=_clamp_tile(sched.block_n,
+                                seq_len if kv_len is None else kv_len))
     return dataclasses.replace(sched, chunk=_clamp_chunk(sched.chunk, seq_len))
 
 

@@ -49,6 +49,8 @@ window): ``queue.waits_s`` (queue wait of each request taken),
 ``steps``, ``slot_steps`` (active slots summed over steps),
 ``valid_positions`` (each active slot's valid cache positions summed
 over steps) and ``capacity_positions`` (``max_batch * max_context``).
+``decode_kernel_calls``, set once when the engine is built, counts the
+distinct kernel calls its decode program runs (0 off a TPU).
 """
 from __future__ import annotations
 
@@ -135,6 +137,7 @@ class ServingEngine:
         self._axes_flat = self._batch_axes()
         self.decode = jax.jit(model.decode)
         self._prefill_jit = jax.jit(model.prefill)
+        self.decode_kernel_calls = self._count_decode_kernel_calls()
         # slot i: None, or dict(req=, pos=, token=, out=[generated tokens])
         self.slots: List[Optional[Dict[str, Any]]] = [None] * self.max_batch
         self.completed: List[Dict[str, Any]] = []
@@ -150,6 +153,28 @@ class ServingEngine:
         # prefills / decode steps whose logits held a NaN or inf: a
         # token sampled from those is garbage however valid its id looks
         self.nonfinite_logits = 0
+
+    def _platform(self) -> str:
+        """The platform the engine's programs run on: its cache's."""
+        leaf = self.jax.tree_util.tree_leaves(self.cache)[0]
+        return next(iter(leaf.devices())).platform
+
+    def _count_decode_kernel_calls(self) -> int:
+        """Distinct Pallas kernel calls, by shape, in the decode program
+        as it runs: ``LM.decode`` on a TPU multiplies each stack
+        segment's f32 projections by ``decode_matmul``.  0 where every
+        projection takes the XLA path, as on any other platform (where
+        the kernel's branch is traced but not lowered).  One abstract
+        trace, no compile."""
+        from repro.hwgen.autotune import discover_kernel_calls
+
+        if self._platform() != "tpu":
+            return 0
+        jax, jnp = self.jax, self.jnp
+        tokens = jax.ShapeDtypeStruct((self.max_batch, 1), jnp.int32)
+        pos = jax.ShapeDtypeStruct((self.max_batch,), jnp.int32)
+        return len(discover_kernel_calls(
+            self.model.decode, (self.params, self.cache, tokens, pos)))
 
     def _pick(self, logits):
         """Greedy token ids from ``logits`` (..., vocab), fetched in one

@@ -24,6 +24,7 @@ from repro.distributed.api import constrain
 from repro.models.specs import LayerSpec, ModelSpec, SubBlock
 from repro.nn import attention as attn
 from repro.nn import initializers as init
+from repro.nn import linear
 from repro.nn import moe as moe_mod
 from repro.nn import mlp as mlp_mod
 from repro.nn import ssm as ssm_mod
@@ -155,6 +156,33 @@ def _sub_decode(sub: SubBlock, params, x, cache, pos):
     if sub.kind == "moe":
         return moe_mod.moe_apply(params, sub.cfg, x), cache
     raise ValueError(sub.kind)
+
+
+# the projection weights that decode may read a layer at a time
+# (``nn/linear.py``), by sub-block kind; each module owns its list
+_STREAMED = {"attention": attn.PROJECTIONS, "mlp": mlp_mod.PROJECTIONS}
+
+
+def _split_streamed(layer: LayerSpec, stacked):
+    """Take the weights :func:`linear.streams` accepts out of a stack
+    segment's params: returns (the rest, {(sub key, name): stack})."""
+    rest, streamed = {}, {}
+    for i, sub in enumerate(layer.subs):
+        key = f"sub_{i}"
+        inner = dict(stacked[key]["inner"])
+        for name in _STREAMED.get(sub.kind, ()):
+            if linear.streams(inner.get(name)):
+                streamed[(key, name)] = inner.pop(name)
+        rest[key] = {**stacked[key], "inner": inner}
+    return rest, streamed
+
+
+def _with_layer(params, streamed, layer):
+    """One layer's params with each streamed stack as a LayerWeight."""
+    params = {key: {**sp, "inner": dict(sp["inner"])} for key, sp in params.items()}
+    for (key, name), stack in streamed.items():
+        params[key]["inner"][name] = linear.LayerWeight(stack, layer)
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -505,8 +533,15 @@ class LM:
                     ncs.append(nc)
                 new_cache[seg.name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ncs)
             else:
+                # streamed weights are closed over, not sliced as xs
+                rest, streamed = _split_streamed(seg.spec, params[seg.name])
+
+                def layer_body(carry, inp, _body=body, _streamed=streamed):
+                    layer, lp, lc = inp
+                    return _body(carry, (_with_layer(lp, _streamed, layer), lc))
+
                 h, new_cache[seg.name] = jax.lax.scan(
-                    body, h, (params[seg.name], cache[seg.name])
-                )
+                    layer_body, h,
+                    (jnp.arange(seg.count, dtype=jnp.int32), rest, cache[seg.name]))
             h = constrain(h, ("batch", None, None))
         return self._head(params, h), new_cache

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.nn import initializers as init
+from repro.nn.linear import dense
 from repro.nn.rope import apply_rope
 from repro.nn.types import P
 
@@ -67,6 +68,12 @@ class AttentionConfig:
         )
 
 
+
+# the weights multiplied by ``nn.linear.dense``, which the decode of a
+# layer scan may hand in a layer at a time (``nn/linear.py``)
+PROJECTIONS = ("wq", "wk", "wv", "wo")
+
+
 def attention_init(cfg: AttentionConfig, key, dtype=jnp.float32):
     dh = cfg.head_dim
     kq, kk, kv, ko = jax.random.split(key, 4)
@@ -107,9 +114,9 @@ def _project_qkv(params, cfg: AttentionConfig, x, kv_x, positions, kv_positions,
 
     b, s, _ = x.shape
     dh = cfg.head_dim
-    q = jnp.einsum("bsd,dh->bsh", x, params["wq"])
-    k = jnp.einsum("bsd,dh->bsh", kv_x, params["wk"])
-    v = jnp.einsum("bsd,dh->bsh", kv_x, params["wv"])
+    q = dense(x, params["wq"])
+    k = dense(kv_x, params["wk"])
+    v = dense(kv_x, params["wv"])
     if cfg.use_bias:
         q, k, v = q + params["bq"], k + params["bk"], v + params["bv"]
     t = kv_x.shape[1]
@@ -365,5 +372,5 @@ def attention_decode(params, cfg: AttentionConfig, x, cache, pos):
     mask = valid[:, None, None, None, :]  # (B or 1, 1,1,1,T)
     out = grouped_attention(q, k_cache.astype(q.dtype), v_cache.astype(q.dtype), mask, cfg.scale)
     out = out.reshape(b, 1, cfg.n_heads * cfg.head_dim)
-    y = jnp.einsum("bsh,hd->bsd", out, params["wo"])
+    y = dense(out, params["wo"])
     return y, {"k": k_cache, "v": v_cache}

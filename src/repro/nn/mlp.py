@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.nn import initializers as init
+from repro.nn.linear import dense
 from repro.nn.types import P
 
 
@@ -33,6 +34,12 @@ class MLPConfig:
     use_bias: bool = False
 
 
+
+# the weights multiplied by ``nn.linear.dense``, which the decode of a
+# layer scan may hand in a layer at a time (``nn/linear.py``)
+PROJECTIONS = ("w_gate", "w_up", "w_down")
+
+
 def mlp_init(cfg: MLPConfig, key, dtype=jnp.float32):
     kg, ku, kd = jax.random.split(key, 3)
     params = {
@@ -49,15 +56,15 @@ def mlp_init(cfg: MLPConfig, key, dtype=jnp.float32):
 
 def mlp_apply(params, cfg: MLPConfig, x):
     act = ACTIVATIONS[cfg.activation]
-    up = jnp.einsum("bsd,df->bsf", x, params["w_up"])
+    up = dense(x, params["w_up"])
     if cfg.use_bias:
         up = up + params["b_up"]
     if cfg.gated:
-        gate = act(jnp.einsum("bsd,df->bsf", x, params["w_gate"]))
+        gate = act(dense(x, params["w_gate"]))
         h = gate * up
     else:
         h = act(up)
-    out = jnp.einsum("bsf,fd->bsd", h, params["w_down"])
+    out = dense(h, params["w_down"])
     if cfg.use_bias:
         out = out + params["b_down"]
     return out

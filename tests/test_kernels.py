@@ -152,3 +152,44 @@ def test_mlstm_chunk_invariance(chunk, gate_bias):
     h1, _ = ops.mlstm_scan(q, k, v, il, fl, chunk=chunk)
     want = ref.mlstm_scan_ref(q, k, v, il, fl)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(want), atol=3e-4, rtol=3e-3)
+
+
+# ---------------------------------------------------------------------------
+# decode matmul (one layer of a stacked f32 weight)
+# ---------------------------------------------------------------------------
+
+# qwen3-1.7b's decode projections, 8 slots, 2 of its 28 layers
+QWEN3_DECODE = {"q": (2048, 2048), "kv": (2048, 1024), "mlp_in": (2048, 6144),
+                "down": (6144, 2048)}
+
+
+def _stack(key, k, n, layers=2):
+    return _rand(key, (layers, k, n), jnp.float32) * k ** -0.5
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("proj", sorted(QWEN3_DECODE))
+def test_decode_matmul_matches_reference(proj, layer):
+    k, n = QWEN3_DECODE[proj]
+    ks = jax.random.split(KEY, 2)
+    x = _rand(ks[0], (8, k), jnp.float32)
+    w = _stack(ks[1], k, n)
+    out = ops.decode_matmul(x, w, jnp.int32(layer))
+    want = ref.decode_matmul_ref(x, w, layer)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("proj", ["kv", "down"])
+def test_decode_matmul_follows_a_scanned_layer_index(proj):
+    """The layer index as decode's scan hands it in: traced, one per
+    step, over a stack of three layers."""
+    k, n = QWEN3_DECODE[proj]
+    ks = jax.random.split(KEY, 2)
+    x = _rand(ks[0], (8, k), jnp.float32)
+    w = _stack(ks[1], k, n, layers=3)
+    _, out = jax.lax.scan(lambda c, layer: (c, ops.decode_matmul(x, w, layer)),
+                          None, jnp.arange(3, dtype=jnp.int32))
+    for layer in range(3):
+        np.testing.assert_allclose(np.asarray(out[layer]),
+                                   np.asarray(ref.decode_matmul_ref(x, w, layer)),
+                                   atol=1e-5, rtol=1e-5)

@@ -81,9 +81,16 @@ def _mlstm_inputs():
     return q, k, v, i_log, f_log
 
 
+def _decode_matmul_inputs():
+    return _rand(13, (8, 256)), _rand(14, (2, 256, 384)), jnp.int32(1)
+
+
 def _call(kernel, schedule=None, **kwargs):
     """Run one schedulable op on the shared inputs; returns the primary
     output array."""
+    if kernel == "decode_matmul":
+        return ops.decode_matmul(*_decode_matmul_inputs(), schedule=schedule,
+                                 **kwargs)
     if kernel == "flash_attention":
         return ops.flash_attention(*_flash_inputs(), causal=True,
                                    schedule=schedule, **kwargs)
@@ -96,6 +103,8 @@ def _call(kernel, schedule=None, **kwargs):
 
 
 def _oracle(kernel):
+    if kernel == "decode_matmul":
+        return ref.decode_matmul_ref(*_decode_matmul_inputs())
     if kernel == "flash_attention":
         return _flash_ref(*_flash_inputs())
     if kernel == "ssm_scan":
@@ -129,11 +138,13 @@ def test_default_schedule_is_bit_identical_to_legacy_path(kernel):
     path bit-for-bit — same blocks, same launch, same floats."""
     plain = _call(kernel)  # no schedule anywhere -> named default
     explicit = _call(kernel, schedule=default_schedule(kernel))
+    assert np.array_equal(np.asarray(plain), np.asarray(explicit))
+    if kernel == "decode_matmul":
+        return  # came after schedules: it has no legacy kwargs
     if kernel == "flash_attention":
         legacy = _call(kernel, block_q=128, block_kv=128)
     else:
         legacy = _call(kernel, chunk=128)
-    assert np.array_equal(np.asarray(plain), np.asarray(explicit))
     assert np.array_equal(np.asarray(plain), np.asarray(legacy))
 
 
@@ -172,6 +183,17 @@ def test_non_power_of_two_field_named_in_error():
         validate_schedule("flash_attention", KernelSchedule(block_kv=96))
 
 
+def test_decode_matmul_fields_named_in_error():
+    with pytest.raises(ScheduleError, match=r"'block_k'=96"):
+        validate_schedule("decode_matmul", KernelSchedule(block_k=96))
+    with pytest.raises(ScheduleError, match=r"'block_n'=2048"):
+        validate_schedule("decode_matmul", KernelSchedule(block_n=2048))
+    with pytest.raises(ScheduleError, match="'chunk'"):
+        validate_schedule("decode_matmul", KernelSchedule(chunk=128))
+    with pytest.raises(ScheduleError, match="'block_k'"):
+        validate_schedule("flash_attention", KernelSchedule(block_k=128))
+
+
 def test_unknown_schedule_dict_field_rejected():
     with pytest.raises(ScheduleError, match="block_z"):
         KernelSchedule.from_dict({"block_z": 64})
@@ -203,6 +225,22 @@ def test_effective_chunk_halves_until_it_divides():
     assert eff.chunk == 192  # min(512, 192) already divides
     eff = effective_schedule("ssm_scan", KernelSchedule(chunk=64), seq_len=96)
     assert eff.chunk == 32  # 64 -> 32 divides 96
+
+
+def test_effective_decode_tiles_divide_and_stay_lane_aligned():
+    eff = effective_schedule("decode_matmul", None, seq_len=6144, kv_len=1024)
+    assert (eff.block_k, eff.block_n) == (512, 1024)
+    eff = effective_schedule("decode_matmul", KernelSchedule(block_k=1024, block_n=32),
+                             seq_len=384, kv_len=640)
+    assert (eff.block_k, eff.block_n) == (384, 128)  # the whole K; lifted to a lane
+
+
+@pytest.mark.parametrize("block_k,block_n", [(128, 128), (256, 128), (1024, 1024)])
+def test_decode_matmul_schedules_match_reference(block_k, block_n):
+    out = _call("decode_matmul",
+                schedule=KernelSchedule(block_k=block_k, block_n=block_n))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_oracle("decode_matmul")),
+                               atol=1e-5, rtol=1e-5)
 
 
 def test_recorder_sees_effective_not_requested():

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ops
+from repro.kernels import schedule as ksched
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 
@@ -33,6 +34,12 @@ CASES = [
     ("mlstm", dict(b=1, l=2048, h=4, p=1024, chunk=512), BF16),
     # needs the kernel's raised scoped-VMEM limit
     ("mlstm", dict(b=1, l=2048, h=4, p=1024, chunk=512), F32),
+    # qwen3-1.7b's decode projection shapes: q and o, k and v, gate and
+    # up, down; 8 slots, its 28 stacked f32 layers
+    ("decode", dict(m=8, k=2048, n=2048), F32),
+    ("decode", dict(m=8, k=2048, n=1024), F32),
+    ("decode", dict(m=8, k=2048, n=6144), F32),
+    ("decode", dict(m=8, k=6144, n=2048), F32),
 ]
 
 
@@ -78,6 +85,12 @@ def _lower(kernel, w, dtype, chip):
             q, k, v, causal=True, window=w["window"], scale=None,
             block_q=128, block_kv=128, interpret=False))
         return fn.lower(arg((b, s, h, d)), arg((b, s, kh, d)), arg((b, s, kh, d)))
+    if kernel == "decode":
+        sched = ksched.default_schedule("decode_matmul")
+        fn = jax.jit(lambda x, stack, layer: ops.decode_matmul_stacked(
+            x, stack, layer, block_k=sched.block_k, block_n=sched.block_n))
+        return fn.lower(arg((w["m"], w["k"])), arg((28, w["k"], w["n"])),
+                        arg((), jnp.int32))
     if kernel == "ssm":
         b, l, h, p, n, g = w["b"], w["l"], w["h"], w["p"], w["n"], w["g"]
         fn = jax.jit(lambda *a: ops._ssm_scan_impl(
@@ -99,3 +112,37 @@ def test_kernel_compiles_for_v5e(kernel, widths, dtype, one_chip,
     compiled = _lower(kernel, widths, dtype, one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+# the decode step's temp bytes when the layer scan sliced the f32
+# stacks itself: the seven bf16 stacks of qwen3-1.7b's projections
+HOISTED_TEMP_BYTES = 2_819_152_896
+
+
+def test_qwen3_decode_reads_f32_stacks_without_whole_stack_converts(
+        one_chip, no_persistent_cache):
+    """``LM.decode`` of qwen3-1.7b at its published widths (f32 weights
+    and cache, 8 slots of 1281) compiled for a v5e: no weight stack is
+    rounded to bf16 outside the layer loop, and the temp bytes that
+    rounding needed are gone."""
+    import re
+
+    from repro.configs import get_arch
+    from repro.models.lm import LM
+    from repro.nn.types import split
+
+    model = LM(get_arch("qwen3-1.7b").spec_fn())
+    params = jax.eval_shape(lambda: split(model.init(jax.random.PRNGKey(0)))[0])
+    cache = jax.eval_shape(
+        lambda p: model.init_cache(p, 8, 1281, dtype=F32), params)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    compiled = jax.jit(model.decode).lower(
+        place(params), place(cache),
+        jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert not re.findall(r"= bf16\[28,[^\]]*\]\S* convert\(", text)
+    assert text.count("tpu_custom_call") >= 7
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= HOISTED_TEMP_BYTES - 2_500_000_000, temp
