@@ -60,16 +60,11 @@ xl = ModelSpec(
 )
 check("xlstm", xl)
 
-# hybrid with a shared attention block
-shared_attn = LayerSpec(
-    subs=transformer_layer(d, 4, 4, 128).subs, shared=True
-)
-hyb_layers = []
-for i in range(4):
-    hyb_layers.append(LayerSpec(subs=(SubBlock("mamba2", Mamba2Config(d, d_state=16, d_head=16, chunk=8)),)))
-    if i % 2 == 1:
-        hyb_layers.append(shared_attn)
-hybrid = ModelSpec(name="tiny-hybrid", d_model=d, vocab=128, layers=tuple(hyb_layers), positional="none")
+# hybrid: Mamba2 layers, two of which run the shared blocks (zamba2)
+from repro.configs.zamba2_2_7b import _spec as zamba2_spec
+
+hybrid = zamba2_spec("tiny-hybrid", d, 4, (1, 3), n_heads=4, d_ff=128, d_state=16,
+                     d_head_ssm=16, adapter_rank=8, vocab=128, chunk=8)
 check("hybrid", hybrid)
 
 # enc-dec (whisper-like)

@@ -12,7 +12,12 @@ once and nothing else of the stack is touched.
 
 Grid: ``(N tiles, K tiles)``, K innermost; the output block stays in
 VMEM across K and accumulates there.  The layer index is a
-scalar-prefetch operand read by the weight's index map.
+scalar-prefetch operand read by the weight's index map.  The tiles may
+cover only the leading ``n`` columns of a wider stack, and the stack
+may come K-minor, as ``(L, N, K)``: the TPU lays out a weight whose N
+is not lane-aligned so (zamba2's Mamba2 ``in_proj``, 10,448 wide, gives
+the kernel its first 10,240 columns, read as rows of its K-minor
+layout).
 """
 from __future__ import annotations
 
@@ -27,10 +32,12 @@ _MIB = 1 << 20
 _VMEM_CAP = 100 * _MIB
 
 
-def _kernel(layer_ref, x_ref, w_ref, o_ref):
+def _kernel(layer_ref, x_ref, w_ref, o_ref, *, k_minor):
     del layer_ref  # read by the index maps only
-    part = jnp.dot(x_ref[...].astype(jnp.bfloat16), w_ref[...].astype(jnp.bfloat16),
-                   preferred_element_type=jnp.float32)
+    contract = (1,) if k_minor else (0,)
+    part = jax.lax.dot_general(
+        x_ref[...].astype(jnp.bfloat16), w_ref[...].astype(jnp.bfloat16),
+        (((1,), contract), ((), ())), preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -49,28 +56,38 @@ def _vmem_limit(m, block_k, block_n):
     return min(_VMEM_CAP, max(32 * _MIB, 2 * (tiles + rows)))
 
 
-@functools.partial(jax.jit, static_argnames=("block_k", "block_n", "interpret"))
-def decode_matmul_stacked(x, w, layer, *, block_k, block_n, interpret=False):
-    """``x @ bf16(w[layer])``.
+@functools.partial(jax.jit, static_argnames=("block_k", "block_n", "n", "k_minor",
+                                             "interpret"))
+def decode_matmul_stacked(x, w, layer, *, block_k, block_n, n=None, k_minor=False,
+                          interpret=False):
+    """``x @ bf16(w[layer][:, :n])``, or ``x @ bf16(w[layer][:n]).T`` with
+    ``k_minor``.
 
-    x: (M, K); w: (L, K, N) float32; layer: int32 scalar.  ``block_k``
-    divides K and ``block_n`` divides N.  Returns (M, N) float32,
-    accumulated in f32 from bf16 operands.
+    x: (M, K); w: (L, K, N) float32, or (L, N, K) with ``k_minor``;
+    layer: int32 scalar; ``n`` at most N, all of it by default.
+    ``block_k`` divides K and ``block_n`` divides ``n``.  Returns (M, n)
+    float32, accumulated in f32 from bf16 operands.
     """
     m, kdim = x.shape
-    n = w.shape[2]
-    assert w.shape[1] == kdim and kdim % block_k == 0 and n % block_n == 0, (
-        w.shape, block_k, block_n)
+    wk, wn = (2, 1) if k_minor else (1, 2)
+    n = w.shape[wn] if n is None else n
+    assert w.shape[wk] == kdim and kdim % block_k == 0 and n % block_n == 0, (
+        w.shape, n, block_k, block_n)
+    assert n <= w.shape[wn], (w.shape, n)
+    if k_minor:
+        w_spec = pl.BlockSpec((pl.Squeezed(), block_n, block_k),
+                              lambda j, k, layer_ref: (layer_ref[0], j, k))
+    else:
+        w_spec = pl.BlockSpec((pl.Squeezed(), block_k, block_n),
+                              lambda j, k, layer_ref: (layer_ref[0], k, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n // block_n, kdim // block_k),
-        in_specs=[pl.BlockSpec((m, block_k), lambda j, k, layer_ref: (0, k)),
-                  pl.BlockSpec((pl.Squeezed(), block_k, block_n),
-                               lambda j, k, layer_ref: (layer_ref[0], k, j))],
+        in_specs=[pl.BlockSpec((m, block_k), lambda j, k, layer_ref: (0, k)), w_spec],
         out_specs=pl.BlockSpec((m, block_n), lambda j, k, layer_ref: (0, j)),
     )
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, k_minor=k_minor),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(

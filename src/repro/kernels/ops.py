@@ -191,20 +191,26 @@ def mlstm_scan(q, k, v, i_log, f_log, *, chunk=None, schedule=None):
 # decode matmul
 # ---------------------------------------------------------------------------
 
-def decode_matmul(x, w, layer, *, schedule=None, platform=None):
-    """``x @ bf16(w[layer])``.
+def decode_matmul(x, w, layer, *, n=None, k_minor=False, schedule=None, platform=None):
+    """``x @ bf16(w[layer][:, :n])``, or ``x @ bf16(w[layer][:n]).T`` with
+    ``k_minor``.
 
-    x: (M, K); w: (L, K, N) float32, K and N multiples of 128; layer:
-    int32 scalar.  Returns (M, N) float32.  ``platform`` names the
-    platform the call is lowered for where the caller knows it (a branch
-    of ``lax.platform_dependent``); by default, the platform being traced
+    x: (M, K); w: (L, K, N) float32, or (L, N, K) with ``k_minor``; K
+    and ``n`` (all of N by default) multiples of 128; layer: int32
+    scalar.  Returns (M, n) float32.  ``platform`` names the platform
+    the call is lowered for where the caller knows it (a branch of
+    ``lax.platform_dependent``); by default, the platform being traced
     for."""
+    kdim, ndim = (w.shape[2], w.shape[1]) if k_minor else (w.shape[1], w.shape[2])
+    n = ndim if n is None else n
     requested = _resolve("decode_matmul", schedule, {})
     eff = _finish(requested, ksched.effective_schedule(
-        "decode_matmul", requested, seq_len=w.shape[1], kv_len=w.shape[2]),
+        "decode_matmul", requested, seq_len=kdim, kv_len=n),
         platform)
-    ksched.note_kernel_call("decode_matmul", requested, eff,
-                            shapes={"x": x.shape, "w": w.shape},
-                            meta={"dtype": str(w.dtype)})
-    return decode_matmul_stacked(x, w, layer, block_k=eff.block_k,
-                                 block_n=eff.block_n, interpret=eff.interpret)
+    shapes, meta = {"x": x.shape, "w": w.shape}, {"dtype": str(w.dtype)}
+    if n != ndim or k_minor:
+        shapes["out"] = (x.shape[0], n)
+        meta["k_minor"] = k_minor
+    ksched.note_kernel_call("decode_matmul", requested, eff, shapes=shapes, meta=meta)
+    return decode_matmul_stacked(x, w, layer, block_k=eff.block_k, block_n=eff.block_n,
+                                 n=n, k_minor=k_minor, interpret=eff.interpret)

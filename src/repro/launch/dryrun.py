@@ -5,6 +5,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # device count at first init).  Everything below is ordinary code.
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import dataclasses  # noqa: E402
 import functools  # noqa: E402
 import json  # noqa: E402
@@ -37,11 +38,11 @@ TRAIN_MICROBATCHES = {
     "whisper-medium": 2,
 }
 
-# Layer-pattern period for the cost extrapolation (archs whose layer list
-# repeats in units > 1: zamba2 = 6 mamba + 1 shared attn; xlstm = 7 mLSTM
-# + 1 sLSTM).
+# Layer-pattern period for the cost extrapolation (archs whose layer mix
+# repeats in units > 1: zamba2 = 5 Mamba2 + 1 hybrid, 9 units of its 54
+# layers; xlstm = 7 mLSTM + 1 sLSTM).
 PATTERN_UNITS = {
-    "zamba2-2.7b": 7,
+    "zamba2-2.7b": 6,
     "xlstm-1.3b": 8,
 }
 
@@ -80,9 +81,18 @@ def _cost_stats(compiled):
 
 
 def _slice_units(spec, arch_name: str, k: int):
-    """Keep the first k layer-pattern units (cost extrapolation)."""
+    """k layer-pattern units (cost extrapolation).  Where a unit is more
+    than one layer, each holds the model's mix of layers (cost per layer
+    is additive): a prefix need not, as zamba2's hybrid layers lie 6
+    apart, then 5, 4 and 3, none among the first 6."""
     unit = PATTERN_UNITS.get(arch_name, 1)
     layers = tuple(spec.layers[: unit * k])
+    if unit > 1:
+        n_units = len(spec.layers) // unit
+        counts = collections.Counter(spec.layers)
+        assert all(c % n_units == 0 for c in counts.values()), (arch_name, unit)
+        mix = tuple(layer for layer in counts for _ in range(counts[layer] // n_units))
+        layers = mix * k
     enc = tuple(spec.encoder_layers[:k]) if spec.encoder_layers else ()
     return dataclasses.replace(spec, layers=layers, encoder_layers=enc)
 
@@ -103,8 +113,14 @@ def _map_attention_cfg(layers, **fields):
     return _map_sub_cfg(layers, ("attention",), **fields)
 
 
-def _swap_attention_impl(layers, impl):
-    return _map_attention_cfg(layers, impl=impl)
+def _map_attention(spec, **fields):
+    """``spec`` with ``fields`` set on every attention config: its
+    layers', its encoder's and its shared blocks'."""
+    shared = spec.shared and dataclasses.replace(
+        spec.shared, layer=_map_attention_cfg((spec.shared.layer,), **fields)[0])
+    return dataclasses.replace(
+        spec, layers=_map_attention_cfg(spec.layers, **fields),
+        encoder_layers=_map_attention_cfg(spec.encoder_layers, **fields), shared=shared)
 
 
 def _map_moe_cfg(layers, **fields):
@@ -126,11 +142,7 @@ def apply_variant(spec, variant):
     (chunked_loss is a train-step knob handled in build_cell.)
     """
     if "chunked_attn" in variant:
-        spec = dataclasses.replace(
-            spec,
-            layers=_swap_attention_impl(spec.layers, "xla_chunked"),
-            encoder_layers=_swap_attention_impl(spec.encoder_layers, "xla_chunked"),
-        )
+        spec = _map_attention(spec, impl="xla_chunked")
     if "remat_dots" in variant:
         spec = dataclasses.replace(spec, remat_policy="dots")
     if "no_remat" in variant:
@@ -138,19 +150,10 @@ def apply_variant(spec, variant):
     if "moe_2d" in variant:
         spec = dataclasses.replace(spec, layers=_map_moe_cfg(spec.layers, shard_ff=True))
     if "seq_shard" in variant:
-        spec = dataclasses.replace(
-            spec,
-            layers=_map_attention_cfg(spec.layers, seq_shard=True),
-            encoder_layers=_map_attention_cfg(spec.encoder_layers, seq_shard=True),
-        )
+        spec = _map_attention(spec, seq_shard=True)
     for flag in variant.split(","):
         if flag.startswith("kvc") and flag[3:].isdigit():
-            kvc = int(flag[3:])
-            spec = dataclasses.replace(
-                spec,
-                layers=_map_attention_cfg(spec.layers, kv_chunk=kvc),
-                encoder_layers=_map_attention_cfg(spec.encoder_layers, kv_chunk=kvc),
-            )
+            spec = _map_attention(spec, kv_chunk=int(flag[3:]))
     return spec
 
 

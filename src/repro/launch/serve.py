@@ -48,7 +48,10 @@ numbers that grow for the engine's life (read differences over a
 window): ``queue.waits_s`` (queue wait of each request taken),
 ``steps``, ``slot_steps`` (active slots summed over steps),
 ``valid_positions`` (each active slot's valid cache positions summed
-over steps) and ``capacity_positions`` (``max_batch * max_context``).
+over steps) and ``capacity_positions`` (``max_batch * max_context``),
+positions of one K/V cache: every K/V cache of a model holds
+``max_context`` positions a slot, be it an attention layer's or, in a
+hybrid model, a shared block invocation's.
 ``decode_kernel_calls``, set once when the engine is built, counts the
 distinct kernel calls its decode program runs (0 off a TPU).
 """
@@ -137,6 +140,9 @@ class ServingEngine:
         self._axes_flat = self._batch_axes()
         self.decode = jax.jit(model.decode)
         self._prefill_jit = jax.jit(model.prefill)
+        # a join's merge into its slot, one program: eagerly it was an
+        # op per cache leaf
+        self._merged = jax.jit(self._merge_leaves)
         self.decode_kernel_calls = self._count_decode_kernel_calls()
         # slot i: None, or dict(req=, pos=, token=, out=[generated tokens])
         self.slots: List[Optional[Dict[str, Any]]] = [None] * self.max_batch
@@ -195,11 +201,11 @@ class ServingEngine:
             self.model.cache_axes(), is_leaf=is_axes)[0]
         return [len(t) - t.index("batch") for t in axes_leaves]
 
-    def _merge_slot(self, single_cache, slot: int) -> None:
-        """Write a batch-1 prefilled cache into slot ``slot`` of the
-        batched cache (dynamic_update_slice on each leaf's batch axis)."""
+    def _merge_leaves(self, cache, single_cache, slot):
+        """``cache`` with a batch-1 prefilled cache written into slot
+        ``slot`` (dynamic_update_slice on each leaf's batch axis)."""
         jax = self.jax
-        b_leaves, treedef = jax.tree_util.tree_flatten(self.cache)
+        b_leaves, treedef = jax.tree_util.tree_flatten(cache)
         s_leaves = jax.tree_util.tree_flatten(single_cache)[0]
         merged = []
         for b, s, from_right in zip(b_leaves, s_leaves, self._axes_flat):
@@ -207,7 +213,12 @@ class ServingEngine:
             starts[b.ndim - from_right] = slot
             merged.append(jax.lax.dynamic_update_slice(
                 b, s.astype(b.dtype), tuple(starts)))
-        self.cache = jax.tree_util.tree_unflatten(treedef, merged)
+        return jax.tree_util.tree_unflatten(treedef, merged)
+
+    def _merge_slot(self, single_cache, slot: int) -> None:
+        """Write a batch-1 prefilled cache into slot ``slot`` of the
+        batched cache."""
+        self.cache = self._merged(self.cache, single_cache, self.jnp.int32(slot))
 
     def _join(self, req) -> None:
         """Prefill one request (full-sequence kernel) into a free slot."""

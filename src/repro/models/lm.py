@@ -3,8 +3,10 @@
 Consecutive identical layers are grouped into *segments*; each segment's
 parameters are stacked on a leading ``layers`` axis and executed with
 ``jax.lax.scan`` (bounded compile time for 96-layer models, and the scan
-body is the natural remat unit).  Weight-shared layers (zamba2's shared
-attention block) hold one parameter set but per-invocation KV caches.
+body is the natural remat unit).  zamba2's hybrid layers run one of the
+model's weight-tied shared blocks (``shared_<b>``) on the hidden state
+beside the embedding output; each invocation keeps its own adapter,
+``linear`` and K/V cache under its own segment.
 
 Decode runs against preallocated caches (attention KV / SSM state /
 mLSTM matrix state), one token per step, positions passed explicitly.
@@ -35,25 +37,22 @@ from repro.nn.types import P
 
 @dataclasses.dataclass(frozen=True)
 class Segment:
-    kind: str  # "stack" | "shared"
     spec: LayerSpec
     count: int
     name: str
 
 
 def build_segments(layers: Tuple[LayerSpec, ...], prefix: str = "seg") -> Tuple[Segment, ...]:
+    """Runs of identical layers, each stacked on a leading axis.  A hybrid
+    layer is a segment of its own: each invocation picks its block."""
     segments = []
     i = 0
     while i < len(layers):
         spec = layers[i]
-        if spec.shared:
-            segments.append(Segment("shared", spec, 1, f"{prefix}_{len(segments)}"))
-            i += 1
-            continue
-        j = i
-        while j < len(layers) and layers[j] == spec and not layers[j].shared:
+        j = i + 1
+        while not spec.hybrid and j < len(layers) and layers[j] == spec:
             j += 1
-        segments.append(Segment("stack", spec, j - i, f"{prefix}_{len(segments)}"))
+        segments.append(Segment(spec, j - i, f"{prefix}_{len(segments)}"))
         i = j
     return tuple(segments)
 
@@ -115,13 +114,16 @@ def _sub_prefill(sub: SubBlock, params, x, cache, pos_offset):
 
     Attention runs through the same full-sequence kernel dispatch as
     :func:`attention_apply` and writes the whole prompt's K/V in one
-    shot.  Recurrent kinds (mamba2/mlstm/slstm) ingest the prompt with a
-    ``lax.scan`` of their decode step — one compiled program, batched
-    over the prompt, and bitwise identical to the token-by-token loop it
-    replaces.  Returns (y (B,S,d), new_cache).
+    shot.  Mamba2 runs its chunked scan from the cache's state and hands
+    back the state after the prompt.  mLSTM and sLSTM ingest the prompt
+    with a ``lax.scan`` of their decode step — one compiled program,
+    batched over the prompt, and bitwise identical to the token-by-token
+    loop it replaces.  Returns (y (B,S,d), new_cache).
     """
     if sub.kind == "attention":
         return attn.attention_prefill(params, sub.cfg, x, cache, pos_offset)
+    if sub.kind == "mamba2":
+        return ssm_mod.mamba2_apply(params, sub.cfg, x, cache)
     if sub.kind == "cross_attention":
         return attn.cross_attention_cached(params, sub.cfg, x, cache), cache
     if sub.kind == "mlp":
@@ -160,28 +162,55 @@ def _sub_decode(sub: SubBlock, params, x, cache, pos):
 
 # the projection weights that decode may read a layer at a time
 # (``nn/linear.py``), by sub-block kind; each module owns its list
-_STREAMED = {"attention": attn.PROJECTIONS, "mlp": mlp_mod.PROJECTIONS}
+_STREAMED = {"attention": attn.PROJECTIONS, "mlp": mlp_mod.PROJECTIONS,
+             "mamba2": ssm_mod.PROJECTIONS}
+# and a hybrid layer's own: its adapter and linear
+_HYBRID_STREAMED = (("adapter", "lora_a"), ("adapter", "lora_b"), ("linear",))
+
+
+def _streamed_paths(layer: LayerSpec):
+    paths = [(f"sub_{i}", "inner", name)
+             for i, sub in enumerate(layer.subs) for name in _STREAMED.get(sub.kind, ())]
+    return paths + list(_HYBRID_STREAMED) * layer.hybrid
+
+
+def _get(tree, path):
+    for key in path:
+        if not isinstance(tree, dict) or key not in tree:
+            return None
+        tree = tree[key]
+    return tree
+
+
+def _set(tree, path, value):
+    """A copy of nested dict ``tree`` with ``path`` set (or, for None,
+    removed)."""
+    key, rest = path[0], path[1:]
+    out = dict(tree)
+    if rest:
+        out[key] = _set(tree[key], rest, value)
+    elif value is None:
+        del out[key]
+    else:
+        out[key] = value
+    return out
 
 
 def _split_streamed(layer: LayerSpec, stacked):
     """Take the weights :func:`linear.streams` accepts out of a stack
-    segment's params: returns (the rest, {(sub key, name): stack})."""
-    rest, streamed = {}, {}
-    for i, sub in enumerate(layer.subs):
-        key = f"sub_{i}"
-        inner = dict(stacked[key]["inner"])
-        for name in _STREAMED.get(sub.kind, ()):
-            if linear.streams(inner.get(name)):
-                streamed[(key, name)] = inner.pop(name)
-        rest[key] = {**stacked[key], "inner": inner}
+    segment's params: returns (the rest, {path: stack})."""
+    rest, streamed = stacked, {}
+    for path in _streamed_paths(layer):
+        if linear.streams(_get(stacked, path)):
+            streamed[path] = _get(stacked, path)
+            rest = _set(rest, path, None)
     return rest, streamed
 
 
 def _with_layer(params, streamed, layer):
     """One layer's params with each streamed stack as a LayerWeight."""
-    params = {key: {**sp, "inner": dict(sp["inner"])} for key, sp in params.items()}
-    for (key, name), stack in streamed.items():
-        params[key]["inner"][name] = linear.LayerWeight(stack, layer)
+    for path, stack in streamed.items():
+        params = _set(params, path, linear.LayerWeight(stack, layer))
     return params
 
 
@@ -189,22 +218,39 @@ def _with_layer(params, streamed, layer):
 # layer = sequence of pre-norm residual sub-blocks
 # ---------------------------------------------------------------------------
 
+def _take(tree, i):
+    return jax.tree_util.tree_map(lambda x: x[i], tree)
+
+
 class LM:
     def __init__(self, spec: ModelSpec):
         self.spec = spec
         self.segments = build_segments(spec.layers)
         self.enc_segments = build_segments(spec.encoder_layers, prefix="enc")
+        hybrid = [seg.name for seg in self.segments if seg.spec.hybrid]
+        assert not hybrid or spec.shared, "hybrid layers need ModelSpec.shared"
+        # the shared block of each hybrid segment: its invocation index mod n
+        self.block = {name: f"shared_{i % spec.shared.n}" for i, name in enumerate(hybrid)}
+
+    def _norm(self, params, x):
+        eps = {} if self.spec.norm_eps is None else {"eps": self.spec.norm_eps}
+        return NORM_APPLY[self.spec.norm](params, x, **eps)
 
     # -- init ---------------------------------------------------------------
 
     def _layer_init(self, layer: LayerSpec, key, dtype):
         params = {}
-        keys = jax.random.split(key, len(layer.subs))
+        keys = jax.random.split(key, len(layer.subs) + 2 * layer.hybrid)
         for i, (sub, k) in enumerate(zip(layer.subs, keys)):
             params[f"sub_{i}"] = {
-                "norm": NORM_INIT[self.spec.norm](self.spec.d_model, dtype),
+                "norm": NORM_INIT[self.spec.norm](sub.cfg.d_model, dtype),
                 "inner": _sub_init(sub, k, dtype),
             }
+        if layer.hybrid:
+            shared, d = self.spec.shared, self.spec.d_model
+            params["adapter"] = mlp_mod.adapter_init(
+                shared.layer.subs[1].cfg, shared.adapter_rank, keys[-2], dtype)
+            params["linear"] = P(init.scaled_normal(keys[-1], (d, d), dtype), (None, "embed"))
         return params
 
     def init(self, key, dtype=jnp.float32):
@@ -227,13 +273,7 @@ class LM:
             )
         params["final_norm"] = NORM_INIT[spec.norm](spec.d_model, dtype)
         kidx = 3
-        shared_done = False
         for seg, k in zip(self.segments, keys[kidx : kidx + len(self.segments)]):
-            if seg.kind == "shared":
-                if not shared_done:
-                    params["shared"] = self._layer_init(seg.spec, k, dtype)
-                    shared_done = True
-                continue
             layer_keys = jax.random.split(k, seg.count)
             params[seg.name] = jax.vmap(
                 functools.partial(self._layer_init, seg.spec, dtype=dtype)
@@ -246,30 +286,59 @@ class LM:
                 params[seg.name] = jax.vmap(
                     functools.partial(self._layer_init, seg.spec, dtype=dtype)
                 )(layer_keys)
+        if spec.shared:
+            for b, k in enumerate(jax.random.split(keys[-1], spec.shared.n)):
+                params[f"shared_{b}"] = self._layer_init(spec.shared.layer, k, dtype)
         return params
 
-    # -- forward ------------------------------------------------------------
+    # -- one layer ------------------------------------------------------------
 
-    def _layer_apply(self, layer: LayerSpec, params, h, *, positions, enc_out):
+    def _layer(self, layer: LayerSpec, params, h, call, emb=None, block=None):
+        """One layer's pre-norm residual sub-blocks, ``h + f(norm(h))``.
+        ``call(key, sub, inner params, x)`` runs one and returns ``(y, its
+        new cache)``, the cache under ``key`` in the layer's.  A hybrid
+        layer first runs shared block ``block`` (:meth:`_shared`), whose
+        output joins the first sub-block's input.  Returns (h, new cache).
+        """
+        new_cache = {}
+        lift = None
+        if layer.hybrid:
+            lift, new_cache["shared"] = self._shared(block, params, h, emb, call)
         for i, sub in enumerate(layer.subs):
             sp = params[f"sub_{i}"]
             with jax.named_scope(sub.kind):
-                x = NORM_APPLY[self.spec.norm](sp["norm"], h)
-                y = _sub_apply(sub, sp["inner"], x, positions=positions, enc_out=enc_out)
+                x = self._norm(sp["norm"], h if lift is None else h + lift)
+                y, new_cache[f"sub_{i}"] = call(f"sub_{i}", sub, sp["inner"], x)
                 h = h + y
-        return h
+            lift = None
+        return h, new_cache
 
-    def _run_segments(self, segments, params, h, *, positions, enc_out):
+    def _shared(self, block, params, h, emb, call):
+        """A hybrid layer's shared block on ``[h; emb]``, with the layer's
+        own adapter and ``linear``: ``linear(mlp(norm(attention(norm([h;
+        emb])))))``, no residual inside.  Returns (it, the K/V cache)."""
+        attn_sub, mlp_sub = self.spec.shared.layer.subs
+        a, m = block["sub_0"], block["sub_1"]
+        with jax.named_scope("attention"):
+            x = self._norm(a["norm"], jnp.concatenate([h, emb.astype(h.dtype)], axis=-1))
+            t, cache = call("shared", attn_sub, a["inner"], x)
+        with jax.named_scope("mlp"):
+            t = mlp_mod.mlp_apply(m["inner"], mlp_sub.cfg, self._norm(m["norm"], t),
+                                  adapter=params["adapter"])
+            t = linear.dense(t, params["linear"])
+        return t, cache
+
+    # -- forward ------------------------------------------------------------
+
+    def _run_segments(self, segments, params, h, *, positions, enc_out, emb=None):
+        def call(key, sub, inner, x):
+            return _sub_apply(sub, inner, x, positions=positions, enc_out=enc_out), None
+
         for seg in segments:
-            if seg.kind == "shared":
-                h = self._layer_apply(seg.spec, params["shared"], h, positions=positions, enc_out=enc_out)
-                h = constrain(h, ("batch", None, None))
-                continue
+            block = params.get(self.block.get(seg.name))
 
-            def body(carry, layer_params, _seg=seg):
-                out = self._layer_apply(
-                    _seg.spec, layer_params, carry, positions=positions, enc_out=enc_out
-                )
+            def body(carry, layer_params, _seg=seg, _block=block):
+                out, _ = self._layer(_seg.spec, layer_params, carry, call, emb, _block)
                 return out, None
 
             if self.spec.remat:
@@ -278,10 +347,10 @@ class LM:
                     policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                 body = jax.checkpoint(body, prevent_cse=False, policy=policy)
             if seg.count == 1:
-                h, _ = body(h, jax.tree_util.tree_map(lambda x: x[0], params[seg.name]))
+                h, _ = body(h, _take(params[seg.name], 0))
             elif not self.spec.scan_layers:
                 for i in range(seg.count):
-                    h, _ = body(h, jax.tree_util.tree_map(lambda x, _i=i: x[_i], params[seg.name]))
+                    h, _ = body(h, _take(params[seg.name], i))
             else:
                 h, _ = jax.lax.scan(body, h, params[seg.name])
             h = constrain(h, ("batch", None, None))
@@ -299,7 +368,7 @@ class LM:
 
     def _head(self, params, h):
         with jax.named_scope("head"):
-            h = NORM_APPLY[self.spec.norm](params["final_norm"], h)
+            h = self._norm(params["final_norm"], h)
             if self.spec.tie_embeddings:
                 logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])
             else:
@@ -316,7 +385,17 @@ class LM:
             h = h + params["pos_embed"][: h.shape[1]][None].astype(h.dtype)
         positions = jnp.arange(h.shape[1])[None]
         h = self._run_segments(self.enc_segments, params, h, positions=positions, enc_out=None)
-        return NORM_APPLY[self.spec.norm](params["enc_final_norm"], h)
+        return self._norm(params["enc_final_norm"], h)
+
+    def _backbone(self, params, tokens, prefix_embeds, enc_out, positions):
+        h = emb = self._embed(params, tokens, prefix_embeds)
+        if positions is None:
+            positions = jnp.arange(tokens.shape[1])[None]
+        if self.spec.positional == "learned":
+            h = h + params["pos_embed"][: h.shape[1]][None].astype(h.dtype)
+        h = constrain(h, ("batch", None, None))
+        return self._run_segments(self.segments, params, h, positions=positions,
+                                  enc_out=enc_out, emb=emb)
 
     def hidden(self, params, tokens, *, prefix_embeds=None, enc_out=None, positions=None):
         """Full-sequence forward -> final normed hidden states (B, S, d).
@@ -324,14 +403,8 @@ class LM:
         Used with :func:`repro.train.loss.chunked_cross_entropy` so the
         (B, S, vocab) logits never materialize at once.
         """
-        h = self._embed(params, tokens, prefix_embeds)
-        if positions is None:
-            positions = jnp.arange(tokens.shape[1])[None]
-        if self.spec.positional == "learned":
-            h = h + params["pos_embed"][: h.shape[1]][None].astype(h.dtype)
-        h = constrain(h, ("batch", None, None))
-        h = self._run_segments(self.segments, params, h, positions=positions, enc_out=enc_out)
-        return NORM_APPLY[self.spec.norm](params["final_norm"], h)
+        h = self._backbone(params, tokens, prefix_embeds, enc_out, positions)
+        return self._norm(params["final_norm"], h)
 
     def head_weight(self, params):
         """(weight, transposed): logits = h @ w or einsum('bsd,vd', h, w)."""
@@ -341,13 +414,7 @@ class LM:
 
     def apply(self, params, tokens, *, prefix_embeds=None, enc_out=None, positions=None):
         """Full-sequence forward -> logits (B, S, vocab)."""
-        h = self._embed(params, tokens, prefix_embeds)
-        if positions is None:
-            positions = jnp.arange(tokens.shape[1])[None]
-        if self.spec.positional == "learned":
-            h = h + params["pos_embed"][: h.shape[1]][None].astype(h.dtype)
-        h = constrain(h, ("batch", None, None))
-        h = self._run_segments(self.segments, params, h, positions=positions, enc_out=enc_out)
+        h = self._backbone(params, tokens, prefix_embeds, enc_out, positions)
         return self._head(params, h)
 
     # -- decode -------------------------------------------------------------
@@ -359,32 +426,25 @@ class LM:
             if sub.kind == "cross_attention" and enc_out is not None:
                 c = attn.precompute_cross_kv(params_layer[f"sub_{i}"]["inner"], sub.cfg, enc_out, dtype)
             cache[f"sub_{i}"] = c
+        if layer.hybrid:
+            cache["shared"] = _sub_cache_init(
+                self.spec.shared.layer.subs[0], batch, max_seq, enc_len, dtype)
         return cache
 
     def init_cache(self, params, batch, max_seq, *, enc_out=None, dtype=jnp.bfloat16):
         """Build the full decode cache pytree (segment-stacked)."""
         enc_len = enc_out.shape[1] if enc_out is not None else 0
         cache: Dict[str, Any] = {}
-        shared_idx = 0
         for seg in self.segments:
-            if seg.kind == "shared":
-                cache[f"shared_{shared_idx}"] = self._layer_cache(
-                    seg.spec, params["shared"], batch, max_seq, enc_len, enc_out, dtype
-                )
-                shared_idx += 1
-                continue
-            one = lambda i: self._layer_cache(
-                seg.spec,
-                jax.tree_util.tree_map(lambda x: x[i], params[seg.name]),
-                batch, max_seq, enc_len, enc_out, dtype,
-            )
             if any(sub.kind == "cross_attention" for sub in seg.spec.subs):
-                layer_caches = [one(i) for i in range(seg.count)]
+                layer_caches = [self._layer_cache(
+                    seg.spec, _take(params[seg.name], i), batch, max_seq, enc_len, enc_out, dtype)
+                    for i in range(seg.count)]
                 cache[seg.name] = jax.tree_util.tree_map(
                     lambda *xs: jnp.stack(xs), *layer_caches
                 )
-            else:
-                c0 = one(0)
+            else:  # only cross-attention reads a layer's params
+                c0 = self._layer_cache(seg.spec, None, batch, max_seq, enc_len, enc_out, dtype)
                 cache[seg.name] = jax.tree_util.tree_map(
                     lambda x: jnp.tile(x[None], (seg.count,) + (1,) * x.ndim), c0
                 )
@@ -407,41 +467,64 @@ class LM:
 
         Stacked (per-segment) leaves gain a leading layers dim; the
         sharding resolver pads missing leading axes with None, so the
-        same tuples serve both stacked and shared entries.
+        same tuples serve every entry.
         """
         axes: Dict[str, Any] = {}
-        shared_idx = 0
         for seg in self.segments:
             entry = {
                 f"sub_{i}": dict(self._CACHE_AXES[sub.kind])
                 for i, sub in enumerate(seg.spec.subs)
             }
-            if seg.kind == "shared":
-                axes[f"shared_{shared_idx}"] = entry
-                shared_idx += 1
-            else:
-                axes[seg.name] = entry
+            if seg.spec.hybrid:
+                entry["shared"] = dict(self._CACHE_AXES["attention"])
+            axes[seg.name] = entry
         return axes
 
-    def _layer_decode(self, layer: LayerSpec, params, cache, h, pos):
-        new_cache = {}
-        for i, sub in enumerate(layer.subs):
-            sp = params[f"sub_{i}"]
-            with jax.named_scope(sub.kind):
-                x = NORM_APPLY[self.spec.norm](sp["norm"], h)
-                y, new_cache[f"sub_{i}"] = _sub_decode(sub, sp["inner"], x, cache[f"sub_{i}"], pos)
-                h = h + y
-        return h, new_cache
+    def _walk(self, params, cache, h, call, emb, stream=False):
+        """Every segment's layers over ``h`` with their caches; ``call(layer
+        cache)`` gives the ``call`` that :meth:`_layer` takes.  With
+        ``stream``, each segment is a scan (of one layer, too) that closes
+        over the stacks :func:`linear.streams` accepts and hands each
+        layer a :class:`linear.LayerWeight`, and so are the shared blocks'
+        weights, as stacks of one.  Returns (h, new cache)."""
+        new_cache: Dict[str, Any] = {}
+        stream = stream and self.spec.scan_layers
+        for seg in self.segments:
+            block = params.get(self.block.get(seg.name))
+            if stream and block is not None:
+                # an unstacked weight is a stack of one layer
+                rest, streamed = _split_streamed(
+                    self.spec.shared.layer, jax.tree_util.tree_map(lambda x: x[None], block))
+                block = _with_layer(_take(rest, 0), streamed, jnp.int32(0))
 
-    def _layer_prefill(self, layer: LayerSpec, params, cache, h, pos_offset):
-        new_cache = {}
-        for i, sub in enumerate(layer.subs):
-            sp = params[f"sub_{i}"]
-            with jax.named_scope(sub.kind):
-                x = NORM_APPLY[self.spec.norm](sp["norm"], h)
-                y, new_cache[f"sub_{i}"] = _sub_prefill(
-                    sub, sp["inner"], x, cache[f"sub_{i}"], pos_offset)
-                h = h + y
+            def body(carry, inp, _seg=seg, _block=block):
+                lp, lc = inp
+                return self._layer(_seg.spec, lp, carry, call(lc), emb, _block)
+
+            if stream:
+                # streamed weights are closed over, not sliced as xs
+                rest, streamed = _split_streamed(seg.spec, params[seg.name])
+
+                def layer_body(carry, inp, _body=body, _streamed=streamed):
+                    layer, lp, lc = inp
+                    return _body(carry, (_with_layer(lp, _streamed, layer), lc))
+
+                h, new_cache[seg.name] = jax.lax.scan(
+                    layer_body, h,
+                    (jnp.arange(seg.count, dtype=jnp.int32), rest, cache[seg.name]))
+            elif seg.count == 1:
+                h, nc = body(h, (_take(params[seg.name], 0), _take(cache[seg.name], 0)))
+                new_cache[seg.name] = jax.tree_util.tree_map(lambda x: x[None], nc)
+            elif not self.spec.scan_layers:
+                ncs = []
+                for i in range(seg.count):
+                    h, nc = body(h, (_take(params[seg.name], i), _take(cache[seg.name], i)))
+                    ncs.append(nc)
+                new_cache[seg.name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ncs)
+            else:
+                h, new_cache[seg.name] = jax.lax.scan(
+                    body, h, (params[seg.name], cache[seg.name]))
+            h = constrain(h, ("batch", None, None))
         return h, new_cache
 
     def prefill(self, params, cache, tokens, pos_offset=0):
@@ -453,43 +536,17 @@ class LM:
         token-by-token ``decode`` loop over the prompt (quadratic in
         prompt length, and meaningless to measure prefill latency on).
         """
-        h = self._embed(params, tokens, None)
+        h = emb = self._embed(params, tokens, None)
         s = tokens.shape[1]
         if self.spec.positional == "learned":
             pe = jax.lax.dynamic_slice_in_dim(
                 params["pos_embed"], pos_offset, s, axis=0)
             h = h + pe[None].astype(h.dtype)
-        new_cache: Dict[str, Any] = {}
-        shared_idx = 0
-        for seg in self.segments:
-            if seg.kind == "shared":
-                key = f"shared_{shared_idx}"
-                h, new_cache[key] = self._layer_prefill(
-                    seg.spec, params["shared"], cache[key], h, pos_offset)
-                shared_idx += 1
-                continue
 
-            def body(carry, inp, _seg=seg):
-                lp, lc = inp
-                out, nc = self._layer_prefill(_seg.spec, lp, lc, carry, pos_offset)
-                return out, nc
+        def call(lc):
+            return lambda key, sub, inner, x: _sub_prefill(sub, inner, x, lc[key], pos_offset)
 
-            if seg.count == 1:
-                take0 = lambda t: jax.tree_util.tree_map(lambda x: x[0], t)
-                h, nc = body(h, (take0(params[seg.name]), take0(cache[seg.name])))
-                new_cache[seg.name] = jax.tree_util.tree_map(lambda x: x[None], nc)
-            elif not self.spec.scan_layers:
-                takei = lambda t, i: jax.tree_util.tree_map(lambda x: x[i], t)
-                ncs = []
-                for i in range(seg.count):
-                    h, nc = body(h, (takei(params[seg.name], i), takei(cache[seg.name], i)))
-                    ncs.append(nc)
-                new_cache[seg.name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ncs)
-            else:
-                h, new_cache[seg.name] = jax.lax.scan(
-                    body, h, (params[seg.name], cache[seg.name])
-                )
-            h = constrain(h, ("batch", None, None))
+        h, new_cache = self._walk(params, cache, h, call, emb)
         return self._head(params, h), new_cache
 
     def decode(self, params, cache, tokens, pos):
@@ -499,7 +556,7 @@ class LM:
 
         Returns (logits (B, 1, vocab), new_cache).
         """
-        h = self._embed(params, tokens, None)
+        h = emb = self._embed(params, tokens, None)
         pos = jnp.asarray(pos, jnp.int32)
         if self.spec.positional == "learned":
             if pos.ndim == 1:
@@ -507,41 +564,9 @@ class LM:
             else:
                 pe = jax.lax.dynamic_slice_in_dim(params["pos_embed"], pos, 1, axis=0)[None]
             h = h + pe.astype(h.dtype)
-        new_cache: Dict[str, Any] = {}
-        shared_idx = 0
-        for seg in self.segments:
-            if seg.kind == "shared":
-                key = f"shared_{shared_idx}"
-                h, new_cache[key] = self._layer_decode(seg.spec, params["shared"], cache[key], h, pos)
-                shared_idx += 1
-                continue
 
-            def body(carry, inp, _seg=seg):
-                lp, lc = inp
-                out, nc = self._layer_decode(_seg.spec, lp, lc, carry, pos)
-                return out, nc
+        def call(lc):
+            return lambda key, sub, inner, x: _sub_decode(sub, inner, x, lc[key], pos)
 
-            if seg.count == 1:
-                take0 = lambda t: jax.tree_util.tree_map(lambda x: x[0], t)
-                h, nc = body(h, (take0(params[seg.name]), take0(cache[seg.name])))
-                new_cache[seg.name] = jax.tree_util.tree_map(lambda x: x[None], nc)
-            elif not self.spec.scan_layers:
-                takei = lambda t, i: jax.tree_util.tree_map(lambda x: x[i], t)
-                ncs = []
-                for i in range(seg.count):
-                    h, nc = body(h, (takei(params[seg.name], i), takei(cache[seg.name], i)))
-                    ncs.append(nc)
-                new_cache[seg.name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ncs)
-            else:
-                # streamed weights are closed over, not sliced as xs
-                rest, streamed = _split_streamed(seg.spec, params[seg.name])
-
-                def layer_body(carry, inp, _body=body, _streamed=streamed):
-                    layer, lp, lc = inp
-                    return _body(carry, (_with_layer(lp, _streamed, layer), lc))
-
-                h, new_cache[seg.name] = jax.lax.scan(
-                    layer_body, h,
-                    (jnp.arange(seg.count, dtype=jnp.int32), rest, cache[seg.name]))
-            h = constrain(h, ("batch", None, None))
+        h, new_cache = self._walk(params, cache, h, call, emb, stream=True)
         return self._head(params, h), new_cache

@@ -4,7 +4,9 @@ A model is: embedding -> [LayerSpec, ...] -> final norm -> LM head.
 Each LayerSpec is a tuple of residual *sub-blocks* (pre-norm residual:
 ``h = h + f(norm(h))``).  A standard transformer layer is
 ``(attention, mlp)``; a Mamba2 layer is ``(mamba2,)``; an xLSTM layer is
-``(mlstm,)`` or ``(slstm,)``; a DBRX layer is ``(attention, moe)``.
+``(mlstm,)`` or ``(slstm,)``; a DBRX layer is ``(attention, moe)``.  A
+*hybrid* layer (zamba2) first runs one of the model's weight-tied
+:class:`SharedBlocks`, whose output joins its first sub-block's input.
 
 The same IR is produced both by the hand-written architecture configs
 (`repro/configs/*.py`) and by the NAS ModelBuilder when the search space
@@ -45,7 +47,30 @@ class SubBlock:
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     subs: Tuple[SubBlock, ...]
-    shared: bool = False  # weight-tied to the model's shared block (zamba2)
+    # runs a shared block first (ModelSpec.shared); never stacked with
+    # another layer, since each invocation picks its own block
+    hybrid: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class SharedBlocks:
+    """zamba2's weight-tied transformer blocks (arXiv:2411.15242).
+
+    The i-th hybrid layer runs block ``i % n`` on ``[h; e]``, the hidden
+    state beside the embedding output (``2 d_model`` wide), with no
+    residual inside: ``T = mlp(norm(attention(norm([h; e]))))``.  The
+    MLP adds the invocation's own rank-``adapter_rank`` adapter to its
+    gate and up products, and the invocation's own ``linear``
+    (``d_model x d_model``) carries ``T`` into the layer, whose first
+    sub-block ``f`` then gives ``h + f(norm(h + linear(T)))``.
+    """
+
+    layer: LayerSpec  # (attention from 2 d_model to d_model, gated mlp)
+    n: int
+    adapter_rank: int
+
+    def __post_init__(self):
+        assert [s.kind for s in self.layer.subs] == ["attention", "mlp"], self.layer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +80,7 @@ class ModelSpec:
     vocab: int
     layers: Tuple[LayerSpec, ...]
     norm: str = "rmsnorm"
+    norm_eps: Optional[float] = None  # None: the norm's own default
     tie_embeddings: bool = False
     embed_scale: bool = False  # gemma-style sqrt(d_model) embedding scaling
     positional: str = "rope"  # "rope" | "learned" | "none"
@@ -76,6 +102,7 @@ class ModelSpec:
     # production).  False: Python-unrolled layers — used by the dry-run cost
     # lowering because XLA's HloCostAnalysis counts while bodies once.
     scan_layers: bool = True
+    shared: Optional[SharedBlocks] = None  # run by the hybrid layers
 
     @property
     def n_layers(self) -> int:
@@ -84,7 +111,8 @@ class ModelSpec:
     def is_subquadratic(self) -> bool:
         """True when decode state is O(1) in context (SSM/recurrent archs,
         possibly with sliding-window attention)."""
-        for layer in self.layers:
+        shared = (self.shared.layer,) if self.shared else ()
+        for layer in self.layers + shared:
             for sub in layer.subs:
                 if sub.kind == "attention" and sub.cfg.window is None:
                     return False

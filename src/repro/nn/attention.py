@@ -42,6 +42,7 @@ class AttentionConfig:
     window: Optional[int] = None  # sliding-window size (None = full)
     impl: str = "xla"  # "xla" | "xla_chunked" | "pallas"
     softmax_scale: Optional[float] = None
+    d_out: Optional[int] = None  # output width, where not d_model (zamba2)
     # cost-variant accounting: unroll the chunked-attention KV scan so
     # HloCostAnalysis sees every chunk (see launch/dryrun.py)
     scan_unroll: bool = False
@@ -53,6 +54,10 @@ class AttentionConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head if self.d_head is not None else self.d_model // self.n_heads
+
+    @property
+    def out_dim(self) -> int:
+        return self.d_out if self.d_out is not None else self.d_model
 
     @property
     def group(self) -> int:
@@ -81,7 +86,7 @@ def attention_init(cfg: AttentionConfig, key, dtype=jnp.float32):
         "wq": P(init.scaled_normal(kq, (cfg.d_model, cfg.n_heads * dh), dtype), ("embed", "heads")),
         "wk": P(init.scaled_normal(kk, (cfg.d_model, cfg.n_kv_heads * dh), dtype), ("embed", "kv_heads")),
         "wv": P(init.scaled_normal(kv, (cfg.d_model, cfg.n_kv_heads * dh), dtype), ("embed", "kv_heads")),
-        "wo": P(init.scaled_normal(ko, (cfg.n_heads * dh, cfg.d_model), dtype, fan_in=cfg.n_heads * dh), ("heads", "embed")),
+        "wo": P(init.scaled_normal(ko, (cfg.n_heads * dh, cfg.out_dim), dtype, fan_in=cfg.n_heads * dh), ("heads", "embed")),
     }
     if cfg.use_bias:
         params["bq"] = P(jnp.zeros((cfg.n_heads * dh,), dtype), ("heads",))

@@ -12,7 +12,12 @@ and reads the copy back.  So decode keeps the weights that
 :func:`repro.kernels.ops.decode_matmul`, which rounds each tile in VMEM
 as it reads it.  Elsewhere it slices the layer out and multiplies as
 for an array: the CPU's f32 dot is a full f32 product, which the
-kernel's rounding would change.
+kernel's rounding would change.  Of a weight whose width is not
+lane-aligned the kernel takes the leading whole tiles of the default
+schedule's width (zamba2's Mamba2 ``in_proj``: 10,240 of 10,448
+columns, its z and x) and XLA the rest; the TPU lays such a stack out
+with its contracted axis minor, so the kernel reads it as ``(L, N, K)``
+(a view, not a copy).
 """
 from __future__ import annotations
 
@@ -21,7 +26,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.schedule import LANES
+from repro.kernels.schedule import LANES, default_schedule
 
 # default matmul precisions that round f32 operands to bf16 in one pass
 _ONE_PASS = (None, "default", "fastest", "bfloat16")
@@ -34,13 +39,24 @@ class LayerWeight(NamedTuple):
     layer: jax.Array  # int32 scalar
 
 
+def kernel_columns(n: int) -> int:
+    """The leading columns of an ``n``-wide weight that the kernel
+    multiplies: all where ``n`` is lane-aligned, else as many whole tiles
+    of the default schedule's width as fit."""
+    if n % LANES == 0:
+        return n
+    tile = default_schedule("decode_matmul").block_n
+    return n - n % tile
+
+
 def streams(w) -> bool:
     """Whether decode reads stacked weight ``w`` a layer at a time: an
-    f32 ``(L, K, N)`` stack with K and N lane-aligned, under a precision
-    that rounds the operands to bf16 (bf16 weights have nothing to
-    round; at a higher precision the kernel would change the product)."""
+    f32 ``(L, K, N)`` stack with K lane-aligned and kernel columns in N,
+    under a precision that rounds the operands to bf16 (bf16 weights
+    have nothing to round; at a higher precision the kernel would change
+    the product)."""
     return (getattr(w, "dtype", None) == jnp.float32 and w.ndim == 3
-            and w.shape[1] % LANES == 0 and w.shape[2] % LANES == 0
+            and w.shape[1] % LANES == 0 and kernel_columns(w.shape[2]) > 0
             and jax.config.jax_default_matmul_precision in _ONE_PASS)
 
 
@@ -66,7 +82,15 @@ def dense(x, w):
         from repro.kernels import ops as kops
 
         b, s, k = x.shape
-        y = kops.decode_matmul(x.reshape(b * s, k), w.stack, w.layer, platform="tpu")
-        return y.reshape(b, s, -1)
+        width = w.stack.shape[2]
+        n = kernel_columns(width)
+        if n == width:
+            y = kops.decode_matmul(x.reshape(b * s, k), w.stack, w.layer, platform="tpu")
+            return y.reshape(b, s, -1)
+        y = kops.decode_matmul(x.reshape(b * s, k), jnp.swapaxes(w.stack, 1, 2), w.layer,
+                               n=n, k_minor=True, platform="tpu").reshape(b, s, -1)
+        # the rest of the layer's columns alone, not the whole layer
+        rest = jax.lax.dynamic_slice(w.stack, (w.layer, 0, n), (1, k, width - n))[0]
+        return jnp.concatenate([y, _einsum(x, rest)], axis=-1)
 
     return jax.lax.platform_dependent(x, w, tpu=kernel, default=_einsum)

@@ -1,7 +1,9 @@
-"""Feed-forward blocks: SwiGLU / GELU / squared-ReLU / ReLU variants."""
+"""Feed-forward blocks: SwiGLU / GELU / squared-ReLU / ReLU variants,
+and a low-rank adapter on a gated block's gate and up products."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +20,8 @@ def squared_relu(x):
 
 ACTIVATIONS = {
     "silu": jax.nn.silu,
-    "gelu": jax.nn.gelu,
+    "gelu": jax.nn.gelu,  # tanh approximation
+    "gelu_exact": functools.partial(jax.nn.gelu, approximate=False),  # erf
     "relu": jax.nn.relu,
     "squared_relu": squared_relu,
     "identity": lambda x: x,
@@ -54,14 +57,27 @@ def mlp_init(cfg: MLPConfig, key, dtype=jnp.float32):
     return params
 
 
-def mlp_apply(params, cfg: MLPConfig, x):
+def adapter_init(cfg: MLPConfig, rank: int, key, dtype=jnp.float32):
+    """A rank-``rank`` adapter of a gated block: ``x @ lora_a @ lora_b``
+    adds to the gate (first ``d_ff`` columns) and up products."""
+    ka, kb = jax.random.split(key)
+    return {
+        "lora_a": P(init.scaled_normal(ka, (cfg.d_model, rank), dtype), ("embed", None)),
+        "lora_b": P(init.scaled_normal(kb, (rank, 2 * cfg.d_ff), dtype, fan_in=rank), (None, "mlp")),
+    }
+
+
+def mlp_apply(params, cfg: MLPConfig, x, adapter=None):
     act = ACTIVATIONS[cfg.activation]
     up = dense(x, params["w_up"])
     if cfg.use_bias:
         up = up + params["b_up"]
     if cfg.gated:
-        gate = act(dense(x, params["w_gate"]))
-        h = gate * up
+        gate = dense(x, params["w_gate"])
+        if adapter is not None:
+            low = dense(dense(x, adapter["lora_a"]), adapter["lora_b"])
+            gate, up = gate + low[..., : cfg.d_ff], up + low[..., cfg.d_ff :]
+        h = act(gate) * up
     else:
         h = act(up)
     out = dense(h, params["w_down"])

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.nn import initializers as init
+from repro.nn.linear import dense
 from repro.nn.types import P as Param
 
 
@@ -36,6 +37,7 @@ class Mamba2Config:
     conv_width: int = 4
     chunk: int = 128
     impl: str = "xla"  # "xla" | "pallas"
+    norm_eps: float = 1e-6  # of the gated RMSNorm before out_proj
 
     @property
     def d_inner(self) -> int:
@@ -44,6 +46,16 @@ class Mamba2Config:
     @property
     def n_heads(self) -> int:
         return self.d_inner // self.d_head
+
+
+# the weights multiplied by ``nn.linear.dense``, which the decode of a
+# layer scan may hand in a layer at a time (``nn/linear.py``)
+PROJECTIONS = ("in_proj", "out_proj")
+
+# the convolution and the state's own products run in float32 whatever
+# the default matmul precision, as Mamba2's kernels keep the state; they
+# are a small share of a layer's work
+STATE_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def mamba2_init(cfg: Mamba2Config, key, dtype=jnp.float32):
@@ -66,21 +78,18 @@ def mamba2_init(cfg: Mamba2Config, key, dtype=jnp.float32):
 def causal_conv1d(x, w, b, state=None):
     """Depthwise causal conv.  x: (B,L,C), w: (W,C).
 
-    When ``state`` (B, W-1, C) is given, performs one-step decode and also
-    returns the updated state.
+    ``state`` (B, W-1, C) holds the inputs before ``x`` (zeros when not
+    given); with it, also returns the last W-1 inputs, the state after
+    ``x`` (one decode step, or a prompt from the cache).
     """
-    width = w.shape[0]
-    if state is not None:
-        window = jnp.concatenate([state, x], axis=1)  # (B, W, C)
-        y = jnp.einsum("bwc,wc->bc", window, w) + b
-        return y[:, None, :], window[:, 1:, :]
-    pad = jnp.zeros(x.shape[:1] + (width - 1,) + x.shape[2:], x.dtype)
-    xp = jnp.concatenate([pad, x], axis=1)
+    width, length = w.shape[0], x.shape[1]
+    prev = state if state is not None else jnp.zeros(
+        x.shape[:1] + (width - 1,) + x.shape[2:], x.dtype)
+    xp = jnp.concatenate([prev, x], axis=1)
     # (B, L, W, C) windows via stacked slices (W is tiny: 4).
-    windows = jnp.stack(
-        [xp[:, i : i + x.shape[1]] for i in range(width)], axis=2
-    )
-    return jnp.einsum("blwc,wc->blc", windows, w) + b
+    windows = jnp.stack([xp[:, i : i + length] for i in range(width)], axis=2)
+    y = jnp.einsum("blwc,wc->blc", windows, w, precision=STATE_PRECISION) + b
+    return y if state is None else (y, xp[:, length:])
 
 
 def _segsum_cumsum(a):
@@ -88,10 +97,11 @@ def _segsum_cumsum(a):
     return jnp.cumsum(a, axis=-2)
 
 
-def ssd_chunked(x, dt, A, B_, C_, chunk):
+def ssd_chunked(x, dt, A, B_, C_, chunk, initial_state=None):
     """Chunked SSD scan.  Shapes per module docstring; returns (y, final_state).
 
-    y: (B, L, H, P);  final_state: (B, H, N, P).
+    y: (B, L, H, P);  final_state: (B, H, N, P).  ``initial_state``
+    (B, H, N, P) is the state before the first step (zeros by default).
     """
     b, l, h, p = x.shape
     g, n = B_.shape[2], B_.shape[3]
@@ -109,18 +119,21 @@ def ssd_chunked(x, dt, A, B_, C_, chunk):
     total = cs[:, :, -1]  # (b,nc,h)
 
     # Intra-chunk: att[i,j] = (C_i . B_j) exp(cs_i - cs_j) dt_j for j <= i.
-    cb = jnp.einsum("bcqhn,bckhn->bchqk", Cc, Bc).astype(jnp.float32)
+    cb = jnp.einsum("bcqhn,bckhn->bchqk", Cc, Bc,
+                    precision=STATE_PRECISION).astype(jnp.float32)
     cs_i = cs.transpose(0, 1, 3, 2)[:, :, :, :, None]  # (b,nc,h,q_i,1)
     cs_j = cs.transpose(0, 1, 3, 2)[:, :, :, None, :]  # (b,nc,h,1,q_j)
     tri = jnp.tril(jnp.ones((q, q), bool))[None, None, None]
     # Mask in log-space BEFORE exp so j>i never overflows.
     decay = jnp.exp(jnp.where(tri, cs_i - cs_j, -jnp.inf))  # (b,nc,h,q_i,q_j)
     att = cb * decay * dtc.transpose(0, 1, 3, 2)[:, :, :, None, :]
-    y_intra = jnp.einsum("bchqk,bckhp->bcqhp", att.astype(x.dtype), xc)
+    y_intra = jnp.einsum("bchqk,bckhp->bcqhp", att.astype(x.dtype), xc,
+                         precision=STATE_PRECISION)
 
     # Chunk states: S_c = sum_j exp(total - cs_j) dt_j B_j x_j  -> (b,nc,h,n,p)
     w_state = jnp.exp(total[:, :, None, :] - cs) * dtc  # (b,nc,q,h)
-    s_chunk = jnp.einsum("bcqhn,bcqh,bcqhp->bchnp", Bc.astype(jnp.float32), w_state, xc.astype(jnp.float32))
+    s_chunk = jnp.einsum("bcqhn,bcqh,bcqhp->bchnp", Bc.astype(jnp.float32), w_state,
+                         xc.astype(jnp.float32), precision=STATE_PRECISION)
 
     # Inter-chunk recurrence over nc.
     def step(carry, inp):
@@ -129,7 +142,8 @@ def ssd_chunked(x, dt, A, B_, C_, chunk):
         s_next = jnp.exp(tot_c)[:, :, None, None] * s_prev + s_c
         return s_next, s_prev
 
-    init_s = jnp.zeros((b, h, n, p), jnp.float32)
+    init_s = (jnp.zeros((b, h, n, p), jnp.float32) if initial_state is None
+              else initial_state.astype(jnp.float32))
     final_state, s_carry = jax.lax.scan(
         step,
         init_s,
@@ -137,7 +151,8 @@ def ssd_chunked(x, dt, A, B_, C_, chunk):
     )
     s_carry = s_carry.transpose(1, 0, 2, 3, 4)  # (b,nc,h,n,p): state entering chunk c
 
-    y_inter = jnp.einsum("bcqhn,bchnp->bcqhp", (Cc.astype(jnp.float32) * jnp.exp(cs)[..., None]), s_carry)
+    y_inter = jnp.einsum("bcqhn,bchnp->bcqhp", (Cc.astype(jnp.float32) * jnp.exp(cs)[..., None]),
+                         s_carry, precision=STATE_PRECISION)
     y = (y_intra.astype(jnp.float32) + y_inter).reshape(b, l, h, p)
     return y.astype(x.dtype), final_state
 
@@ -151,9 +166,10 @@ def ssd_recurrent_step(state, x_t, dt_t, A, B_t, C_t):
     Ch = jnp.repeat(C_t, rep, axis=1).astype(jnp.float32)
     dtf = dt_t.astype(jnp.float32)
     da = jnp.exp(dtf * A[None, :])  # (B,H)
-    upd = jnp.einsum("bhn,bh,bhp->bhnp", Bh, dtf, x_t.astype(jnp.float32))
+    upd = jnp.einsum("bhn,bh,bhp->bhnp", Bh, dtf, x_t.astype(jnp.float32),
+                     precision=STATE_PRECISION)
     new_state = da[:, :, None, None] * state + upd
-    y = jnp.einsum("bhn,bhnp->bhp", Ch, new_state)
+    y = jnp.einsum("bhn,bhnp->bhp", Ch, new_state, precision=STATE_PRECISION)
     return y.astype(x_t.dtype), new_state
 
 
@@ -165,19 +181,34 @@ def _split_proj(cfg: Mamba2Config, zxbcdt):
     return z, xbc, dt_raw
 
 
-def _gated_norm(y, z, scale, eps=1e-6):
+def _gated_norm(cfg: Mamba2Config, y, z, scale):
+    """RMSNorm of ``y * silu(z)`` over each group's ``d_inner / n_groups``
+    channels."""
     yf = (y * jax.nn.silu(z.astype(jnp.float32))).astype(jnp.float32)
-    var = jnp.mean(jnp.square(yf), axis=-1, keepdims=True)
-    return (yf * (var + eps) ** -0.5 * scale.astype(jnp.float32)).astype(y.dtype)
+    grouped = yf.reshape(yf.shape[:-1] + (cfg.n_groups, -1))
+    var = jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
+    yf = (grouped * (var + cfg.norm_eps) ** -0.5).reshape(yf.shape)
+    return (yf * scale.astype(jnp.float32)).astype(y.dtype)
 
 
-def mamba2_apply(params, cfg: Mamba2Config, x):
-    """Full-sequence forward.  x: (B, L, d_model) -> (B, L, d_model)."""
+def mamba2_apply(params, cfg: Mamba2Config, x, cache=None):
+    """Full-sequence forward.  x: (B, L, d_model) -> (B, L, d_model).
+
+    With ``cache`` (:func:`init_ssm_cache`: the convolution window and
+    state to continue from), returns (y, the cache after the last
+    position): a prompt's prefill, the chunked scan handing back its
+    final state.
+    """
     b, l, _ = x.shape
     d_in, gn = cfg.d_inner, cfg.n_groups * cfg.d_state
-    zxbcdt = jnp.einsum("bld,dk->blk", x, params["in_proj"])
+    zxbcdt = dense(x, params["in_proj"])
     z, xbc, dt_raw = _split_proj(cfg, zxbcdt)
-    xbc = jax.nn.silu(causal_conv1d(xbc, params["conv_w"], params["conv_b"]))
+    if cache is None:
+        xbc = causal_conv1d(xbc, params["conv_w"], params["conv_b"])
+    else:
+        xbc, conv_state = causal_conv1d(xbc, params["conv_w"], params["conv_b"],
+                                        state=cache["conv"].astype(xbc.dtype))
+    xbc = jax.nn.silu(xbc)
     xs = xbc[..., :d_in].reshape(b, l, cfg.n_heads, cfg.d_head)
     B_ = xbc[..., d_in : d_in + gn].reshape(b, l, cfg.n_groups, cfg.d_state)
     C_ = xbc[..., d_in + gn :].reshape(b, l, cfg.n_groups, cfg.d_state)
@@ -186,16 +217,20 @@ def mamba2_apply(params, cfg: Mamba2Config, x):
     chunk = min(cfg.chunk, l)
     while l % chunk:
         chunk -= 1
-    if cfg.impl == "pallas":
+    if cfg.impl == "pallas" and cache is None:
         from repro.kernels import ops as kops
 
         y, _ = kops.ssm_scan(xs, dt, A, B_, C_, chunk=chunk)
     else:
-        y, _ = ssd_chunked(xs, dt, A, B_, C_, chunk)
+        y, state = ssd_chunked(xs, dt, A, B_, C_, chunk,
+                               initial_state=None if cache is None else cache["state"])
     y = (y + xs * params["D"][None, None, :, None]).astype(x.dtype)
     y = y.reshape(b, l, d_in)
-    y = _gated_norm(y, z, params["norm_scale"])
-    return jnp.einsum("bld,dk->blk", y, params["out_proj"])
+    y = _gated_norm(cfg, y, z, params["norm_scale"])
+    out = dense(y, params["out_proj"])
+    if cache is None:
+        return out
+    return out, {"conv": conv_state.astype(cache["conv"].dtype), "state": state}
 
 
 def init_ssm_cache(cfg: Mamba2Config, batch, dtype=jnp.float32):
@@ -210,7 +245,7 @@ def mamba2_decode(params, cfg: Mamba2Config, x, cache):
     """One-token decode.  x: (B, 1, d_model)."""
     b = x.shape[0]
     d_in, gn = cfg.d_inner, cfg.n_groups * cfg.d_state
-    zxbcdt = jnp.einsum("bld,dk->blk", x, params["in_proj"])
+    zxbcdt = dense(x, params["in_proj"])
     z, xbc, dt_raw = _split_proj(cfg, zxbcdt)
     xbc_t, conv_state = causal_conv1d(
         xbc, params["conv_w"], params["conv_b"], state=cache["conv"].astype(xbc.dtype)
@@ -226,6 +261,6 @@ def mamba2_decode(params, cfg: Mamba2Config, x, cache):
     )
     y_t = (y_t + x_t * params["D"][None, :, None]).astype(x.dtype)
     y = y_t.reshape(b, 1, d_in)
-    y = _gated_norm(y, z, params["norm_scale"])
-    out = jnp.einsum("bld,dk->blk", y, params["out_proj"])
+    y = _gated_norm(cfg, y, z, params["norm_scale"])
+    out = dense(y, params["out_proj"])
     return out, {"conv": conv_state.astype(cache["conv"].dtype), "state": state}

@@ -9,7 +9,6 @@ only; on the CPU the layer is sliced and multiplied as before, which
 these tests hold to the bit.  One test lowers the TPU branch on the
 CPU, with the kernel in the Pallas interpreter.
 """
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch
-from repro.configs.zamba2_2_7b import _layers as zamba2_layers
+from repro.configs.zamba2_2_7b import _spec as zamba2_spec
 from repro.hwgen.autotune import discover_kernel_calls
 from repro.kernels import ops as kops
 from repro.kernels import schedule as ksched
@@ -64,8 +63,14 @@ def xla_only(monkeypatch):
     return use
 
 
-@pytest.mark.parametrize("spec", [ALIGNED, get_arch("qwen3-1.7b").smoke_spec_fn()],
-                         ids=["aligned", "qwen3-smoke"])
+# zamba2 at widths where its Mamba2 in_proj (2,096 wide) gives the
+# kernel 2,048 columns, read K-minor, and XLA the other 48
+HYBRID = zamba2_spec("zamba2-512", 512, 4, (2,), n_heads=4, d_ff=1024, d_state=16,
+                     d_head_ssm=64, adapter_rank=128, vocab=256, chunk=8)
+
+
+@pytest.mark.parametrize("spec", [ALIGNED, get_arch("qwen3-1.7b").smoke_spec_fn(), HYBRID],
+                         ids=["aligned", "qwen3-smoke", "zamba2-512"])
 def test_cpu_decode_is_bit_identical_to_xla_path(spec, xla_only):
     model = LM(spec)
     args = _decode_args(model, _params(spec))
@@ -121,15 +126,18 @@ def test_kernel_engages_for_stacked_f32_decode_only():
 
 
 def test_shared_segment_keeps_the_xla_path():
-    """zamba2's weight-shared attention block holds one (unstacked)
-    parameter set: nothing to stream, even at lane-aligned widths."""
-    base = get_arch("zamba2-2.7b").smoke_spec_fn()
-    spec = dataclasses.replace(
-        base, d_model=128,
-        layers=zamba2_layers(128, 1, 256, 16, 32, 4, 2, None, smoke=True))
-    assert any(layer.shared for layer in spec.layers)
+    """zamba2's weight-shared blocks (one unstacked parameter set each)
+    and its hybrid layers (each a segment of one) no longer keep XLA's
+    path: at lane-aligned widths decode reads their f32 projections
+    through the kernel too, each weight as a stack of one layer."""
+    spec = zamba2_spec("zamba2-aligned", 128, 5, (1, 3), n_heads=2, d_ff=256, d_state=16,
+                       d_head_ssm=32, adapter_rank=128, vocab=256, chunk=8)
+    assert any(layer.hybrid for layer in spec.layers)
     model = LM(spec)
-    assert not _calls(model.decode, _decode_args(model, _params(spec), batch=2))
+    weights = _weights(_calls(model.decode, _decode_args(model, _params(spec), batch=2)))
+    # q, k, v; o; gate, up; down; adapter; linear; Mamba2 out_proj
+    assert weights == [(1, 128, 128), (1, 128, 256), (1, 128, 512), (1, 256, 128),
+                       (1, 256, 256)]
 
 
 def test_qwen3_decode_traces_four_kernel_calls_at_published_widths():
@@ -193,3 +201,30 @@ def test_tpu_branch_matches_xla_path_within_bf16_operands(pos, monkeypatch):
         assert got.shape[0] == len(ALIGNED.layers)
         for layer in range(got.shape[0]):
             close(got[layer], want[layer])
+
+
+def test_tpu_branch_matches_xla_path_for_a_hybrid_model(monkeypatch):
+    """As above for zamba2's streamed weights: Mamba2 stacks, in_proj's
+    leading columns read K-minor beside XLA's rest, a hybrid layer's
+    adapter and linear, and the shared blocks as stacks of one."""
+    model = LM(HYBRID)
+    params, cache, tokens, _ = _decode_args(model, _params(HYBRID))
+    args = (params, cache, tokens, jnp.asarray((0, 3, 9, 5), jnp.int32))
+    want_logits, want_cache = jax.jit(model.decode)(*args)
+
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *operands, tpu, default: tpu(*operands))
+    monkeypatch.setattr(kops, "_interpret", lambda requested, platform=None: True)
+    with ksched.record_kernel_calls({}) as sink:
+        logits, cache = jax.jit(model.decode)(*args)
+    assert {c["effective"].interpret for c in sink.values()} == {True}
+    assert {c["shapes"].get("out") for c in sink.values()} == {None, (4, 2048)}
+
+    def close(got, want):
+        got, want = np.asarray(got), np.asarray(want)
+        np.testing.assert_allclose(got, want, rtol=0, atol=0.025 * np.abs(want).max())
+
+    close(logits, want_logits)
+    for got, want in zip(jax.tree_util.tree_leaves(cache),
+                         jax.tree_util.tree_leaves(want_cache)):
+        close(got, want)

@@ -179,6 +179,19 @@ def test_decode_matmul_matches_reference(proj, layer):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("layer", [0, 1])
+def test_decode_matmul_reads_leading_columns_of_a_k_minor_stack(layer):
+    """zamba2's Mamba2 in_proj at its published widths (2560 x 10448):
+    the kernel takes the first 10240 columns of the stack handed over
+    as (L, N, K)."""
+    ks = jax.random.split(KEY, 2)
+    x = _rand(ks[0], (8, 2560), jnp.float32)
+    w = _stack(ks[1], 2560, 10448)
+    out = ops.decode_matmul(x, jnp.swapaxes(w, 1, 2), jnp.int32(layer), n=10240, k_minor=True)
+    want = ref.decode_matmul_ref(x, w, layer)[:, :10240]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5, rtol=1e-5)
+
+
 @pytest.mark.parametrize("proj", ["kv", "down"])
 def test_decode_matmul_follows_a_scanned_layer_index(proj):
     """The layer index as decode's scan hands it in: traced, one per

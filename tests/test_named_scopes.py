@@ -56,3 +56,43 @@ def test_scopes_change_nothing_but_metadata(arch, entry, monkeypatch):
     assert {"embed", "head"} <= _scopes(scoped)
     assert _scopes(plain).isdisjoint({"embed", "head"})
     assert scoped.as_text() == plain.as_text()
+
+
+# what the compiled program carries outside every scope, apart from its
+# inputs: the scans' bookkeeping (slicing and updating the stacked
+# params and caches, the loop counter), which qwen3 has too
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\S+\s+([\w\-]+)\(.*?op_name=\"([^\"]*)\"", re.M)
+_INPUTS = {"parameter", "bitcast", "get-tuple-element", "constant"}
+_SCOPES = {"attention", "cross_attention", "mlp", "moe", "mamba2", "mlstm", "slstm",
+           "embed", "head"}
+
+
+def _unscoped(arch, entry):
+    """``{(opcode, op_name)}`` of the compiled program's ops in no scope
+    (the attribution ``bench/lib/program_trace.py`` makes), digits
+    dropped."""
+    text = _lowered(get_arch(arch).smoke_spec_fn(), entry).compile().as_text()
+    return {(op, re.sub(r"\d+", "", name)) for op, name in _INSTRUCTION.findall(text)
+            if op not in _INPUTS and not set(re.split(r"[/;]", name)) & _SCOPES}
+
+
+@pytest.mark.parametrize("entry", ["decode", "prefill"])
+def test_no_op_of_a_hybrid_layer_lands_in_other(entry):
+    """zamba2's hybrid layers (the concat, the shared block's norms,
+    attention, MLP and adapter, the invocation's linear, the Mamba2
+    layer with its input) leave nothing outside the sub-block scopes
+    that qwen3's program does not leave there too, apart from more of
+    the same bookkeeping: the stacked output buffers of more layer scans
+    (``broadcast_in_dim`` at the top), and the layout copies the
+    compiler makes of a weight in a layer's body (``closed_call``) or as
+    a one-layer segment's weight is taken off its stack (``squeeze``)."""
+    top = f"jit({entry})"
+    layout = {(op, f"{top}/{where}") for op in ("copy", "transpose", "fusion")
+              for where in ("while/body/closed_call", "squeeze")}
+    bookkeeping = layout | {("broadcast", f"{top}/broadcast_in_dim"),
+                            ("fusion", f"{top}/broadcast_in_dim"),
+                            # the adders of a reduction and of a cumsum
+                            ("add", "reduce_sum"), ("add", "reduce_window_sum")}
+    extra = _unscoped("zamba2-2.7b", entry) - _unscoped("qwen3-1.7b", entry)
+    assert extra <= bookkeeping, extra - bookkeeping

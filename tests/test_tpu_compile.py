@@ -22,9 +22,11 @@ from repro.kernels import schedule as ksched
 F32, BF16 = jnp.float32, jnp.bfloat16
 
 # (kernel, widths, dtype): qwen3-1.7b attention (16 q / 8 kv heads,
-# d=128), zamba2-2.7b Mamba2 (H=80, P=N=64, one group), xlstm-1.3b mLSTM
-# (H=4, P=1024), at a 2048-token sequence
+# d=128), zamba2-2.7b's shared attention (32 heads of 160 from its 5120
+# input) and Mamba2 (H=80, P=N=64, one group), xlstm-1.3b mLSTM (H=4,
+# P=1024), at a 2048-token sequence
 CASES = [
+    ("flash", dict(b=1, s=2048, h=32, kh=32, d=160, window=None), BF16),
     ("flash", dict(b=1, s=2048, h=16, kh=8, d=128, window=None), F32),
     ("flash", dict(b=1, s=2048, h=16, kh=8, d=128, window=None), BF16),
     ("flash", dict(b=1, s=2048, h=16, kh=8, d=128, window=512), BF16),
@@ -40,6 +42,9 @@ CASES = [
     ("decode", dict(m=8, k=2048, n=1024), F32),
     ("decode", dict(m=8, k=2048, n=6144), F32),
     ("decode", dict(m=8, k=6144, n=2048), F32),
+    # zamba2-2.7b's Mamba2 in_proj, K-minor: its first 10240 of 10448
+    # columns
+    ("decode", dict(m=8, k=2560, n=10448, cols=10240), F32),
 ]
 
 
@@ -87,10 +92,12 @@ def _lower(kernel, w, dtype, chip):
         return fn.lower(arg((b, s, h, d)), arg((b, s, kh, d)), arg((b, s, kh, d)))
     if kernel == "decode":
         sched = ksched.default_schedule("decode_matmul")
+        k_minor = "cols" in w
         fn = jax.jit(lambda x, stack, layer: ops.decode_matmul_stacked(
-            x, stack, layer, block_k=sched.block_k, block_n=sched.block_n))
-        return fn.lower(arg((w["m"], w["k"])), arg((28, w["k"], w["n"])),
-                        arg((), jnp.int32))
+            x, stack, layer, block_k=sched.block_k, block_n=sched.block_n,
+            n=w.get("cols"), k_minor=k_minor))
+        stack = (28, w["n"], w["k"]) if k_minor else (28, w["k"], w["n"])
+        return fn.lower(arg((w["m"], w["k"])), arg(stack), arg((), jnp.int32))
     if kernel == "ssm":
         b, l, h, p, n, g = w["b"], w["l"], w["h"], w["p"], w["n"], w["g"]
         fn = jax.jit(lambda *a: ops._ssm_scan_impl(
@@ -146,3 +153,33 @@ def test_qwen3_decode_reads_f32_stacks_without_whole_stack_converts(
     assert text.count("tpu_custom_call") >= 7
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= HOISTED_TEMP_BYTES - 2_500_000_000, temp
+
+
+def test_zamba2_decode_fits_one_chip_without_whole_stack_converts(
+        one_chip, no_persistent_cache):
+    """``LM.decode`` of zamba2-2.7b at its published widths (f32 weights
+    and cache, 8 slots of 513) compiled for a v5e: no weight stack is
+    rounded to bf16 outside the layer loops (every Mamba2 stack, hybrid
+    layer and shared block reads its f32 projections through the
+    kernel), and the program with its arguments and outputs fits the
+    chip's 16 GiB."""
+    import re
+
+    from repro.configs import get_arch
+    from repro.models.lm import LM
+    from repro.nn.types import split
+
+    model = LM(get_arch("zamba2-2.7b").spec_fn())
+    params = jax.eval_shape(lambda: split(model.init(jax.random.PRNGKey(0)))[0])
+    cache = jax.eval_shape(
+        lambda p: model.init_cache(p, 8, 513, dtype=F32), params)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    compiled = jax.jit(model.decode).lower(
+        place(params), place(cache),
+        jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert not re.findall(r"= bf16\[\d+,(2560,10448|5120,2560)\][^ ]* convert\(", text)
+    assert text.count("tpu_custom_call") >= 2 * 10 + 9 * (5 + 2 + 3)
+    assert compiled.memory_analysis().peak_memory_in_bytes <= 16 * 2**30
