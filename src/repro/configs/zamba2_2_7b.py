@@ -19,6 +19,11 @@ Three values follow the Zamba2 family rather than the defaults of
 ``num_mem_blocks`` 2 (default 1), as the family's published configs set
 them; ``use_mem_rope`` false is that default.
 
+Its matmuls run at the ``high`` precision (bf16_3x: three bf16 passes of
+each f32 product).  At one pass, the default, the served tokens of this
+54-layer hybrid on random weights lie as far from the float32 reference
+as those of a model computed wholly in bf16.
+
 Long-context note (DESIGN.md §shape-cell skips): at long_500k the shared
 attention runs with a 4096-token sliding window; the Mamba2 state is the
 O(1) context carrier.
@@ -49,7 +54,7 @@ def _spec(name, d, n_layers, hybrid_ids, *, n_heads, d_ff, d_state, d_head_ssm,
     ))
     return ModelSpec(
         name=name, d_model=d, vocab=vocab, layers=layers, norm="rmsnorm", norm_eps=EPS,
-        positional="none", tie_embeddings=True,
+        positional="none", tie_embeddings=True, matmul_precision="high",
         shared=SharedBlocks(block, n=2, adapter_rank=adapter_rank))
 
 

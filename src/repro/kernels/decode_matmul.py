@@ -10,6 +10,11 @@ reads the copy back.  Here the rounding happens in VMEM, on the tile
 that is being multiplied, so each f32 tile of layer ``l`` leaves HBM
 once and nothing else of the stack is touched.
 
+At ``passes=3`` the product is XLA's ``HIGH`` precision (bf16_3x): each
+f32 operand is split into a bf16 high part and a bf16 remainder, and the
+remainders' products with the other high part are added, which keeps
+about 16 bits of each operand.
+
 Grid: ``(N tiles, K tiles)``, K innermost; the output block stays in
 VMEM across K and accumulates there.  The layer index is a
 scalar-prefetch operand read by the weight's index map.  The tiles may
@@ -32,12 +37,22 @@ _MIB = 1 << 20
 _VMEM_CAP = 100 * _MIB
 
 
-def _kernel(layer_ref, x_ref, w_ref, o_ref, *, k_minor):
+def _kernel(layer_ref, x_ref, w_ref, o_ref, *, k_minor, passes):
     del layer_ref  # read by the index maps only
     contract = (1,) if k_minor else (0,)
-    part = jax.lax.dot_general(
-        x_ref[...].astype(jnp.bfloat16), w_ref[...].astype(jnp.bfloat16),
-        (((1,), contract), ((), ())), preferred_element_type=jnp.float32)
+
+    def dot(a, b):  # of bf16 operands, whatever the default precision
+        return jax.lax.dot_general(a, b, (((1,), contract), ((), ())),
+                                   precision=jax.lax.Precision.DEFAULT,
+                                   preferred_element_type=jnp.float32)
+
+    x, w = x_ref[...], w_ref[...]
+    x_hi, w_hi = x.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    part = dot(x_hi, w_hi)
+    if passes == 3:
+        x_lo = (x.astype(jnp.float32) - x_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        w_lo = (w - w_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        part += dot(x_hi, w_lo) + dot(x_lo, w_hi)
 
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -50,24 +65,25 @@ def _kernel(layer_ref, x_ref, w_ref, o_ref, *, k_minor):
 
 def _vmem_limit(m, block_k, block_n):
     """Double-buffered weight tile, x and output, doubled for the
-    in-kernel bf16 copies and headroom."""
+    in-kernel bf16 parts and headroom."""
     tiles = 2 * block_k * block_n * 4
     rows = 2 * (m * block_k + m * block_n) * 4
     return min(_VMEM_CAP, max(32 * _MIB, 2 * (tiles + rows)))
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "block_n", "n", "k_minor",
-                                             "interpret"))
+                                             "passes", "interpret"))
 def decode_matmul_stacked(x, w, layer, *, block_k, block_n, n=None, k_minor=False,
-                          interpret=False):
+                          passes=1, interpret=False):
     """``x @ bf16(w[layer][:, :n])``, or ``x @ bf16(w[layer][:n]).T`` with
-    ``k_minor``.
+    ``k_minor``; at ``passes=3``, the bf16_3x product instead.
 
     x: (M, K); w: (L, K, N) float32, or (L, N, K) with ``k_minor``;
     layer: int32 scalar; ``n`` at most N, all of it by default.
     ``block_k`` divides K and ``block_n`` divides ``n``.  Returns (M, n)
     float32, accumulated in f32 from bf16 operands.
     """
+    assert passes in (1, 3), passes
     m, kdim = x.shape
     wk, wn = (2, 1) if k_minor else (1, 2)
     n = w.shape[wn] if n is None else n
@@ -87,14 +103,14 @@ def decode_matmul_stacked(x, w, layer, *, block_k, block_n, n=None, k_minor=Fals
         out_specs=pl.BlockSpec((m, block_n), lambda j, k, layer_ref: (0, j)),
     )
     return pl.pallas_call(
-        functools.partial(_kernel, k_minor=k_minor),
+        functools.partial(_kernel, k_minor=k_minor, passes=passes),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(m, block_k, block_n)),
         cost_estimate=pl.CostEstimate(
-            flops=2 * m * kdim * n,
+            flops=2 * passes * m * kdim * n,
             bytes_accessed=kdim * n * 4 + m * n * 4 + m * kdim * x.dtype.itemsize,
             transcendentals=0),
         interpret=interpret,

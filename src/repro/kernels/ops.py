@@ -191,9 +191,10 @@ def mlstm_scan(q, k, v, i_log, f_log, *, chunk=None, schedule=None):
 # decode matmul
 # ---------------------------------------------------------------------------
 
-def decode_matmul(x, w, layer, *, n=None, k_minor=False, schedule=None, platform=None):
+def decode_matmul(x, w, layer, *, n=None, k_minor=False, passes=1, schedule=None,
+                  platform=None):
     """``x @ bf16(w[layer][:, :n])``, or ``x @ bf16(w[layer][:n]).T`` with
-    ``k_minor``.
+    ``k_minor``; ``passes=3`` gives the bf16_3x product (XLA's ``HIGH``).
 
     x: (M, K); w: (L, K, N) float32, or (L, N, K) with ``k_minor``; K
     and ``n`` (all of N by default) multiples of 128; layer: int32
@@ -211,6 +212,9 @@ def decode_matmul(x, w, layer, *, n=None, k_minor=False, schedule=None, platform
     if n != ndim or k_minor:
         shapes["out"] = (x.shape[0], n)
         meta["k_minor"] = k_minor
+    if passes != 1:
+        meta["passes"] = passes
     ksched.note_kernel_call("decode_matmul", requested, eff, shapes=shapes, meta=meta)
     return decode_matmul_stacked(x, w, layer, block_k=eff.block_k, block_n=eff.block_n,
-                                 n=n, k_minor=k_minor, interpret=eff.interpret)
+                                 n=n, k_minor=k_minor, passes=passes,
+                                 interpret=eff.interpret)

@@ -36,7 +36,18 @@ def mlstm_scan_ref(q, k, v, i_log, f_log):
     return h
 
 
-def decode_matmul_ref(x, w, layer):
-    """x: (M, K); w: (L, K, N) -> x @ w[layer], bf16 operands, f32 sums."""
-    return jnp.dot(x.astype(jnp.bfloat16), w[layer].astype(jnp.bfloat16),
-                   preferred_element_type=jnp.float32)
+def decode_matmul_ref(x, w, layer, passes=1):
+    """x: (M, K); w: (L, K, N) -> x @ w[layer], bf16 operands, f32 sums;
+    at ``passes=3`` the bf16_3x product: the high parts' product plus
+    each high part times the other operand's bf16 remainder."""
+    def dot(a, b):
+        return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+    bf16 = jnp.bfloat16
+    x_hi, w_hi = x.astype(bf16), w[layer].astype(bf16)
+    out = dot(x_hi, w_hi)
+    if passes == 3:
+        x_lo = (x - x_hi.astype(jnp.float32)).astype(bf16)
+        w_lo = (w[layer] - w_hi.astype(jnp.float32)).astype(bf16)
+        out = out + dot(x_hi, w_lo) + dot(x_lo, w_hi)
+    return out

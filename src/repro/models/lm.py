@@ -222,6 +222,17 @@ def _take(tree, i):
     return jax.tree_util.tree_map(lambda x: x[i], tree)
 
 
+def _at_spec_precision(fn):
+    """Trace ``fn`` under the spec's default matmul precision."""
+    @functools.wraps(fn)
+    def run(self, *args, **kwargs):
+        if self.spec.matmul_precision is None:
+            return fn(self, *args, **kwargs)
+        with jax.default_matmul_precision(self.spec.matmul_precision):
+            return fn(self, *args, **kwargs)
+    return run
+
+
 class LM:
     def __init__(self, spec: ModelSpec):
         self.spec = spec
@@ -397,6 +408,7 @@ class LM:
         return self._run_segments(self.segments, params, h, positions=positions,
                                   enc_out=enc_out, emb=emb)
 
+    @_at_spec_precision
     def hidden(self, params, tokens, *, prefix_embeds=None, enc_out=None, positions=None):
         """Full-sequence forward -> final normed hidden states (B, S, d).
 
@@ -412,6 +424,7 @@ class LM:
             return params["embed"], True
         return params["head"], False
 
+    @_at_spec_precision
     def apply(self, params, tokens, *, prefix_embeds=None, enc_out=None, positions=None):
         """Full-sequence forward -> logits (B, S, vocab)."""
         h = self._backbone(params, tokens, prefix_embeds, enc_out, positions)
@@ -527,6 +540,7 @@ class LM:
             h = constrain(h, ("batch", None, None))
         return h, new_cache
 
+    @_at_spec_precision
     def prefill(self, params, cache, tokens, pos_offset=0):
         """Batched prefill: the whole prompt in one full-sequence forward
         that also fills the decode caches.  tokens: (B, S) int32.
@@ -549,6 +563,7 @@ class LM:
         h, new_cache = self._walk(params, cache, h, call, emb)
         return self._head(params, h), new_cache
 
+    @_at_spec_precision
     def decode(self, params, cache, tokens, pos):
         """One-step decode.  tokens: (B, 1) int32; pos: scalar int32 or
         an int32 vector (B,) of per-sequence positions (continuous

@@ -103,6 +103,9 @@ class ModelSpec:
     # lowering because XLA's HloCostAnalysis counts while bodies once.
     scan_layers: bool = True
     shared: Optional[SharedBlocks] = None  # run by the hybrid layers
+    # the default matmul precision of apply, hidden, prefill and decode
+    # (``jax.default_matmul_precision``); None leaves JAX's default
+    matmul_precision: Optional[str] = None
 
     @property
     def n_layers(self) -> int:

@@ -17,7 +17,9 @@ lane-aligned the kernel takes the leading whole tiles of the default
 schedule's width (zamba2's Mamba2 ``in_proj``: 10,240 of 10,448
 columns, its z and x) and XLA the rest; the TPU lays such a stack out
 with its contracted axis minor, so the kernel reads it as ``(L, N, K)``
-(a view, not a copy).
+(a view, not a copy).  Under the ``high`` default precision (bf16_3x,
+three passes) the kernel makes the same three passes, so streaming keeps
+the product XLA would give.
 """
 from __future__ import annotations
 
@@ -28,8 +30,9 @@ import jax.numpy as jnp
 
 from repro.kernels.schedule import LANES, default_schedule
 
-# default matmul precisions that round f32 operands to bf16 in one pass
-_ONE_PASS = (None, "default", "fastest", "bfloat16")
+# default matmul precisions by the bf16 passes they make of f32 operands
+_PASSES = {None: 1, "default": 1, "fastest": 1, "bfloat16": 1,
+           "high": 3, "bfloat16_3x": 3, "tensorfloat32": 3}
 
 
 class LayerWeight(NamedTuple):
@@ -52,12 +55,12 @@ def kernel_columns(n: int) -> int:
 def streams(w) -> bool:
     """Whether decode reads stacked weight ``w`` a layer at a time: an
     f32 ``(L, K, N)`` stack with K lane-aligned and kernel columns in N,
-    under a precision that rounds the operands to bf16 (bf16 weights
-    have nothing to round; at a higher precision the kernel would change
-    the product)."""
+    under a precision that the kernel's passes give (bf16 weights have
+    nothing to round; at ``highest`` the kernel would change the
+    product)."""
     return (getattr(w, "dtype", None) == jnp.float32 and w.ndim == 3
             and w.shape[1] % LANES == 0 and kernel_columns(w.shape[2]) > 0
-            and jax.config.jax_default_matmul_precision in _ONE_PASS)
+            and jax.config.jax_default_matmul_precision in _PASSES)
 
 
 def _matrix(w):
@@ -84,11 +87,14 @@ def dense(x, w):
         b, s, k = x.shape
         width = w.stack.shape[2]
         n = kernel_columns(width)
+        passes = _PASSES[jax.config.jax_default_matmul_precision]
         if n == width:
-            y = kops.decode_matmul(x.reshape(b * s, k), w.stack, w.layer, platform="tpu")
+            y = kops.decode_matmul(x.reshape(b * s, k), w.stack, w.layer, passes=passes,
+                                   platform="tpu")
             return y.reshape(b, s, -1)
         y = kops.decode_matmul(x.reshape(b * s, k), jnp.swapaxes(w.stack, 1, 2), w.layer,
-                               n=n, k_minor=True, platform="tpu").reshape(b, s, -1)
+                               n=n, k_minor=True, passes=passes,
+                               platform="tpu").reshape(b, s, -1)
         # the rest of the layer's columns alone, not the whole layer
         rest = jax.lax.dynamic_slice(w.stack, (w.layer, 0, n), (1, k, width - n))[0]
         return jnp.concatenate([y, _einsum(x, rest)], axis=-1)

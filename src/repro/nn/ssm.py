@@ -52,11 +52,6 @@ class Mamba2Config:
 # layer scan may hand in a layer at a time (``nn/linear.py``)
 PROJECTIONS = ("in_proj", "out_proj")
 
-# the convolution and the state's own products run in float32 whatever
-# the default matmul precision, as Mamba2's kernels keep the state; they
-# are a small share of a layer's work
-STATE_PRECISION = jax.lax.Precision.HIGHEST
-
 
 def mamba2_init(cfg: Mamba2Config, key, dtype=jnp.float32):
     d_in = cfg.d_inner
@@ -88,7 +83,7 @@ def causal_conv1d(x, w, b, state=None):
     xp = jnp.concatenate([prev, x], axis=1)
     # (B, L, W, C) windows via stacked slices (W is tiny: 4).
     windows = jnp.stack([xp[:, i : i + length] for i in range(width)], axis=2)
-    y = jnp.einsum("blwc,wc->blc", windows, w, precision=STATE_PRECISION) + b
+    y = jnp.einsum("blwc,wc->blc", windows, w) + b
     return y if state is None else (y, xp[:, length:])
 
 
@@ -119,21 +114,19 @@ def ssd_chunked(x, dt, A, B_, C_, chunk, initial_state=None):
     total = cs[:, :, -1]  # (b,nc,h)
 
     # Intra-chunk: att[i,j] = (C_i . B_j) exp(cs_i - cs_j) dt_j for j <= i.
-    cb = jnp.einsum("bcqhn,bckhn->bchqk", Cc, Bc,
-                    precision=STATE_PRECISION).astype(jnp.float32)
+    cb = jnp.einsum("bcqhn,bckhn->bchqk", Cc, Bc).astype(jnp.float32)
     cs_i = cs.transpose(0, 1, 3, 2)[:, :, :, :, None]  # (b,nc,h,q_i,1)
     cs_j = cs.transpose(0, 1, 3, 2)[:, :, :, None, :]  # (b,nc,h,1,q_j)
     tri = jnp.tril(jnp.ones((q, q), bool))[None, None, None]
     # Mask in log-space BEFORE exp so j>i never overflows.
     decay = jnp.exp(jnp.where(tri, cs_i - cs_j, -jnp.inf))  # (b,nc,h,q_i,q_j)
     att = cb * decay * dtc.transpose(0, 1, 3, 2)[:, :, :, None, :]
-    y_intra = jnp.einsum("bchqk,bckhp->bcqhp", att.astype(x.dtype), xc,
-                         precision=STATE_PRECISION)
+    y_intra = jnp.einsum("bchqk,bckhp->bcqhp", att.astype(x.dtype), xc)
 
     # Chunk states: S_c = sum_j exp(total - cs_j) dt_j B_j x_j  -> (b,nc,h,n,p)
     w_state = jnp.exp(total[:, :, None, :] - cs) * dtc  # (b,nc,q,h)
     s_chunk = jnp.einsum("bcqhn,bcqh,bcqhp->bchnp", Bc.astype(jnp.float32), w_state,
-                         xc.astype(jnp.float32), precision=STATE_PRECISION)
+                         xc.astype(jnp.float32))
 
     # Inter-chunk recurrence over nc.
     def step(carry, inp):
@@ -152,7 +145,7 @@ def ssd_chunked(x, dt, A, B_, C_, chunk, initial_state=None):
     s_carry = s_carry.transpose(1, 0, 2, 3, 4)  # (b,nc,h,n,p): state entering chunk c
 
     y_inter = jnp.einsum("bcqhn,bchnp->bcqhp", (Cc.astype(jnp.float32) * jnp.exp(cs)[..., None]),
-                         s_carry, precision=STATE_PRECISION)
+                         s_carry)
     y = (y_intra.astype(jnp.float32) + y_inter).reshape(b, l, h, p)
     return y.astype(x.dtype), final_state
 
@@ -166,10 +159,9 @@ def ssd_recurrent_step(state, x_t, dt_t, A, B_t, C_t):
     Ch = jnp.repeat(C_t, rep, axis=1).astype(jnp.float32)
     dtf = dt_t.astype(jnp.float32)
     da = jnp.exp(dtf * A[None, :])  # (B,H)
-    upd = jnp.einsum("bhn,bh,bhp->bhnp", Bh, dtf, x_t.astype(jnp.float32),
-                     precision=STATE_PRECISION)
+    upd = jnp.einsum("bhn,bh,bhp->bhnp", Bh, dtf, x_t.astype(jnp.float32))
     new_state = da[:, :, None, None] * state + upd
-    y = jnp.einsum("bhn,bhnp->bhp", Ch, new_state, precision=STATE_PRECISION)
+    y = jnp.einsum("bhn,bhnp->bhp", Ch, new_state)
     return y.astype(x_t.dtype), new_state
 
 

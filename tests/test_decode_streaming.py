@@ -125,11 +125,11 @@ def test_kernel_engages_for_stacked_f32_decode_only():
         assert not _calls(model.decode, args)
 
 
-def test_shared_segment_keeps_the_xla_path():
+def test_shared_and_hybrid_weights_stream_through_the_kernel():
     """zamba2's weight-shared blocks (one unstacked parameter set each)
-    and its hybrid layers (each a segment of one) no longer keep XLA's
-    path: at lane-aligned widths decode reads their f32 projections
-    through the kernel too, each weight as a stack of one layer."""
+    and its hybrid layers (each a segment of one): at lane-aligned
+    widths decode reads their f32 projections through the kernel, each
+    weight as a stack of one layer."""
     spec = zamba2_spec("zamba2-aligned", 128, 5, (1, 3), n_heads=2, d_ff=256, d_state=16,
                        d_head_ssm=32, adapter_rank=128, vocab=256, chunk=8)
     assert any(layer.hybrid for layer in spec.layers)
@@ -138,6 +138,36 @@ def test_shared_segment_keeps_the_xla_path():
     # q, k, v; o; gate, up; down; adapter; linear; Mamba2 out_proj
     assert weights == [(1, 128, 128), (1, 128, 256), (1, 128, 512), (1, 256, 128),
                        (1, 256, 256)]
+
+
+@pytest.mark.parametrize("spec,passes", [(HYBRID, 3), (ALIGNED, None)],
+                         ids=["zamba2-high", "aligned-default"])
+def test_spec_precision_sets_the_kernel_passes(spec, passes):
+    """zamba2's spec sets the ``high`` precision: decode's kernel makes
+    three bf16 passes.  A spec that sets none keeps one."""
+    model = LM(spec)
+    calls = _calls(model.decode, _decode_args(model, _params(spec)))
+    assert calls and {c["meta"].get("passes") for c in calls.values()} == {passes}
+
+
+@pytest.mark.parametrize("arch,want", [("zamba2-2.7b", "HIGH"), ("qwen3-1.7b", "DEFAULT")])
+def test_spec_precision_reaches_every_xla_dot(arch, want):
+    """``apply``, ``prefill`` and ``decode`` trace their dots at the
+    spec's precision; without one, at the default."""
+    model = LM(get_arch(arch).smoke_spec_fn())
+    params = _params(model.spec)
+    cache = model.init_cache(params, 1, 16, dtype=jnp.float32)
+    tokens = jnp.ones((1, 4), jnp.int32)
+    texts = [jax.jit(model.apply).lower(params, tokens).as_text(),
+             jax.jit(model.prefill).lower(params, cache, tokens).as_text(),
+             jax.jit(model.decode).lower(params, cache, tokens[:, :1],
+                                         jnp.zeros((1,), jnp.int32)).as_text()]
+    for text in texts:
+        dots = [line for line in text.splitlines() if "stablehlo.dot_general" in line]
+        assert dots
+        found = {line.split("precision = [")[1].split(",")[0] if "precision = [" in line
+                 else None for line in dots}
+        assert found == {want}
 
 
 def test_qwen3_decode_traces_four_kernel_calls_at_published_widths():

@@ -192,6 +192,24 @@ def test_decode_matmul_reads_leading_columns_of_a_k_minor_stack(layer):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("k_minor", [False, True], ids=["k-major", "k-minor"])
+@pytest.mark.parametrize("proj", ["kv", "down"])
+def test_decode_matmul_three_passes_is_bf16_3x(proj, k_minor):
+    """``passes=3`` is XLA's ``high`` precision: the reference's bf16_3x
+    product, and far nearer the float32 product than one pass."""
+    k, n = QWEN3_DECODE[proj]
+    ks = jax.random.split(KEY, 2)
+    x = _rand(ks[0], (8, k), jnp.float32)
+    w = _stack(ks[1], k, n)
+    stack = jnp.swapaxes(w, 1, 2) if k_minor else w
+    out = np.asarray(ops.decode_matmul(x, stack, jnp.int32(1), k_minor=k_minor, passes=3))
+    want = np.asarray(ref.decode_matmul_ref(x, w, 1, passes=3))
+    np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)
+    exact = np.asarray(jnp.dot(x, w[1], precision=jax.lax.Precision.HIGHEST))
+    one = np.asarray(ref.decode_matmul_ref(x, w, 1))
+    assert np.abs(out - exact).max() * 100 < np.abs(one - exact).max()
+
+
 @pytest.mark.parametrize("proj", ["kv", "down"])
 def test_decode_matmul_follows_a_scanned_layer_index(proj):
     """The layer index as decode's scan hands it in: traced, one per

@@ -45,6 +45,10 @@ CASES = [
     # zamba2-2.7b's Mamba2 in_proj, K-minor: its first 10240 of 10448
     # columns
     ("decode", dict(m=8, k=2560, n=10448, cols=10240), F32),
+    # zamba2-2.7b's MLP gate and its in_proj at its spec's precision:
+    # three bf16 passes
+    ("decode", dict(m=8, k=2560, n=10240, passes=3), F32),
+    ("decode", dict(m=8, k=2560, n=10448, cols=10240, passes=3), F32),
 ]
 
 
@@ -95,7 +99,7 @@ def _lower(kernel, w, dtype, chip):
         k_minor = "cols" in w
         fn = jax.jit(lambda x, stack, layer: ops.decode_matmul_stacked(
             x, stack, layer, block_k=sched.block_k, block_n=sched.block_n,
-            n=w.get("cols"), k_minor=k_minor))
+            n=w.get("cols"), k_minor=k_minor, passes=w.get("passes", 1)))
         stack = (28, w["n"], w["k"]) if k_minor else (28, w["k"], w["n"])
         return fn.lower(arg((w["m"], w["k"])), arg(stack), arg((), jnp.int32))
     if kernel == "ssm":
@@ -161,8 +165,9 @@ def test_zamba2_decode_fits_one_chip_without_whole_stack_converts(
     and cache, 8 slots of 513) compiled for a v5e: no weight stack is
     rounded to bf16 outside the layer loops (every Mamba2 stack, hybrid
     layer and shared block reads its f32 projections through the
-    kernel), and the program with its arguments and outputs fits the
-    chip's 16 GiB."""
+    kernel), the kernel reads in_proj's stacks K-minor as a view (no
+    f32 transpose or copy of them), and the program with its arguments
+    and outputs fits the chip's 16 GiB."""
     import re
 
     from repro.configs import get_arch
@@ -181,5 +186,6 @@ def test_zamba2_decode_fits_one_chip_without_whole_stack_converts(
         jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)).compile()
     text = compiled.as_text()
     assert not re.findall(r"= bf16\[\d+,(2560,10448|5120,2560)\][^ ]* convert\(", text)
+    assert not re.findall(r"= f32\[\d+,(2560,10448|10448,2560)\][^ ]* (transpose|copy)\(", text)
     assert text.count("tpu_custom_call") >= 2 * 10 + 9 * (5 + 2 + 3)
     assert compiled.memory_analysis().peak_memory_in_bytes <= 16 * 2**30
