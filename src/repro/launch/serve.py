@@ -140,9 +140,14 @@ class ServingEngine:
         self._axes_flat = self._batch_axes()
         self.decode = jax.jit(model.decode)
         self._prefill_jit = jax.jit(model.prefill)
-        # a join's merge into its slot, one program: eagerly it was an
-        # op per cache leaf
-        self._merged = jax.jit(self._merge_leaves)
+        # a join's merge into its slot, one program (eagerly, an op per
+        # cache leaf) that writes the slot in place of the batched cache
+        # it is given
+        self._merged = jax.jit(self._merge_leaves, donate_argnums=0)
+        # each row's greedy id at its last position and a finiteness flag,
+        # one program (eagerly, the indexing alone cost milliseconds)
+        self._greedy = jax.jit(lambda logits: (jnp.argmax(logits[:, -1], axis=-1),
+                                               jnp.isfinite(logits[:, -1]).all()))
         self.decode_kernel_calls = self._count_decode_kernel_calls()
         # slot i: None, or dict(req=, pos=, token=, out=[generated tokens])
         self.slots: List[Optional[Dict[str, Any]]] = [None] * self.max_batch
@@ -183,11 +188,10 @@ class ServingEngine:
             self.model.decode, (self.params, self.cache, tokens, pos)))
 
     def _pick(self, logits):
-        """Greedy token ids from ``logits`` (..., vocab), fetched in one
-        transfer with a finiteness flag that feeds ``nonfinite_logits``."""
-        jnp = self.jnp
-        ids, finite = self.jax.device_get(
-            (jnp.argmax(logits, axis=-1), jnp.isfinite(logits).all()))
+        """Greedy token ids at each row's last position of ``logits``
+        (batch, seq, vocab), fetched in one transfer with a finiteness
+        flag of those logits that feeds ``nonfinite_logits``."""
+        ids, finite = self.jax.device_get(self._greedy(logits))
         self.nonfinite_logits += int(not finite)
         return np.asarray(ids)
 
@@ -236,7 +240,7 @@ class ServingEngine:
                 self._merge_slot(single, slot)
             self.prefills += 1
             with span("serve.join.pick", request_id=rid):
-                first = int(self._pick(logits[0, -1]))
+                first = int(self._pick(logits)[0])
             self.slots[slot] = {"req": req, "pos": req.prompt_len,
                                 "token": first, "out": [first]}
 
@@ -256,7 +260,7 @@ class ServingEngine:
                 logits, self.cache = self.decode(self.params, self.cache,
                                                  tokens_d, pos_d)
             with span("serve.step.pick"):
-                nxt = self._pick(logits[:, 0])
+                nxt = self._pick(logits)
             with span("serve.step.bookkeep"):
                 self.steps += 1
                 self.slot_steps += len(active)

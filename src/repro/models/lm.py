@@ -242,6 +242,11 @@ class LM:
         assert not hybrid or spec.shared, "hybrid layers need ModelSpec.shared"
         # the shared block of each hybrid segment: its invocation index mod n
         self.block = {name: f"shared_{i % spec.shared.n}" for i, name in enumerate(hybrid)}
+        # a cache no leaf of which reads the params, as one program per
+        # (batch, max_seq, dtype): eagerly it was an op or two per leaf
+        self._empty_cache = jax.jit(
+            lambda batch, max_seq, dtype: self._build_cache(None, batch, max_seq, None, dtype),
+            static_argnums=(0, 1, 2))
 
     def _norm(self, params, x):
         eps = {} if self.spec.norm_eps is None else {"eps": self.spec.norm_eps}
@@ -445,18 +450,25 @@ class LM:
         return cache
 
     def init_cache(self, params, batch, max_seq, *, enc_out=None, dtype=jnp.bfloat16):
-        """Build the full decode cache pytree (segment-stacked)."""
+        """Build the full decode cache pytree (segment-stacked).  Only
+        cross-attention over ``enc_out`` reads ``params``; without it the
+        cache comes from one compiled program."""
+        if enc_out is None:
+            return self._empty_cache(int(batch), int(max_seq), jnp.dtype(dtype))
+        return self._build_cache(params, batch, max_seq, enc_out, dtype)
+
+    def _build_cache(self, params, batch, max_seq, enc_out, dtype):
         enc_len = enc_out.shape[1] if enc_out is not None else 0
         cache: Dict[str, Any] = {}
         for seg in self.segments:
-            if any(sub.kind == "cross_attention" for sub in seg.spec.subs):
+            if enc_out is not None and any(sub.kind == "cross_attention" for sub in seg.spec.subs):
                 layer_caches = [self._layer_cache(
                     seg.spec, _take(params[seg.name], i), batch, max_seq, enc_len, enc_out, dtype)
                     for i in range(seg.count)]
                 cache[seg.name] = jax.tree_util.tree_map(
                     lambda *xs: jnp.stack(xs), *layer_caches
                 )
-            else:  # only cross-attention reads a layer's params
+            else:
                 c0 = self._layer_cache(seg.spec, None, batch, max_seq, enc_len, enc_out, dtype)
                 cache[seg.name] = jax.tree_util.tree_map(
                     lambda x: jnp.tile(x[None], (seg.count,) + (1,) * x.ndim), c0

@@ -238,6 +238,55 @@ def test_engine_matches_isolated_generation():
         assert by_id[req.id]["tokens"] == alone
 
 
+@pytest.mark.parametrize("name", PREFILL_ARCHS)
+def test_join_programs_match_their_eager_forms(name):
+    """A join's batch-1 cache, one compiled program, is the cache built
+    op by op, and its merge writes the slot in place: the batched cache
+    it was given is donated, the slot holds the batch-1 cache, the
+    others are kept."""
+    spec, model, params = _smoke_model(name)
+    engine = ServingEngine(model, params, max_batch=2, queue_limit=2, max_context=16)
+    fresh = model.init_cache(params, 1, 16, dtype=jnp.float32)
+    with jax.disable_jit():
+        want = model._build_cache(params, 1, 16, None, jnp.float32)
+    assert jax.tree_util.tree_structure(fresh) == jax.tree_util.tree_structure(want)
+    for a, b in zip(jax.tree_util.tree_leaves(fresh), jax.tree_util.tree_leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 5), 0, spec.vocab)
+    _, single = engine._prefill_jit(params, fresh, tokens)
+    # copies: a host view of a buffer would keep it from being donated
+    before = [np.array(x) for x in jax.tree_util.tree_leaves(engine.cache)]
+    old = engine.cache
+    engine._merge_slot(single, 1)
+    assert all(x.is_deleted() for x in jax.tree_util.tree_leaves(old))
+    for b, s, m, from_right in zip(before, jax.tree_util.tree_leaves(single),
+                                   jax.tree_util.tree_leaves(engine.cache), engine._axes_flat):
+        axis = b.ndim - from_right
+        np.testing.assert_array_equal(np.take(np.asarray(m), 1, axis=axis),
+                                      np.take(np.asarray(s), 0, axis=axis))
+        np.testing.assert_array_equal(np.take(np.asarray(m), 0, axis=axis),
+                                      np.take(b, 0, axis=axis))
+
+
+def test_pick_reads_each_rows_last_position():
+    """``_pick`` takes (batch, seq, vocab) logits: the greedy id at each
+    row's last position, and only those logits count as non-finite."""
+    _, model, params = _smoke_model("qwen3-1.7b")
+    engine = ServingEngine(model, params, max_batch=2, queue_limit=2, max_context=16)
+    logits = np.array(jax.random.normal(jax.random.PRNGKey(3), (2, 3, 11)))
+    np.testing.assert_array_equal(engine._pick(jnp.asarray(logits)),
+                                  logits[:, -1].argmax(-1))
+    assert engine.nonfinite_logits == 0
+    logits[:, 0, 4] = np.nan
+    engine._pick(jnp.asarray(logits))
+    assert engine.nonfinite_logits == 0
+    logits[1, -1, 4] = np.inf
+    engine._pick(jnp.asarray(logits))
+    assert engine.nonfinite_logits == 1
+
+
 def test_engine_sheds_and_replays_deterministically():
     spec, model, params = _smoke_model("qwen3-1.7b")
     traffic = TrafficSpec.from_raw({
