@@ -78,7 +78,10 @@ class SpecObjective:
                 KeepRule,
                 OptimizationCriteria,
             )
+            from repro.hwgen.generator import generate_call_count
 
+            # compiles this process made before the run are not the run's
+            generates_before = generate_call_count()
             spec = ExperimentSpec.from_dict(self.spec_dict)
             space = parse_search_space(dict(spec.search_space))
             builder = ModelBuilder(space.input_shape, space.output_dim)
@@ -123,7 +126,7 @@ class SpecObjective:
                           if k[0] == self._key[0] and k != self._key]:
                 del _PROCESS_STATE[stale]
             state = _PROCESS_STATE[self._key] = (
-                spec, space, builder, runner, cache, tuner)
+                spec, space, builder, runner, cache, tuner, generates_before)
         return state
 
     @property
@@ -139,7 +142,7 @@ class SpecObjective:
         :meth:`Explorer.best_model` to hand back the winning network."""
         from repro.core.translate import sample_architecture
 
-        _, space, builder, _, _, _ = self._state()
+        _, space, builder, _, _, _, _ = self._state()
         return builder.build(sample_architecture(space, trial))
 
     def screen_cohort(self, trials):
@@ -151,7 +154,7 @@ class SpecObjective:
         from repro.core.translate import sample_architecture
         from repro.search.parallel import ScreenDecision
 
-        _, space, builder, runner, _, _ = self._state()
+        _, space, builder, runner, _, _, _ = self._state()
         models = []
         for trial in trials:
             arch = sample_architecture(space, trial)
@@ -200,7 +203,7 @@ class SpecObjective:
         from repro.core.translate import sample_architecture
         from repro.hwgen.generator import generate_call_count
 
-        spec, space, builder, runner, cache, tuner = self._state()
+        spec, space, builder, runner, cache, tuner, generates_before = self._state()
         arch = sample_architecture(space, trial)
         model = builder.build(arch)
         trial.set_user_attr("signature", arch.signature())
@@ -213,10 +216,11 @@ class SpecObjective:
             value = runner.evaluate(model, context=context, trial=trial)
         else:
             value = runner.evaluate_multi(model, context=context, trial=trial)
-        # generates: cumulative XLA generator invocations in this process —
-        # the report's funnel aggregates it per pid to count how many
-        # candidates actually paid a compile (screened-out ones never do)
-        worker = {"pid": os.getpid(), "generates": generate_call_count(),
+        # generates: cumulative XLA generator invocations of this run in
+        # this process — the report's funnel aggregates it per pid to count
+        # how many candidates actually paid a compile (screened-out ones
+        # never do)
+        worker = {"pid": os.getpid(), "generates": generate_call_count() - generates_before,
                   **cache.stats.as_dict()}
         if cache.disk is not None:
             worker.update(cache.disk.stats())

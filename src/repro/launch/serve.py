@@ -53,7 +53,10 @@ positions of one K/V cache: every K/V cache of a model holds
 ``max_context`` positions a slot, be it an attention layer's or, in a
 hybrid model, a shared block invocation's.
 ``decode_kernel_calls``, set once when the engine is built, counts the
-distinct kernel calls its decode program runs (0 off a TPU).
+distinct kernel calls its decode program runs (0 off a TPU);
+``decode_cache_donated_bytes``, set with it, the bytes of cache that the
+decode program takes donated and updates in place (on a TPU the whole
+cache, else 0: each step then writes a second one).
 """
 from __future__ import annotations
 
@@ -138,7 +141,11 @@ class ServingEngine:
         self.cache = model.init_cache(params, self.max_batch,
                                       self.max_context, dtype=jnp.float32)
         self._axes_flat = self._batch_axes()
-        self.decode = jax.jit(model.decode)
+        # on a TPU decode takes the cache donated and updates it in place
+        # (``LM.decode``); elsewhere the cache it is given stays valid, for
+        # callers that keep it (the benchmark's CPU fault tests)
+        self.decode = jax.jit(model.decode,
+                              donate_argnums=1 if self._platform() == "tpu" else ())
         self._prefill_jit = jax.jit(model.prefill)
         # a join's merge into its slot, one program (eagerly, an op per
         # cache leaf) that writes the slot in place of the batched cache
@@ -148,7 +155,7 @@ class ServingEngine:
         # one program (eagerly, the indexing alone cost milliseconds)
         self._greedy = jax.jit(lambda logits: (jnp.argmax(logits[:, -1], axis=-1),
                                                jnp.isfinite(logits[:, -1]).all()))
-        self.decode_kernel_calls = self._count_decode_kernel_calls()
+        self.decode_kernel_calls, self.decode_cache_donated_bytes = self._trace_decode()
         # slot i: None, or dict(req=, pos=, token=, out=[generated tokens])
         self.slots: List[Optional[Dict[str, Any]]] = [None] * self.max_batch
         self.completed: List[Dict[str, Any]] = []
@@ -170,22 +177,24 @@ class ServingEngine:
         leaf = self.jax.tree_util.tree_leaves(self.cache)[0]
         return next(iter(leaf.devices())).platform
 
-    def _count_decode_kernel_calls(self) -> int:
-        """Distinct Pallas kernel calls, by shape, in the decode program
-        as it runs: ``LM.decode`` on a TPU multiplies each stack
-        segment's f32 projections by ``decode_matmul``.  0 where every
-        projection takes the XLA path, as on any other platform (where
-        the kernel's branch is traced but not lowered).  One abstract
-        trace, no compile."""
-        from repro.hwgen.autotune import discover_kernel_calls
+    def _trace_decode(self):
+        """One abstract trace of the decode program, no compile (the
+        call reuses it): (distinct Pallas kernel calls in it, by shape,
+        bytes of the arguments it takes donated).  ``LM.decode`` on a TPU
+        multiplies each stack segment's f32 projections by
+        ``decode_matmul``; the calls count 0 where every projection takes
+        the XLA path, as on any other platform (where the kernel's branch
+        is traced but not lowered)."""
+        from repro.kernels import schedule as ksched
 
-        if self._platform() != "tpu":
-            return 0
         jax, jnp = self.jax, self.jnp
         tokens = jax.ShapeDtypeStruct((self.max_batch, 1), jnp.int32)
         pos = jax.ShapeDtypeStruct((self.max_batch,), jnp.int32)
-        return len(discover_kernel_calls(
-            self.model.decode, (self.params, self.cache, tokens, pos)))
+        with ksched.record_kernel_calls({}) as calls:
+            traced = self.decode.trace(self.params, self.cache, tokens, pos)
+        donated = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(traced.args_info) if a.donated)
+        return (len(calls) if self._platform() == "tpu" else 0), donated
 
     def _pick(self, logits):
         """Greedy token ids at each row's last position of ``logits``

@@ -140,9 +140,19 @@ def _sub_prefill(sub: SubBlock, params, x, cache, pos_offset):
 
 
 def _sub_decode(sub: SubBlock, params, x, cache, pos):
-    """Returns (y, new_cache)."""
+    """Returns (y, new_cache).  ``cache`` may be an
+    :class:`attn.LayerCache`, one layer of a stacked cache; the new cache
+    is then the stack.  Attention writes its K/V rows into the stack
+    itself.  Every other kind decodes on the layer's slice; a state it
+    rewrote is written back whole, and a cache it returns as it came
+    (cross-attention's static K/V; mlp and moe hold none) leaves the
+    stack as it was."""
     if sub.kind == "attention":
         return attn.attention_decode(params, sub.cfg, x, cache, pos)
+    if isinstance(cache, attn.LayerCache):
+        own = cache.read()
+        y, new = _sub_decode(sub, params, x, own, pos)
+        return y, cache.stack if new is own else cache.write(new)
     if sub.kind == "cross_attention":
         # cross KV is precomputed and static during decode
         q_only = attn.cross_attention_cached(params, sub.cfg, x, cache)
@@ -508,10 +518,16 @@ class LM:
     def _walk(self, params, cache, h, call, emb, stream=False):
         """Every segment's layers over ``h`` with their caches; ``call(layer
         cache)`` gives the ``call`` that :meth:`_layer` takes.  With
-        ``stream``, each segment is a scan (of one layer, too) that closes
-        over the stacks :func:`linear.streams` accepts and hands each
-        layer a :class:`linear.LayerWeight`, and so are the shared blocks'
-        weights, as stacks of one.  Returns (h, new cache)."""
+        ``stream`` (decode), each segment is a scan (of one layer, too)
+        that closes over the stacks :func:`linear.streams` accepts and
+        hands each layer a :class:`linear.LayerWeight`, and so are the
+        shared blocks' weights, as stacks of one.  The segment's stacked
+        cache rides in the scan's carry beside ``h``, and each layer gets
+        an :class:`attn.LayerCache` view of it per sub-block: the layer
+        writes what it changes (one K/V row per sequence, a recurrent
+        state) into the stack, which a program that takes the cache
+        donated updates in place.  Otherwise each layer's cache is its
+        slice, and the new cache is restacked.  Returns (h, new cache)."""
         new_cache: Dict[str, Any] = {}
         stream = stream and self.spec.scan_layers
         for seg in self.segments:
@@ -531,12 +547,14 @@ class LM:
                 rest, streamed = _split_streamed(seg.spec, params[seg.name])
 
                 def layer_body(carry, inp, _body=body, _streamed=streamed):
-                    layer, lp, lc = inp
-                    return _body(carry, (_with_layer(lp, _streamed, layer), lc))
+                    h, stack = carry
+                    layer, lp = inp
+                    view = {key: attn.LayerCache(c, layer) for key, c in stack.items()}
+                    return _body(h, (_with_layer(lp, _streamed, layer), view)), None
 
-                h, new_cache[seg.name] = jax.lax.scan(
-                    layer_body, h,
-                    (jnp.arange(seg.count, dtype=jnp.int32), rest, cache[seg.name]))
+                (h, new_cache[seg.name]), _ = jax.lax.scan(
+                    layer_body, (h, cache[seg.name]),
+                    (jnp.arange(seg.count, dtype=jnp.int32), rest))
             elif seg.count == 1:
                 h, nc = body(h, (_take(params[seg.name], 0), _take(cache[seg.name], 0)))
                 new_cache[seg.name] = jax.tree_util.tree_map(lambda x: x[None], nc)
@@ -581,7 +599,13 @@ class LM:
         an int32 vector (B,) of per-sequence positions (continuous
         batching: each serving slot decodes at its own depth).
 
-        Returns (logits (B, 1, vocab), new_cache).
+        Returns (logits (B, 1, vocab), new_cache).  Each segment's stacked
+        cache is carried through its layer scan and updated in place: a
+        layer writes one K/V row per sequence, or its recurrent state, and
+        nothing else (:meth:`_walk`).  On a TPU ``ServingEngine`` donates
+        the cache to its decode program, so the step writes into the
+        buffer it was given; a caller that does not donate gets the same
+        answers, with one copy of the cache at the program's boundary.
         """
         h = emb = self._embed(params, tokens, None)
         pos = jnp.asarray(pos, jnp.int32)

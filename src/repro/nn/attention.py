@@ -5,7 +5,9 @@ Three entry points:
   * ``attention_apply``   -- full-sequence (training / prefill / encoder /
                              cross-attention) attention
   * ``attention_decode``  -- single-token decode against a preallocated
-                             KV cache (in-place ``.at[].set`` update)
+                             KV cache, or one layer of a stacked one
+                             (:class:`LayerCache`): writes one K/V row
+                             per sequence
 
 The sequence-mixing math is grouped (no materialized KV repetition): q is
 reshaped to (batch, seq, kv_heads, group, d_head) so the einsum contracts
@@ -15,11 +17,12 @@ level rather than the MHA level.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.schedule import LANES
 from repro.nn import initializers as init
 from repro.nn.linear import dense
 from repro.nn.rope import apply_rope
@@ -267,6 +270,26 @@ def attention_apply(
     return jnp.einsum("bsh,hd->bsd", out, params["wo"])
 
 
+class LayerCache(NamedTuple):
+    """Layer ``layer`` of a segment's stacked cache, not sliced: ``stack``
+    is a dict of ``(L, ...)`` leaves.  Decode's layer scan carries the
+    stack and hands each layer this view, so a layer writes only what it
+    changes into the stack the step updates in place."""
+
+    stack: Dict[str, Any]
+    layer: jax.Array  # int32 scalar
+
+    def read(self):
+        """The layer's own cache, sliced out of the stack."""
+        return {name: jax.lax.dynamic_index_in_dim(leaf, self.layer, keepdims=False)
+                for name, leaf in self.stack.items()}
+
+    def write(self, cache):
+        """The stack with the layer's cache replaced by ``cache``."""
+        return {name: jax.lax.dynamic_update_index_in_dim(leaf, cache[name], self.layer, 0)
+                for name, leaf in self.stack.items()}
+
+
 def init_kv_cache(cfg: AttentionConfig, batch, max_seq, dtype=jnp.bfloat16):
     shape = (batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -347,27 +370,61 @@ def attention_prefill(params, cfg: AttentionConfig, x, cache, pos_offset=0):
     return y, {"k": k_cache, "v": v_cache}
 
 
+def _write_rows(leaf, new, pos, lead=()):
+    """``leaf`` with row ``b`` of ``new`` (B,1,K,Dh) written at position
+    ``pos[b]`` (or the scalar ``pos``) of sequence ``b``; ``lead`` indexes
+    the leading axes of a stacked ``leaf``.  A TPU lays out a cache whose
+    heads are lane-aligned with the head dim minor, and XLA scatters the
+    rows into it in place.  One whose heads are not (zamba2's 160) it
+    lays out with the positions minor: XLA would copy it whole to scatter
+    into it, but writes each sequence's row as a slice in place."""
+    zero = jnp.int32(0)
+    new = new.astype(leaf.dtype)
+    if pos.ndim == 0:
+        return jax.lax.dynamic_update_slice(
+            leaf, new[(None,) * len(lead)], lead + (zero, pos, zero, zero))
+    if new.shape[-1] % LANES == 0:
+        return leaf.at[lead + (jnp.arange(new.shape[0]), pos)].set(new[:, 0])
+    for i in range(new.shape[0]):
+        leaf = jax.lax.dynamic_update_slice(
+            leaf, new[i][(None,) * (len(lead) + 1)], lead + (jnp.int32(i), pos[i], zero, zero))
+    return leaf
+
+
 def attention_decode(params, cfg: AttentionConfig, x, cache, pos):
     """One-token decode.  x: (B,1,d_model); pos: scalar int32, or an
     int32 vector (B,) of *per-sequence* positions (continuous batching:
     each serving slot decodes at its own depth).
 
-    Updates ``cache`` in place (functionally) and attends to positions
-    ``<= pos`` (within the sliding window when configured).
+    ``cache`` is the layer's ``{"k", "v"}`` of (B,T,K,Dh), or a
+    :class:`LayerCache` over the segment's stacked ``(L,B,T,K,Dh)``
+    leaves.  Writes one K/V row per sequence at its position, attends to
+    positions ``<= pos`` (within the sliding window when configured) in
+    the updated cache, and returns (y, the cache in the form it came: for
+    a LayerCache the whole stack).  Where the heads are lane-aligned the
+    rows go straight into layer ``cache.layer`` of the stack.  Else they
+    go into the layer's slice, which goes back into the stack whole: in a
+    layer loop XLA would copy a positions-minor stack to write rows into
+    it (:func:`_write_rows`), and the slice of a stack of one layer is
+    the stack.  On a TPU the serving engine donates the cache to its
+    decode program, so the writes are in place.
     """
     b = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     per_slot = pos.ndim == 1
     positions = pos[:, None] if per_slot else jnp.full((b, 1), pos, jnp.int32)
     q, k_new, v_new = _project_qkv(params, cfg, x, x, positions, positions)
-    if per_slot:
-        # scatter one (K,Dh) row per sequence at that sequence's position
-        rows = jnp.arange(b)
-        k_cache = cache["k"].at[rows, pos].set(k_new[:, 0].astype(cache["k"].dtype))
-        v_cache = cache["v"].at[rows, pos].set(v_new[:, 0].astype(cache["v"].dtype))
+    new = {"k": k_new, "v": v_new}
+    if not isinstance(cache, LayerCache):
+        kv = new_cache = {n: _write_rows(cache[n], new[n], pos) for n in new}
+    elif cfg.head_dim % LANES == 0:
+        new_cache = {n: _write_rows(cache.stack[n], new[n], pos, (cache.layer,)) for n in new}
+        kv = LayerCache(new_cache, cache.layer).read()
     else:
-        k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new.astype(cache["k"].dtype), pos, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new.astype(cache["v"].dtype), pos, axis=1)
+        own = cache.read()
+        kv = {n: _write_rows(own[n], new[n], pos) for n in new}
+        new_cache = cache.write(kv)
+    k_cache, v_cache = kv["k"], kv["v"]
     t = k_cache.shape[1]
     kj = jnp.arange(t)
     valid = kj[None, :] <= positions if per_slot else (kj <= pos)[None, :]
@@ -378,4 +435,4 @@ def attention_decode(params, cfg: AttentionConfig, x, cache, pos):
     out = grouped_attention(q, k_cache.astype(q.dtype), v_cache.astype(q.dtype), mask, cfg.scale)
     out = out.reshape(b, 1, cfg.n_heads * cfg.head_dim)
     y = dense(out, params["wo"])
-    return y, {"k": k_cache, "v": v_cache}
+    return y, new_cache

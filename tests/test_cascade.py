@@ -367,6 +367,16 @@ def test_cascade_deterministic_across_backends(tmp_path, backend, schedule):
     assert run_cascade(tmp_path / "run", backend, schedule) == reference
 
 
+def test_cascade_counts_only_the_compiles_of_its_run(tmp_path, monkeypatch):
+    """``compiled`` counts the generator calls the run made, not the ones
+    its process made before it (an earlier study, another test)."""
+    from repro.hwgen import generator
+
+    monkeypatch.setattr(generator, "_generate_count", generator._generate_count + 5)
+    report = Explorer.from_dict(make_cascade_experiment(tmp_path)).run(save_report=False)
+    assert report.fidelity["funnel"]["compiled"] == 0
+
+
 def test_cascade_report_funnel_and_spearman(tmp_path):
     raw = make_cascade_experiment(tmp_path)
     explorer = Explorer.from_dict(raw)

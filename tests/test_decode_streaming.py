@@ -10,6 +10,8 @@ these tests hold to the bit.  One test lowers the TPU branch on the
 CPU, with the kernel in the Pallas interpreter.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -258,3 +260,118 @@ def test_tpu_branch_matches_xla_path_for_a_hybrid_model(monkeypatch):
     for got, want in zip(jax.tree_util.tree_leaves(cache),
                          jax.tree_util.tree_leaves(want_cache)):
         close(got, want)
+
+
+# every kind of decode cache: lane-aligned heads (K/V rows scattered into
+# the stack), narrow heads (rows written into the layer's slice), Mamba2
+# state and hybrid layers, mLSTM/sLSTM state, cross-attention's static
+# K/V, a sliding window
+CACHE_SPECS = {
+    "aligned": ALIGNED,
+    "qwen3-smoke": get_arch("qwen3-1.7b").smoke_spec_fn(),
+    "zamba2-smoke": get_arch("zamba2-2.7b").smoke_spec_fn(),
+    "xlstm-smoke": get_arch("xlstm-1.3b").smoke_spec_fn(),
+    "whisper-smoke": get_arch("whisper-medium").smoke_spec_fn(),
+    "window": ModelSpec(name="window", d_model=64, vocab=256,
+                        layers=(transformer_layer(64, 4, 2, 128, window=5),) * 3),
+}
+
+
+def _carried_and_unscanned(spec, pos):
+    """``LM.decode`` with each segment's cache carried through its layer
+    scan, and with the layers unrolled (each layer's cache its slice,
+    restacked), from one cache already holding a few steps."""
+    model = LM(spec)
+    params = _params(spec)
+    enc_out = None
+    if spec.encoder_layers:
+        enc_out = model.encode(params, jax.random.normal(jax.random.PRNGKey(1), (4, 6, spec.d_model)))
+    cache = model.init_cache(params, 4, 16, enc_out=enc_out, dtype=jnp.float32)
+    step = jax.jit(model.decode)
+    for i in range(3):
+        tokens = (jnp.arange(4, dtype=jnp.int32)[:, None] * 7 + i) % spec.vocab
+        _, cache = step(params, cache, tokens, jnp.asarray(pos, jnp.int32) + i)
+    tokens = (jnp.arange(4, dtype=jnp.int32)[:, None] + 5) % spec.vocab
+    args = (params, cache, tokens, jnp.asarray(pos, jnp.int32) + 3)
+    unscanned = LM(dataclasses.replace(spec, scan_layers=False))
+    return step(*args), jax.jit(unscanned.decode)(*args)
+
+
+@pytest.mark.parametrize("pos", [(0, 5, 2, 9), 4], ids=["per-slot", "scalar"])
+@pytest.mark.parametrize("name", list(CACHE_SPECS))
+def test_carried_cache_decode_is_bit_identical_to_unscanned_layers(name, pos):
+    (logits, cache), (want_logits, want_cache) = _carried_and_unscanned(CACHE_SPECS[name], pos)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    assert jax.tree_util.tree_structure(cache) == jax.tree_util.tree_structure(want_cache)
+    for got, want in zip(jax.tree_util.tree_leaves(cache),
+                         jax.tree_util.tree_leaves(want_cache)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _cache_bytes(cache):
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(cache))
+
+
+def test_engine_decode_updates_its_cache_in_place(monkeypatch):
+    """The engine's decode program on a TPU (here its CPU lowering) takes
+    the cache donated and writes it in place: XLA aliases every byte of
+    it to the new cache, and copies no stacked K/V leaf (a copy per leaf
+    and one more was what a donated cache cost when each layer's K/V went
+    through the scan as xs and ys).  Two steps in a row still serve the
+    tokens that each request gives alone."""
+    import re
+
+    monkeypatch.setattr(ServingEngine, "_platform", lambda self: "tpu")
+    spec = get_arch("qwen3-1.7b").smoke_spec_fn()
+    model, params = LM(spec), _params(spec)
+    engine = ServingEngine(model, params, max_batch=8, queue_limit=8, max_context=256)
+    nbytes = _cache_bytes(engine.cache)
+    assert engine.decode_cache_donated_bytes == nbytes
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32)
+    compiled = engine.decode.lower(engine.params, engine.cache, tokens,
+                                   jax.ShapeDtypeStruct((8,), jnp.int32)).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes == nbytes
+    kv = {"f32[" + ",".join(map(str, leaf.shape)) + "]"
+          for path, leaf in jax.tree_util.tree_leaves_with_path(engine.cache)
+          if path[-1].key in ("k", "v")}
+    copies = re.findall(r"= (f32\[[\d,]+\])\S* copy\(", compiled.as_text())
+    assert kv and not kv & set(copies)
+
+    from repro.launch.traffic import TrafficSpec
+
+    requests = TrafficSpec(seed=2, n_requests=2, arrival="burst", prompt_lens={4: 0.5, 6: 0.5},
+                           gen_lens={3: 1.0}).requests()
+    for req in requests:
+        engine._join(req)
+    engine._decode_step()
+    engine._decode_step()
+    served = {s["req"].id: s["out"] for s in engine.slots if s is not None}
+    served.update({r["id"]: r["tokens"] for r in engine.completed})
+    for req in requests:
+        cache = model.init_cache(params, 1, 256, dtype=jnp.float32)
+        logits, cache = model.prefill(params, cache, jnp.asarray(req.prompt_tokens(spec.vocab)[None]))
+        alone = [int(jnp.argmax(logits[0, -1]))]
+        for pos in range(req.prompt_len, req.prompt_len + 2):
+            logits, cache = model.decode(params, cache, jnp.array([[alone[-1]]], jnp.int32),
+                                         jnp.array([pos]))
+            alone.append(int(jnp.argmax(logits[0, 0])))
+        assert served[req.id] == alone
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+@pytest.mark.parametrize("name", ["zamba2-smoke", "xlstm-smoke", "whisper-smoke"])
+def test_engine_donates_the_whole_cache_on_a_tpu(name, platform, monkeypatch):
+    """On a TPU every leaf of the cache, be it K/V, recurrent state or
+    cross-attention K/V, is donated to the decode program; elsewhere none
+    is, and the cache a step was given stays valid."""
+    monkeypatch.setattr(ServingEngine, "_platform", lambda self: platform)
+    spec = CACHE_SPECS[name]
+    engine = ServingEngine(LM(spec), _params(spec), max_batch=2, queue_limit=2, max_context=16)
+    nbytes = _cache_bytes(engine.cache)
+    assert engine.decode_cache_donated_bytes == (nbytes if platform == "tpu" else 0)
+    given = engine.cache
+    _, engine.cache = engine.decode(engine.params, given, jnp.ones((2, 1), jnp.int32),
+                                    jnp.zeros((2,), jnp.int32))
+    deleted = {leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(given)}
+    assert deleted == {platform == "tpu"}
