@@ -109,54 +109,13 @@ def _sub_cache_init(sub: SubBlock, batch, max_seq, enc_len, dtype):
     return {}
 
 
-def _sub_prefill(sub: SubBlock, params, x, cache, pos_offset):
-    """Full-sequence forward that also fills the decode cache.
-
-    Attention runs through the same full-sequence kernel dispatch as
-    :func:`attention_apply` and writes the whole prompt's K/V in one
-    shot.  Mamba2 runs its chunked scan from the cache's state and hands
-    back the state after the prompt.  mLSTM and sLSTM ingest the prompt
-    with a ``lax.scan`` of their decode step — one compiled program,
-    batched over the prompt, and bitwise identical to the token-by-token
-    loop it replaces.  Returns (y (B,S,d), new_cache).
-    """
-    if sub.kind == "attention":
-        return attn.attention_prefill(params, sub.cfg, x, cache, pos_offset)
-    if sub.kind == "mamba2":
-        return ssm_mod.mamba2_apply(params, sub.cfg, x, cache)
+def _sub_step(sub: SubBlock, params, x, cache):
+    """A sub-block other than self-attention on the layer's own cache:
+    one token, or for cross-attention, mlp and moe a whole sequence.
+    Returns (y, new cache); a cache it returns as it came
+    (cross-attention's static K/V; mlp and moe hold none) is unchanged."""
     if sub.kind == "cross_attention":
         return attn.cross_attention_cached(params, sub.cfg, x, cache), cache
-    if sub.kind == "mlp":
-        return mlp_mod.mlp_apply(params, sub.cfg, x), cache
-    if sub.kind == "moe":
-        return moe_mod.moe_apply(params, sub.cfg, x), cache
-
-    def body(carry, x_t):
-        y_t, new_carry = _sub_decode(sub, params, x_t[:, None], carry, 0)
-        return new_carry, y_t[:, 0]
-
-    new_cache, ys = jax.lax.scan(body, cache, x.transpose(1, 0, 2))
-    return ys.transpose(1, 0, 2), new_cache
-
-
-def _sub_decode(sub: SubBlock, params, x, cache, pos):
-    """Returns (y, new_cache).  ``cache`` may be an
-    :class:`attn.LayerCache`, one layer of a stacked cache; the new cache
-    is then the stack.  Attention writes its K/V rows into the stack
-    itself.  Every other kind decodes on the layer's slice; a state it
-    rewrote is written back whole, and a cache it returns as it came
-    (cross-attention's static K/V; mlp and moe hold none) leaves the
-    stack as it was."""
-    if sub.kind == "attention":
-        return attn.attention_decode(params, sub.cfg, x, cache, pos)
-    if isinstance(cache, attn.LayerCache):
-        own = cache.read()
-        y, new = _sub_decode(sub, params, x, own, pos)
-        return y, cache.stack if new is own else cache.write(new)
-    if sub.kind == "cross_attention":
-        # cross KV is precomputed and static during decode
-        q_only = attn.cross_attention_cached(params, sub.cfg, x, cache)
-        return q_only, cache
     if sub.kind == "mamba2":
         return ssm_mod.mamba2_decode(params, sub.cfg, x, cache)
     if sub.kind == "mlstm":
@@ -168,6 +127,46 @@ def _sub_decode(sub: SubBlock, params, x, cache, pos):
     if sub.kind == "moe":
         return moe_mod.moe_apply(params, sub.cfg, x), cache
     raise ValueError(sub.kind)
+
+
+def _sub_prefill(sub: SubBlock, params, x, cache: attn.LayerCache, pos_offset):
+    """Full-sequence forward that also fills the layer's cache.
+
+    Attention runs through the same full-sequence kernel dispatch as
+    :func:`attention_apply` and writes the whole prompt's K/V rows into
+    the stack in one shot.  Every other kind runs on the layer's own
+    cache (:meth:`attn.LayerCache.update`): Mamba2 its chunked scan from
+    the cache's state, handing back the state after the prompt; mLSTM
+    and sLSTM a ``lax.scan`` of their decode step — one compiled
+    program, batched over the prompt, and bitwise identical to the
+    token-by-token loop it replaces.  Returns (y (B,S,d), the stack).
+    """
+    if sub.kind == "attention":
+        return attn.attention_prefill(params, sub.cfg, x, cache, pos_offset)
+
+    def run(own):
+        if sub.kind == "mamba2":
+            return ssm_mod.mamba2_apply(params, sub.cfg, x, own)
+        if sub.kind not in ("mlstm", "slstm"):
+            return _sub_step(sub, params, x, own)
+
+        def body(carry, x_t):
+            y_t, new_carry = _sub_step(sub, params, x_t[:, None], carry)
+            return new_carry, y_t[:, 0]
+
+        new, ys = jax.lax.scan(body, own, x.transpose(1, 0, 2))
+        return ys.transpose(1, 0, 2), new
+
+    return cache.update(run)
+
+
+def _sub_decode(sub: SubBlock, params, x, cache: attn.LayerCache, pos):
+    """One token against the layer's cache; returns (y, the stack).
+    Attention writes its K/V rows into the stack itself; every other
+    kind decodes on the layer's own cache (:meth:`attn.LayerCache.update`)."""
+    if sub.kind == "attention":
+        return attn.attention_decode(params, sub.cfg, x, cache, pos)
+    return cache.update(lambda own: _sub_step(sub, params, x, own))
 
 
 # the projection weights that decode may read a layer at a time
@@ -319,68 +318,98 @@ class LM:
 
     # -- one layer ------------------------------------------------------------
 
-    def _layer(self, layer: LayerSpec, params, h, call, emb=None, block=None):
+    def _layer(self, layer: LayerSpec, params, h, cache, call, emb=None, block=None):
         """One layer's pre-norm residual sub-blocks, ``h + f(norm(h))``.
-        ``call(key, sub, inner params, x)`` runs one and returns ``(y, its
-        new cache)``, the cache under ``key`` in the layer's.  A hybrid
-        layer first runs shared block ``block`` (:meth:`_shared`), whose
-        output joins the first sub-block's input.  Returns (h, new cache).
-        """
-        new_cache = {}
+        ``cache`` maps each sub-block's key to its :class:`attn.LayerCache`
+        (empty without a cache); ``call(sub, inner params, x, that view or
+        None)`` runs one and returns ``(y, the stack with its new
+        cache)``.  A hybrid layer first runs shared block ``block``
+        (:meth:`_shared`), whose output joins the first sub-block's input.
+        Returns (h, the new stacks under the keys of ``cache``)."""
+        new = {}
+
+        def run(key, sub, inner, x):
+            y, new[key] = call(sub, inner, x, cache.get(key))
+            return y
+
         lift = None
         if layer.hybrid:
-            lift, new_cache["shared"] = self._shared(block, params, h, emb, call)
+            lift = self._shared(block, params, h, emb, run)
         for i, sub in enumerate(layer.subs):
             sp = params[f"sub_{i}"]
             with jax.named_scope(sub.kind):
                 x = self._norm(sp["norm"], h if lift is None else h + lift)
-                y, new_cache[f"sub_{i}"] = call(f"sub_{i}", sub, sp["inner"], x)
-                h = h + y
+                h = h + run(f"sub_{i}", sub, sp["inner"], x)
             lift = None
-        return h, new_cache
+        return h, {key: new[key] for key in cache}
 
-    def _shared(self, block, params, h, emb, call):
+    def _shared(self, block, params, h, emb, run):
         """A hybrid layer's shared block on ``[h; emb]``, with the layer's
         own adapter and ``linear``: ``linear(mlp(norm(attention(norm([h;
-        emb])))))``, no residual inside.  Returns (it, the K/V cache)."""
+        emb])))))``, no residual inside; its attention runs as
+        ``run("shared", ...)``."""
         attn_sub, mlp_sub = self.spec.shared.layer.subs
         a, m = block["sub_0"], block["sub_1"]
         with jax.named_scope("attention"):
             x = self._norm(a["norm"], jnp.concatenate([h, emb.astype(h.dtype)], axis=-1))
-            t, cache = call("shared", attn_sub, a["inner"], x)
+            t = run("shared", attn_sub, a["inner"], x)
         with jax.named_scope("mlp"):
             t = mlp_mod.mlp_apply(m["inner"], mlp_sub.cfg, self._norm(m["norm"], t),
                                   adapter=params["adapter"])
-            t = linear.dense(t, params["linear"])
-        return t, cache
+            return linear.dense(t, params["linear"])
 
-    # -- forward ------------------------------------------------------------
-
-    def _run_segments(self, segments, params, h, *, positions, enc_out, emb=None):
-        def call(key, sub, inner, x):
-            return _sub_apply(sub, inner, x, positions=positions, enc_out=enc_out), None
-
+    def _walk(self, segments, params, cache, h, call, emb=None, stream=False):
+        """Every segment's layers over ``h``, each segment one
+        ``lax.scan`` (of one layer, too) over (layer index, its params)
+        that carries ``h`` and the segment's stacked cache: ``cache`` is
+        None for ``apply`` and ``encode``, whose scans carry none and
+        whose layer body is rematerialized as the spec says.  Each layer
+        gets an :class:`attn.LayerCache` view of the stack per sub-block,
+        and ``call`` (:meth:`_layer`) writes what it changes (K/V rows, a
+        recurrent state) into the stack, which a program that takes the
+        cache donated updates in place.  With ``stream`` (decode) the
+        scan closes over the stacks :func:`linear.streams` accepts and
+        hands each layer a :class:`linear.LayerWeight`, and so are the
+        shared blocks' weights, as stacks of one.  ``scan_layers`` False
+        unrolls the scans.  Returns (h, new cache)."""
+        new_cache: Dict[str, Any] = {}
         for seg in segments:
             block = params.get(self.block.get(seg.name))
+            rest, streamed = params[seg.name], {}
+            if stream:
+                # streamed weights are closed over, not sliced as xs
+                rest, streamed = _split_streamed(seg.spec, rest)
+                if block is not None:
+                    # an unstacked weight is a stack of one layer
+                    b_rest, b_streamed = _split_streamed(
+                        self.spec.shared.layer, jax.tree_util.tree_map(lambda x: x[None], block))
+                    block = _with_layer(_take(b_rest, 0), b_streamed, jnp.int32(0))
 
-            def body(carry, layer_params, _seg=seg, _block=block):
-                out, _ = self._layer(_seg.spec, layer_params, carry, call, emb, _block)
-                return out, None
+            def body(carry, inp, _seg=seg, _block=block, _streamed=streamed):
+                h, stack = carry
+                layer, lp = inp
+                view = {key: attn.LayerCache(c, layer) for key, c in stack.items()}
+                return self._layer(_seg.spec, _with_layer(lp, _streamed, layer), h, view,
+                                   call, emb, _block), None
 
-            if self.spec.remat:
+            if cache is None and self.spec.remat:
                 policy = None
                 if self.spec.remat_policy == "dots":
                     policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                 body = jax.checkpoint(body, prevent_cse=False, policy=policy)
-            if seg.count == 1:
-                h, _ = body(h, _take(params[seg.name], 0))
-            elif not self.spec.scan_layers:
-                for i in range(seg.count):
-                    h, _ = body(h, _take(params[seg.name], i))
-            else:
-                h, _ = jax.lax.scan(body, h, params[seg.name])
+            (h, new_cache[seg.name]), _ = jax.lax.scan(
+                body, (h, {} if cache is None else cache[seg.name]),
+                (jnp.arange(seg.count, dtype=jnp.int32), rest),
+                unroll=not self.spec.scan_layers)
             h = constrain(h, ("batch", None, None))
-        return h
+        return h, new_cache
+
+    def _apply_call(self, positions, enc_out):
+        def call(sub, inner, x, _):
+            return _sub_apply(sub, inner, x, positions=positions, enc_out=enc_out), None
+        return call
+
+    # -- forward ------------------------------------------------------------
 
     def _embed(self, params, tokens, prefix_embeds):
         with jax.named_scope("embed"):
@@ -409,8 +438,8 @@ class LM:
         h = frames
         if self.spec.positional == "learned":
             h = h + params["pos_embed"][: h.shape[1]][None].astype(h.dtype)
-        positions = jnp.arange(h.shape[1])[None]
-        h = self._run_segments(self.enc_segments, params, h, positions=positions, enc_out=None)
+        call = self._apply_call(jnp.arange(h.shape[1])[None], None)
+        h, _ = self._walk(self.enc_segments, params, None, h, call)
         return self._norm(params["enc_final_norm"], h)
 
     def _backbone(self, params, tokens, prefix_embeds, enc_out, positions):
@@ -420,8 +449,9 @@ class LM:
         if self.spec.positional == "learned":
             h = h + params["pos_embed"][: h.shape[1]][None].astype(h.dtype)
         h = constrain(h, ("batch", None, None))
-        return self._run_segments(self.segments, params, h, positions=positions,
-                                  enc_out=enc_out, emb=emb)
+        h, _ = self._walk(self.segments, params, None, h,
+                          self._apply_call(positions, enc_out), emb)
+        return h
 
     @_at_spec_precision
     def hidden(self, params, tokens, *, prefix_embeds=None, enc_out=None, positions=None):
@@ -515,61 +545,6 @@ class LM:
             axes[seg.name] = entry
         return axes
 
-    def _walk(self, params, cache, h, call, emb, stream=False):
-        """Every segment's layers over ``h`` with their caches; ``call(layer
-        cache)`` gives the ``call`` that :meth:`_layer` takes.  With
-        ``stream`` (decode), each segment is a scan (of one layer, too)
-        that closes over the stacks :func:`linear.streams` accepts and
-        hands each layer a :class:`linear.LayerWeight`, and so are the
-        shared blocks' weights, as stacks of one.  The segment's stacked
-        cache rides in the scan's carry beside ``h``, and each layer gets
-        an :class:`attn.LayerCache` view of it per sub-block: the layer
-        writes what it changes (one K/V row per sequence, a recurrent
-        state) into the stack, which a program that takes the cache
-        donated updates in place.  Otherwise each layer's cache is its
-        slice, and the new cache is restacked.  Returns (h, new cache)."""
-        new_cache: Dict[str, Any] = {}
-        stream = stream and self.spec.scan_layers
-        for seg in self.segments:
-            block = params.get(self.block.get(seg.name))
-            if stream and block is not None:
-                # an unstacked weight is a stack of one layer
-                rest, streamed = _split_streamed(
-                    self.spec.shared.layer, jax.tree_util.tree_map(lambda x: x[None], block))
-                block = _with_layer(_take(rest, 0), streamed, jnp.int32(0))
-
-            def body(carry, inp, _seg=seg, _block=block):
-                lp, lc = inp
-                return self._layer(_seg.spec, lp, carry, call(lc), emb, _block)
-
-            if stream:
-                # streamed weights are closed over, not sliced as xs
-                rest, streamed = _split_streamed(seg.spec, params[seg.name])
-
-                def layer_body(carry, inp, _body=body, _streamed=streamed):
-                    h, stack = carry
-                    layer, lp = inp
-                    view = {key: attn.LayerCache(c, layer) for key, c in stack.items()}
-                    return _body(h, (_with_layer(lp, _streamed, layer), view)), None
-
-                (h, new_cache[seg.name]), _ = jax.lax.scan(
-                    layer_body, (h, cache[seg.name]),
-                    (jnp.arange(seg.count, dtype=jnp.int32), rest))
-            elif seg.count == 1:
-                h, nc = body(h, (_take(params[seg.name], 0), _take(cache[seg.name], 0)))
-                new_cache[seg.name] = jax.tree_util.tree_map(lambda x: x[None], nc)
-            elif not self.spec.scan_layers:
-                ncs = []
-                for i in range(seg.count):
-                    h, nc = body(h, (_take(params[seg.name], i), _take(cache[seg.name], i)))
-                    ncs.append(nc)
-                new_cache[seg.name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ncs)
-            else:
-                h, new_cache[seg.name] = jax.lax.scan(
-                    body, h, (params[seg.name], cache[seg.name]))
-            h = constrain(h, ("batch", None, None))
-        return h, new_cache
-
     @_at_spec_precision
     def prefill(self, params, cache, tokens, pos_offset=0):
         """Batched prefill: the whole prompt in one full-sequence forward
@@ -587,17 +562,16 @@ class LM:
                 params["pos_embed"], pos_offset, s, axis=0)
             h = h + pe[None].astype(h.dtype)
 
-        def call(lc):
-            return lambda key, sub, inner, x: _sub_prefill(sub, inner, x, lc[key], pos_offset)
-
-        h, new_cache = self._walk(params, cache, h, call, emb)
+        call = functools.partial(_sub_prefill, pos_offset=pos_offset)
+        h, new_cache = self._walk(self.segments, params, cache, h, call, emb)
         return self._head(params, h), new_cache
 
     @_at_spec_precision
     def decode(self, params, cache, tokens, pos):
-        """One-step decode.  tokens: (B, 1) int32; pos: scalar int32 or
-        an int32 vector (B,) of per-sequence positions (continuous
-        batching: each serving slot decodes at its own depth).
+        """One-step decode.  tokens: (B, 1) int32; pos: an int32 vector
+        (B,) of per-sequence positions (continuous batching: each serving
+        slot decodes at its own depth), or a scalar that every sequence
+        shares.
 
         Returns (logits (B, 1, vocab), new_cache).  Each segment's stacked
         cache is carried through its layer scan and updated in place: a
@@ -608,16 +582,10 @@ class LM:
         answers, with one copy of the cache at the program's boundary.
         """
         h = emb = self._embed(params, tokens, None)
-        pos = jnp.asarray(pos, jnp.int32)
+        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape[:1])
         if self.spec.positional == "learned":
-            if pos.ndim == 1:
-                pe = jnp.take(params["pos_embed"], pos, axis=0)[:, None]
-            else:
-                pe = jax.lax.dynamic_slice_in_dim(params["pos_embed"], pos, 1, axis=0)[None]
-            h = h + pe.astype(h.dtype)
+            h = h + jnp.take(params["pos_embed"], pos, axis=0)[:, None].astype(h.dtype)
 
-        def call(lc):
-            return lambda key, sub, inner, x: _sub_decode(sub, inner, x, lc[key], pos)
-
-        h, new_cache = self._walk(params, cache, h, call, emb, stream=True)
+        call = functools.partial(_sub_decode, pos=pos)
+        h, new_cache = self._walk(self.segments, params, cache, h, call, emb, stream=True)
         return self._head(params, h), new_cache

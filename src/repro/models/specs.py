@@ -99,8 +99,8 @@ class ModelSpec:
     # a ~1.5x cut in recompute FLOPs; a §Perf lever).
     remat_policy: Optional[str] = None
     # scan_layers=True: lax.scan over stacked segment params (fast compile,
-    # production).  False: Python-unrolled layers — used by the dry-run cost
-    # lowering because XLA's HloCostAnalysis counts while bodies once.
+    # production).  False: the same scans unrolled — used by the dry-run
+    # cost lowering because XLA's HloCostAnalysis counts while bodies once.
     scan_layers: bool = True
     shared: Optional[SharedBlocks] = None  # run by the hybrid layers
     # the default matmul precision of apply, hidden, prefill and decode

@@ -4,8 +4,8 @@ Three entry points:
   * ``attention_init``    -- parameters
   * ``attention_apply``   -- full-sequence (training / prefill / encoder /
                              cross-attention) attention
-  * ``attention_decode``  -- single-token decode against a preallocated
-                             KV cache, or one layer of a stacked one
+  * ``attention_decode``  -- single-token decode against one layer of
+                             a stacked, preallocated KV cache
                              (:class:`LayerCache`): writes one K/V row
                              per sequence
 
@@ -272,9 +272,9 @@ def attention_apply(
 
 class LayerCache(NamedTuple):
     """Layer ``layer`` of a segment's stacked cache, not sliced: ``stack``
-    is a dict of ``(L, ...)`` leaves.  Decode's layer scan carries the
-    stack and hands each layer this view, so a layer writes only what it
-    changes into the stack the step updates in place."""
+    is a dict of ``(L, ...)`` leaves.  A layer scan carries the stack and
+    hands each layer this view, so a layer writes only what it changes
+    into the stack, which a step that takes it donated updates in place."""
 
     stack: Dict[str, Any]
     layer: jax.Array  # int32 scalar
@@ -288,6 +288,14 @@ class LayerCache(NamedTuple):
         """The stack with the layer's cache replaced by ``cache``."""
         return {name: jax.lax.dynamic_update_index_in_dim(leaf, cache[name], self.layer, 0)
                 for name, leaf in self.stack.items()}
+
+    def update(self, fn):
+        """``fn`` on the layer's own cache, which returns (y, a new
+        cache): returns (y, the stack with that cache written back, or
+        as it was where ``fn`` returned the cache it was given)."""
+        own = self.read()
+        y, new = fn(own)
+        return y, self.stack if new is own else self.write(new)
 
 
 def init_kv_cache(cfg: AttentionConfig, batch, max_seq, dtype=jnp.bfloat16):
@@ -326,24 +334,27 @@ def cross_attention_cached(params, cfg: AttentionConfig, x, cache):
     return jnp.einsum("bsh,hd->bsd", out, params["wo"])
 
 
-def attention_prefill(params, cfg: AttentionConfig, x, cache, pos_offset=0):
+def attention_prefill(params, cfg: AttentionConfig, x, cache: LayerCache, pos_offset=0):
     """Batched prefill: full-sequence attention through the same kernel
     dispatch as :func:`attention_apply` (pallas flash / xla_chunked /
-    grouped), writing the prompt's K/V into the preallocated cache in one
-    shot instead of token-by-token.  x: (B,S,d_model); the prompt
-    occupies cache positions ``[pos_offset, pos_offset+S)``.
+    grouped), writing the prompt's K/V rows straight into layer
+    ``cache.layer`` of the stacked cache in one shot instead of
+    token-by-token.  x: (B,S,d_model); the prompt occupies cache
+    positions ``[pos_offset, pos_offset+S)``.
 
-    Returns (y (B,S,d_model), new_cache) — bitwise the same cache a
+    Returns (y (B,S,d_model), the stack) — bitwise the same cache a
     ``attention_decode`` loop over the prompt would produce, at
     full-sequence kernel cost (see tests/test_serving.py).
     """
     b, s, _ = x.shape
     positions = (pos_offset + jnp.arange(s))[None]
     q, k, v = _project_qkv(params, cfg, x, x, positions, positions)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        cache["k"], k.astype(cache["k"].dtype), pos_offset, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        cache["v"], v.astype(cache["v"].dtype), pos_offset, axis=1)
+    # the prompt's rows as the cache holds them, and decode reads them
+    rows = {n: new.astype(cache.stack[n].dtype) for n, new in (("k", k), ("v", v))}
+    zero = jnp.int32(0)
+    start = (cache.layer, zero, jnp.int32(pos_offset), zero, zero)
+    stack = {n: jax.lax.dynamic_update_slice(cache.stack[n], rows[n][None], start)
+             for n in rows}
     if cfg.impl == "pallas" and pos_offset == 0:
         from repro.kernels import ops as kops
 
@@ -357,82 +368,73 @@ def attention_prefill(params, cfg: AttentionConfig, x, cache, pos_offset=0):
             q, k, v, cfg.scale, causal=cfg.causal, window=cfg.window,
             kv_chunk=max(kv_chunk, 1), unroll=cfg.scan_unroll)
     else:
-        # pos_offset > 0 (chunked prompt ingestion) attends against the
-        # cache prefix, which the flash/chunked paths don't slice yet
         t = pos_offset + s
         mask = make_mask(s, t, cfg.causal, cfg.window, q_offset=pos_offset)
-        k_pfx = jax.lax.dynamic_slice_in_dim(k_cache, 0, t, axis=1)
-        v_pfx = jax.lax.dynamic_slice_in_dim(v_cache, 0, t, axis=1)
-        out = grouped_attention(q, k_pfx.astype(q.dtype),
-                                v_pfx.astype(q.dtype), mask, cfg.scale)
+        if pos_offset:
+            # chunked prompt ingestion attends against the cache prefix,
+            # which the flash/chunked paths don't slice yet
+            rows = {n: jax.lax.dynamic_slice(
+                        stack[n], (cache.layer, zero, zero, zero, zero),
+                        (1, b, t) + stack[n].shape[3:])[0] for n in rows}
+        out = grouped_attention(q, rows["k"].astype(q.dtype),
+                                rows["v"].astype(q.dtype), mask, cfg.scale)
     out = out.reshape(b, s, cfg.n_heads * cfg.head_dim)
     y = jnp.einsum("bsh,hd->bsd", out, params["wo"])
-    return y, {"k": k_cache, "v": v_cache}
+    return y, stack
 
 
-def _write_rows(leaf, new, pos, lead=()):
-    """``leaf`` with row ``b`` of ``new`` (B,1,K,Dh) written at position
-    ``pos[b]`` (or the scalar ``pos``) of sequence ``b``; ``lead`` indexes
-    the leading axes of a stacked ``leaf``.  A TPU lays out a cache whose
-    heads are lane-aligned with the head dim minor, and XLA scatters the
-    rows into it in place.  One whose heads are not (zamba2's 160) it
-    lays out with the positions minor: XLA would copy it whole to scatter
-    into it, but writes each sequence's row as a slice in place."""
+def _write_rows(leaf, new, pos):
+    """``leaf`` (B,T,K,Dh) with row ``b`` of ``new`` (B,1,K,Dh) written at
+    position ``pos[b]`` of sequence ``b``, each row a slice."""
     zero = jnp.int32(0)
     new = new.astype(leaf.dtype)
-    if pos.ndim == 0:
-        return jax.lax.dynamic_update_slice(
-            leaf, new[(None,) * len(lead)], lead + (zero, pos, zero, zero))
-    if new.shape[-1] % LANES == 0:
-        return leaf.at[lead + (jnp.arange(new.shape[0]), pos)].set(new[:, 0])
     for i in range(new.shape[0]):
-        leaf = jax.lax.dynamic_update_slice(
-            leaf, new[i][(None,) * (len(lead) + 1)], lead + (jnp.int32(i), pos[i], zero, zero))
+        leaf = jax.lax.dynamic_update_slice(leaf, new[i][None], (jnp.int32(i), pos[i], zero, zero))
     return leaf
 
 
-def attention_decode(params, cfg: AttentionConfig, x, cache, pos):
-    """One-token decode.  x: (B,1,d_model); pos: scalar int32, or an
-    int32 vector (B,) of *per-sequence* positions (continuous batching:
-    each serving slot decodes at its own depth).
+def attention_decode(params, cfg: AttentionConfig, x, cache: LayerCache, pos):
+    """One-token decode.  x: (B,1,d_model); pos: an int32 vector (B,) of
+    *per-sequence* positions (continuous batching: each serving slot
+    decodes at its own depth).
 
-    ``cache`` is the layer's ``{"k", "v"}`` of (B,T,K,Dh), or a
-    :class:`LayerCache` over the segment's stacked ``(L,B,T,K,Dh)``
-    leaves.  Writes one K/V row per sequence at its position, attends to
-    positions ``<= pos`` (within the sliding window when configured) in
-    the updated cache, and returns (y, the cache in the form it came: for
-    a LayerCache the whole stack).  Where the heads are lane-aligned the
-    rows go straight into layer ``cache.layer`` of the stack.  Else they
-    go into the layer's slice, which goes back into the stack whole: in a
-    layer loop XLA would copy a positions-minor stack to write rows into
-    it (:func:`_write_rows`), and the slice of a stack of one layer is
-    the stack.  On a TPU the serving engine donates the cache to its
-    decode program, so the writes are in place.
+    ``cache`` is one layer of the segment's stacked ``{"k", "v"}`` of
+    (L,B,T,K,Dh).  Writes one K/V row per sequence at its position,
+    attends to positions ``<= pos`` (within the sliding window when
+    configured) in the updated cache, and returns (y, the stack).  A TPU
+    lays out a cache whose heads are lane-aligned with the head dim
+    minor: the rows are scattered straight into layer ``cache.layer`` of
+    the stack.  One whose heads are not (zamba2's 160) it lays out with
+    the positions minor, and in a layer loop XLA would copy such a stack
+    whole to scatter into it: the rows go into the layer's own cache as
+    slices (:func:`_write_rows`), which goes back into the stack
+    (:meth:`LayerCache.update`; the slice of a stack of one layer is the
+    stack).  On a TPU the serving engine donates the cache to its decode
+    program, so the writes are in place.
     """
     b = x.shape[0]
-    pos = jnp.asarray(pos, jnp.int32)
-    per_slot = pos.ndim == 1
-    positions = pos[:, None] if per_slot else jnp.full((b, 1), pos, jnp.int32)
+    positions = pos[:, None]
     q, k_new, v_new = _project_qkv(params, cfg, x, x, positions, positions)
     new = {"k": k_new, "v": v_new}
-    if not isinstance(cache, LayerCache):
-        kv = new_cache = {n: _write_rows(cache[n], new[n], pos) for n in new}
-    elif cfg.head_dim % LANES == 0:
-        new_cache = {n: _write_rows(cache.stack[n], new[n], pos, (cache.layer,)) for n in new}
-        kv = LayerCache(new_cache, cache.layer).read()
-    else:
-        own = cache.read()
+
+    def attend(kv):
+        kj = jnp.arange(kv["k"].shape[1])
+        valid = kj[None, :] <= positions
+        if cfg.window is not None:
+            valid &= kj[None, :] > positions - cfg.window
+        mask = valid[:, None, None, None, :]  # (B,1,1,1,T)
+        out = grouped_attention(q, kv["k"].astype(q.dtype), kv["v"].astype(q.dtype), mask,
+                                cfg.scale)
+        return dense(out.reshape(b, 1, cfg.n_heads * cfg.head_dim), params["wo"])
+
+    if cfg.head_dim % LANES == 0:
+        rows = (cache.layer, jnp.arange(b), pos)
+        stack = {n: cache.stack[n].at[rows].set(new[n][:, 0].astype(cache.stack[n].dtype))
+                 for n in new}
+        return attend(LayerCache(stack, cache.layer).read()), stack
+
+    def write(own):
         kv = {n: _write_rows(own[n], new[n], pos) for n in new}
-        new_cache = cache.write(kv)
-    k_cache, v_cache = kv["k"], kv["v"]
-    t = k_cache.shape[1]
-    kj = jnp.arange(t)
-    valid = kj[None, :] <= positions if per_slot else (kj <= pos)[None, :]
-    if cfg.window is not None:
-        wfloor = positions - cfg.window if per_slot else pos - cfg.window
-        valid &= kj[None, :] > wfloor
-    mask = valid[:, None, None, None, :]  # (B or 1, 1,1,1,T)
-    out = grouped_attention(q, k_cache.astype(q.dtype), v_cache.astype(q.dtype), mask, cfg.scale)
-    out = out.reshape(b, 1, cfg.n_heads * cfg.head_dim)
-    y = dense(out, params["wo"])
-    return y, new_cache
+        return attend(kv), kv
+
+    return cache.update(write)

@@ -263,7 +263,7 @@ def test_tpu_branch_matches_xla_path_for_a_hybrid_model(monkeypatch):
 
 
 # every kind of decode cache: lane-aligned heads (K/V rows scattered into
-# the stack), narrow heads (rows written into the layer's slice), Mamba2
+# the stack), narrow heads (rows written into the layer's own cache), Mamba2
 # state and hybrid layers, mLSTM/sLSTM state, cross-attention's static
 # K/V, a sliding window
 CACHE_SPECS = {
@@ -277,36 +277,70 @@ CACHE_SPECS = {
 }
 
 
-def _carried_and_unscanned(spec, pos):
+def _enc_out(model, params, batch):
+    if not model.spec.encoder_layers:
+        return None
+    frames = jax.random.normal(jax.random.PRNGKey(1), (batch, 6, model.spec.d_model))
+    return model.encode(params, frames)
+
+
+def _carried_and_unrolled(spec, pos):
     """``LM.decode`` with each segment's cache carried through its layer
-    scan, and with the layers unrolled (each layer's cache its slice,
-    restacked), from one cache already holding a few steps."""
+    scan, and through the same walk unrolled (``scan_layers=False``),
+    from one cache already holding a few steps."""
     model = LM(spec)
     params = _params(spec)
-    enc_out = None
-    if spec.encoder_layers:
-        enc_out = model.encode(params, jax.random.normal(jax.random.PRNGKey(1), (4, 6, spec.d_model)))
-    cache = model.init_cache(params, 4, 16, enc_out=enc_out, dtype=jnp.float32)
+    cache = model.init_cache(params, 4, 16, enc_out=_enc_out(model, params, 4),
+                             dtype=jnp.float32)
     step = jax.jit(model.decode)
     for i in range(3):
         tokens = (jnp.arange(4, dtype=jnp.int32)[:, None] * 7 + i) % spec.vocab
         _, cache = step(params, cache, tokens, jnp.asarray(pos, jnp.int32) + i)
     tokens = (jnp.arange(4, dtype=jnp.int32)[:, None] + 5) % spec.vocab
     args = (params, cache, tokens, jnp.asarray(pos, jnp.int32) + 3)
-    unscanned = LM(dataclasses.replace(spec, scan_layers=False))
-    return step(*args), jax.jit(unscanned.decode)(*args)
+    unrolled = LM(dataclasses.replace(spec, scan_layers=False))
+    return step(*args), jax.jit(unrolled.decode)(*args)
 
 
 @pytest.mark.parametrize("pos", [(0, 5, 2, 9), 4], ids=["per-slot", "scalar"])
 @pytest.mark.parametrize("name", list(CACHE_SPECS))
-def test_carried_cache_decode_is_bit_identical_to_unscanned_layers(name, pos):
-    (logits, cache), (want_logits, want_cache) = _carried_and_unscanned(CACHE_SPECS[name], pos)
+def test_carried_cache_decode_is_bit_identical_to_unrolled_walk(name, pos):
+    (logits, cache), (want_logits, want_cache) = _carried_and_unrolled(CACHE_SPECS[name], pos)
     np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
     assert jax.tree_util.tree_structure(cache) == jax.tree_util.tree_structure(want_cache)
     for got, want in zip(jax.tree_util.tree_leaves(cache),
                          jax.tree_util.tree_leaves(want_cache)):
         assert got.dtype == want.dtype
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("vector", [True, False], ids=["vector-pos", "scalar-pos"])
+@pytest.mark.parametrize("name", list(CACHE_SPECS))
+def test_apply_prefill_and_decode_agree_at_every_position(name, vector):
+    """The one walk, three ways: ``apply``'s logits over a prompt,
+    ``prefill``'s, and those of a decode loop over the prompt, one token
+    a step at a per-slot position vector (or the scalar every slot
+    shares), agree at every position (f32 on the CPU)."""
+    spec = CACHE_SPECS[name]
+    model = LM(spec)
+    params = _params(spec)
+    batch, length = 2, 8
+    enc_out = _enc_out(model, params, batch)
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (batch, length), 0, spec.vocab)
+    empty = model.init_cache(params, batch, 16, enc_out=enc_out, dtype=jnp.float32)
+
+    applied = jax.jit(model.apply)(params, tokens, enc_out=enc_out)
+    prefilled, _ = jax.jit(model.prefill)(params, empty, tokens)
+    step, cache, decoded = jax.jit(model.decode), empty, []
+    for t in range(length):
+        pos = jnp.full((batch,), t, jnp.int32) if vector else t
+        logits, cache = step(params, cache, tokens[:, t:t + 1], pos)
+        decoded.append(logits)
+    decoded = jnp.concatenate(decoded, axis=1)
+
+    assert applied.shape == prefilled.shape == decoded.shape == (batch, length, spec.vocab)
+    for got in (prefilled, decoded):
+        assert float(jnp.max(jnp.abs(got - applied))) < 1e-4
 
 
 def _cache_bytes(cache):
