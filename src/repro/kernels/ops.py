@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from repro.envvars import read_env
 from repro.kernels import schedule as ksched
+from repro.kernels.decode_attention import decode_attention_stacked
 from repro.kernels.decode_matmul import decode_matmul_stacked
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.mlstm_scan import mlstm_scan_bhlp
@@ -218,3 +219,26 @@ def decode_matmul(x, w, layer, *, n=None, k_minor=False, passes=1, schedule=None
     return decode_matmul_stacked(x, w, layer, block_k=eff.block_k, block_n=eff.block_n,
                                  n=n, k_minor=k_minor, passes=passes,
                                  interpret=eff.interpret)
+
+
+# ---------------------------------------------------------------------------
+# decode attention
+# ---------------------------------------------------------------------------
+
+def decode_attention(q, k, v, layer, lengths, *, scale, schedule=None, platform=None):
+    """One token's attention over positions ``[0, lengths[b])`` of layer
+    ``layer`` of a stacked K/V cache, reading only the blocks of
+    ``block_kv`` positions that hold them.
+
+    q: (B, H, Dh); k, v: (L, B, T, K, Dh), K dividing H; layer: int32
+    scalar; lengths: (B,) int32 in ``[1, T]``.  Returns (B, H, Dh)
+    float32, from bf16 operands.  ``platform`` as for
+    :func:`decode_matmul`."""
+    requested = _resolve("decode_attention", schedule, {})
+    eff = _finish(requested, ksched.effective_schedule(
+        "decode_attention", requested, seq_len=k.shape[2]), platform)
+    ksched.note_kernel_call("decode_attention", requested, eff,
+                            shapes={"q": q.shape, "k": k.shape},
+                            meta={"dtype": str(k.dtype)})
+    return decode_attention_stacked(q, k, v, layer, lengths, scale=scale,
+                                    block_t=eff.block_kv, interpret=eff.interpret)

@@ -51,3 +51,17 @@ def decode_matmul_ref(x, w, layer, passes=1):
         w_lo = (w[layer] - w_hi.astype(jnp.float32)).astype(bf16)
         out = out + dot(x_hi, w_lo) + dot(x_lo, w_hi)
     return out
+
+
+def decode_attention_ref(q, k, v, layer, lengths, *, scale):
+    """q: (B, H, Dh); k/v: (L, B, T, K, Dh) -> (B, H, Dh): the masked
+    grouped attention of each slot's query over positions
+    ``[0, lengths[b])`` of layer ``layer``, from bf16-rounded q, K and V
+    (the kernel's operands) with f32 scores and probabilities."""
+    def bf16(x):
+        return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+    t = k.shape[2]
+    mask = (jnp.arange(t)[None, :] < lengths[:, None])[:, None, None, None, :]
+    out = grouped_attention(bf16(q)[:, None], bf16(k[layer]), bf16(v[layer]), mask, scale)
+    return out[:, 0]

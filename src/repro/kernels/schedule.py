@@ -7,7 +7,8 @@ could not see.  This module makes the mapping explicit:
   * :class:`KernelSchedule` — a frozen (hashable, jit-static) record of
     the tunable launch parameters: ``block_q``/``block_kv`` for flash
     attention, ``chunk`` for the scan kernels, ``block_k``/``block_n``
-    for the decode weight tiles, plus an ``interpret``
+    for the decode weight tiles, ``block_kv`` for the decode attention's
+    K/V blocks, plus an ``interpret``
     override for the Pallas interpreter off-TPU;
   * :func:`validate_schedule` — per-kernel legal-range / power-of-two
     checks whose errors name the offending field;
@@ -32,7 +33,11 @@ The named ``default`` schedule is exactly the pre-schedule constants
 path bit-for-bit (asserted in ``tests/test_schedule.py``).  The decode
 matmul, which came later, defaults to 512x1024 f32 weight tiles: on a
 v5e, tiles from 256x1024 to 2048x1024 all read the weights at 690-730
-GB/s, and this one needs half the VMEM of 1024x1024.
+GB/s, and this one needs half the VMEM of 1024x1024.  The decode
+attention reads K/V in blocks of 128 positions: over qwen3-1.7b's 28
+layers of 8 slots, at a mean context near 576 of 1281, the kernel took
+2.47, 3.06, 3.48 and 5.10 ms at 128, 256, 512 and 1024 on a v5e, each
+block past a slot's length still costing its grid step.
 
 Import-light on purpose: stdlib only, so the spec layer can validate
 ``kernel_tuning:`` sections without touching jax.
@@ -55,6 +60,7 @@ KERNEL_FIELDS: Dict[str, Tuple[str, ...]] = {
     "ssm_scan": ("chunk",),
     "mlstm_scan": ("chunk",),
     "decode_matmul": ("block_k", "block_n"),
+    "decode_attention": ("block_kv",),
 }
 
 # legal range for every size field: powers of two within [MIN, MAX].
@@ -115,6 +121,7 @@ DEFAULT_SCHEDULES: Dict[str, KernelSchedule] = {
     "ssm_scan": KernelSchedule(chunk=128),
     "mlstm_scan": KernelSchedule(chunk=128),
     "decode_matmul": KernelSchedule(block_k=512, block_n=1024),
+    "decode_attention": KernelSchedule(block_kv=128),
 }
 
 
@@ -229,7 +236,9 @@ def effective_schedule(kernel: str, schedule: Optional[KernelSchedule],
     artifact metadata (a requested ``block_q=128`` on a 64-token
     sequence runs as 64; see module docstring).  ``schedule=None`` means
     the kernel default.  For ``decode_matmul`` the two lengths are the
-    weight's contracted width K and its output width N."""
+    weight's contracted width K and its output width N; for
+    ``decode_attention`` ``seq_len`` is the cache's positions, which a
+    block never exceeds."""
     _check_kernel(kernel)
     sched = (schedule or KernelSchedule()).merged_over(default_schedule(kernel))
     if kernel == "flash_attention":
@@ -244,6 +253,8 @@ def effective_schedule(kernel: str, schedule: Optional[KernelSchedule],
             block_k=_clamp_tile(sched.block_k, seq_len),
             block_n=_clamp_tile(sched.block_n,
                                 seq_len if kv_len is None else kv_len))
+    if kernel == "decode_attention":
+        return dataclasses.replace(sched, block_kv=min(sched.block_kv, seq_len))
     return dataclasses.replace(sched, chunk=_clamp_chunk(sched.chunk, seq_len))
 
 

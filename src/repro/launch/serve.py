@@ -48,12 +48,18 @@ numbers that grow for the engine's life (read differences over a
 window): ``queue.waits_s`` (queue wait of each request taken),
 ``steps``, ``slot_steps`` (active slots summed over steps),
 ``valid_positions`` (each active slot's valid cache positions summed
-over steps) and ``capacity_positions`` (``max_batch * max_context``),
+over steps), ``attended_positions`` (the K/V positions each slot's
+decode attention reads, summed over steps: on a TPU, where the decode
+attention kernel runs, the slot's length rounded up to the kernel's
+block, an idle slot's one block; else ``max_context``, the whole layer
+XLA reads) and ``capacity_positions`` (``max_batch * max_context``),
 positions of one K/V cache: every K/V cache of a model holds
 ``max_context`` positions a slot, be it an attention layer's or, in a
 hybrid model, a shared block invocation's.
 ``decode_kernel_calls``, set once when the engine is built, counts the
-distinct kernel calls its decode program runs (0 off a TPU);
+distinct kernel calls its decode program runs (0 off a TPU): the
+``decode_matmul`` shapes and the ``decode_attention`` call of each
+segment whose heads are lane-aligned;
 ``decode_cache_donated_bytes``, set with it, the bytes of cache that the
 decode program takes donated and updates in place (on a TPU the whole
 cache, else 0: each step then writes a second one).
@@ -155,17 +161,20 @@ class ServingEngine:
         # one program (eagerly, the indexing alone cost milliseconds)
         self._greedy = jax.jit(lambda logits: (jnp.argmax(logits[:, -1], axis=-1),
                                                jnp.isfinite(logits[:, -1]).all()))
-        self.decode_kernel_calls, self.decode_cache_donated_bytes = self._trace_decode()
+        (self.decode_kernel_calls, self.decode_cache_donated_bytes,
+         self._attention_block) = self._trace_decode()
         # slot i: None, or dict(req=, pos=, token=, out=[generated tokens])
         self.slots: List[Optional[Dict[str, Any]]] = [None] * self.max_batch
         self.completed: List[Dict[str, Any]] = []
         self.iterations = 0
         self.prefills = 0
         # decode steps; active slots and their valid cache positions
-        # (``pos + 1``) summed over steps; positions the cache reserves
+        # (``pos + 1``) summed over steps; the positions decode attention
+        # reads, summed over steps; positions the cache reserves
         self.steps = 0
         self.slot_steps = 0
         self.valid_positions = 0
+        self.attended_positions = 0
         self.capacity_positions = self.max_batch * self.max_context
         self._span = jax.profiler.TraceAnnotation
         # prefills / decode steps whose logits held a NaN or inf: a
@@ -180,11 +189,14 @@ class ServingEngine:
     def _trace_decode(self):
         """One abstract trace of the decode program, no compile (the
         call reuses it): (distinct Pallas kernel calls in it, by shape,
-        bytes of the arguments it takes donated).  ``LM.decode`` on a TPU
-        multiplies each stack segment's f32 projections by
-        ``decode_matmul``; the calls count 0 where every projection takes
-        the XLA path, as on any other platform (where the kernel's branch
-        is traced but not lowered)."""
+        bytes of the arguments it takes donated, the block of positions
+        the decode attention kernel reads or None where it does not run).
+        ``LM.decode`` on a TPU multiplies each stack segment's f32
+        projections by ``decode_matmul`` and attends over lane-aligned
+        heads with ``decode_attention``; the calls count 0 where every
+        projection and attention takes the XLA path, as on any other
+        platform (where the kernels' branches are traced but not
+        lowered)."""
         from repro.kernels import schedule as ksched
 
         jax, jnp = self.jax, self.jnp
@@ -194,7 +206,20 @@ class ServingEngine:
             traced = self.decode.trace(self.params, self.cache, tokens, pos)
         donated = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                       for a in jax.tree_util.tree_leaves(traced.args_info) if a.donated)
-        return (len(calls) if self._platform() == "tpu" else 0), donated
+        if self._platform() != "tpu":
+            return 0, donated, None
+        blocks = {c["effective"].block_kv for c in calls.values()
+                  if c["kernel"] == "decode_attention"}
+        return len(calls), donated, max(blocks, default=None)
+
+    def _attended(self, pos) -> int:
+        """The K/V positions decode attention reads in a step at slot
+        positions ``pos`` (0 for an idle slot): each slot's length rounded
+        up to the kernel's block where it runs, else the whole layer."""
+        if self._attention_block is None:
+            return self.max_batch * self.max_context
+        block = self._attention_block
+        return int(np.minimum(-(-(pos + 1) // block) * block, self.max_context).sum())
 
     def _pick(self, logits):
         """Greedy token ids at each row's last position of ``logits``
@@ -274,6 +299,7 @@ class ServingEngine:
                 self.steps += 1
                 self.slot_steps += len(active)
                 self.valid_positions += int(pos[active].sum()) + len(active)
+                self.attended_positions += self._attended(pos)
                 for i in active:
                     s = self.slots[i]
                     s["pos"] += 1

@@ -22,9 +22,10 @@ from typing import Any, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.api import current_mesh
 from repro.kernels.schedule import LANES
 from repro.nn import initializers as init
-from repro.nn.linear import dense
+from repro.nn.linear import bf16_passes, dense
 from repro.nn.rope import apply_rope
 from repro.nn.types import P
 
@@ -393,6 +394,48 @@ def _write_rows(leaf, new, pos):
     return leaf
 
 
+def _attend_rows(q, kv, pos, cfg: AttentionConfig):
+    """q (B,1,H,Dh) over the positions ``<= pos`` (within the sliding
+    window when configured) of one layer's K/V (B,T,K,Dh): every
+    position is read and the rest masked off."""
+    positions = pos[:, None]
+    kj = jnp.arange(kv["k"].shape[1])
+    valid = kj[None, :] <= positions
+    if cfg.window is not None:
+        valid &= kj[None, :] > positions - cfg.window
+    mask = valid[:, None, None, None, :]  # (B,1,1,1,T)
+    return grouped_attention(q, kv["k"].astype(q.dtype), kv["v"].astype(q.dtype), mask,
+                             cfg.scale)
+
+
+def _attend_stack(q, cache: LayerCache, pos, cfg: AttentionConfig):
+    """q (B,1,H,Dh) over layer ``cache.layer`` of a stacked K/V cache
+    whose heads are lane-aligned.  On a TPU the decode attention kernel
+    reads each sequence's K/V only up to its position, in blocks, from
+    the stack in place.  XLA slices the whole layer out and masks it
+    elsewhere, and wherever the kernel would change the answer or its
+    partitioning: a sliding window (the kernel starts every sequence at
+    position 0), a precision other than one bf16 pass (the kernel's), or
+    a mesh that shards the cache (a Pallas call does not partition)."""
+    def xla(q, stack, layer, pos):
+        return _attend_rows(q, LayerCache(stack, layer).read(), pos, cfg)
+
+    if cfg.window is not None or bf16_passes() != 1 or current_mesh() is not None:
+        return xla(q, cache.stack, cache.layer, pos)
+
+    # a fresh closure on purpose, as in ``nn.linear.dense``: the recorder
+    # sees the kernel call on every trace
+    def kernel(q, stack, layer, pos):
+        from repro.kernels import ops as kops
+
+        out = kops.decode_attention(q[:, 0], stack["k"], stack["v"], layer, pos + 1,
+                                    scale=cfg.scale, platform="tpu")
+        return out[:, None].astype(q.dtype)
+
+    return jax.lax.platform_dependent(q, cache.stack, cache.layer, pos, tpu=kernel,
+                                      default=xla)
+
+
 def attention_decode(params, cfg: AttentionConfig, x, cache: LayerCache, pos):
     """One-token decode.  x: (B,1,d_model); pos: an int32 vector (B,) of
     *per-sequence* positions (continuous batching: each serving slot
@@ -404,8 +447,10 @@ def attention_decode(params, cfg: AttentionConfig, x, cache: LayerCache, pos):
     configured) in the updated cache, and returns (y, the stack).  A TPU
     lays out a cache whose heads are lane-aligned with the head dim
     minor: the rows are scattered straight into layer ``cache.layer`` of
-    the stack.  One whose heads are not (zamba2's 160) it lays out with
-    the positions minor, and in a layer loop XLA would copy such a stack
+    the stack, and attention reads the stack after them
+    (:func:`_attend_stack`: on a TPU, each sequence's valid blocks
+    only).  One whose heads are not (zamba2's 160) it lays out with the
+    positions minor, and in a layer loop XLA would copy such a stack
     whole to scatter into it: the rows go into the layer's own cache as
     slices (:func:`_write_rows`), which goes back into the stack
     (:meth:`LayerCache.update`; the slice of a stack of one layer is the
@@ -417,24 +462,17 @@ def attention_decode(params, cfg: AttentionConfig, x, cache: LayerCache, pos):
     q, k_new, v_new = _project_qkv(params, cfg, x, x, positions, positions)
     new = {"k": k_new, "v": v_new}
 
-    def attend(kv):
-        kj = jnp.arange(kv["k"].shape[1])
-        valid = kj[None, :] <= positions
-        if cfg.window is not None:
-            valid &= kj[None, :] > positions - cfg.window
-        mask = valid[:, None, None, None, :]  # (B,1,1,1,T)
-        out = grouped_attention(q, kv["k"].astype(q.dtype), kv["v"].astype(q.dtype), mask,
-                                cfg.scale)
+    def project(out):
         return dense(out.reshape(b, 1, cfg.n_heads * cfg.head_dim), params["wo"])
 
     if cfg.head_dim % LANES == 0:
         rows = (cache.layer, jnp.arange(b), pos)
         stack = {n: cache.stack[n].at[rows].set(new[n][:, 0].astype(cache.stack[n].dtype))
                  for n in new}
-        return attend(LayerCache(stack, cache.layer).read()), stack
+        return project(_attend_stack(q, LayerCache(stack, cache.layer), pos, cfg)), stack
 
     def write(own):
         kv = {n: _write_rows(own[n], new[n], pos) for n in new}
-        return attend(kv), kv
+        return project(_attend_rows(q, kv, pos, cfg)), kv
 
     return cache.update(write)

@@ -35,6 +35,13 @@ _PASSES = {None: 1, "default": 1, "fastest": 1, "bfloat16": 1,
            "high": 3, "bfloat16_3x": 3, "tensorfloat32": 3}
 
 
+def bf16_passes():
+    """The bf16 passes the default matmul precision makes of f32
+    operands, or None where it is one a kernel does not give
+    (``highest``)."""
+    return _PASSES.get(jax.config.jax_default_matmul_precision)
+
+
 class LayerWeight(NamedTuple):
     """Layer ``layer`` of a stacked ``(L, K, N)`` weight, not sliced."""
 
@@ -60,7 +67,7 @@ def streams(w) -> bool:
     product)."""
     return (getattr(w, "dtype", None) == jnp.float32 and w.ndim == 3
             and w.shape[1] % LANES == 0 and kernel_columns(w.shape[2]) > 0
-            and jax.config.jax_default_matmul_precision in _PASSES)
+            and bf16_passes() is not None)
 
 
 def _matrix(w):
@@ -87,7 +94,7 @@ def dense(x, w):
         b, s, k = x.shape
         width = w.stack.shape[2]
         n = kernel_columns(width)
-        passes = _PASSES[jax.config.jax_default_matmul_precision]
+        passes = bf16_passes()
         if n == width:
             y = kops.decode_matmul(x.reshape(b * s, k), w.stack, w.layer, passes=passes,
                                    platform="tpu")

@@ -1,13 +1,16 @@
 """Which decode projections read one layer of a stacked f32 weight
-through the ``decode_matmul`` kernel, and that nothing else changes.
+through the ``decode_matmul`` kernel, which attention reads its stacked
+K/V through the ``decode_attention`` kernel, and that nothing else
+changes.
 
 ``LM.decode`` hands the layer scan's stacked f32 attention and MLP
-projections to ``nn.linear.dense`` as ``LayerWeight``s.  The kernel is
+projections to ``nn.linear.dense`` as ``LayerWeight``s, and attention
+over lane-aligned heads reads the stacked cache itself.  Each kernel is
 one branch of ``lax.platform_dependent``: it is traced on every
 platform (so the recorder sees it here too) and lowered on a TPU
 only; on the CPU the layer is sliced and multiplied as before, which
-these tests hold to the bit.  One test lowers the TPU branch on the
-CPU, with the kernel in the Pallas interpreter.
+these tests hold to the bit.  Some tests lower the TPU branches on the
+CPU, with the kernels in the Pallas interpreter.
 """
 
 import dataclasses
@@ -54,7 +57,11 @@ def _calls(fn, args):
 
 
 def _weights(calls):
-    return sorted(c["shapes"]["w"] for c in calls.values())
+    return sorted(c["shapes"]["w"] for c in calls.values() if c["kernel"] == "decode_matmul")
+
+
+def _attention_calls(calls):
+    return [c for c in calls.values() if c["kernel"] == "decode_attention"]
 
 
 @pytest.fixture
@@ -112,13 +119,15 @@ def test_kernel_engages_for_stacked_f32_decode_only():
     params = _params(ALIGNED)
     args = _decode_args(model, params)
     calls = _calls(model.decode, args)
-    assert {c["kernel"] for c in calls.values()} == {"decode_matmul"}
+    assert {c["kernel"] for c in calls.values()} == {"decode_matmul", "decode_attention"}
     # q; k and v; o; gate and up; down
     assert _weights(calls) == [(3, 128, 128), (3, 128, 256), (3, 128, 384),
                                (3, 256, 128), (3, 384, 128)]
 
+    # bf16 weights stay on XLA's dots; attention still reads the f32 cache
     bf16 = _params(ALIGNED, jnp.bfloat16)
-    assert not _calls(model.decode, _decode_args(model, bf16))
+    calls = _calls(model.decode, _decode_args(model, bf16))
+    assert {c["kernel"] for c in calls.values()} == {"decode_attention"}
     tokens = jnp.ones((1, 8), jnp.int32)
     cache = model.init_cache(params, 1, 32, dtype=jnp.float32)
     assert not _calls(model.prefill, (params, cache, tokens))
@@ -183,12 +192,16 @@ def test_qwen3_decode_traces_four_kernel_calls_at_published_widths():
     # q and o; k and v; gate and up; down
     assert _weights(calls) == [(28, 2048, 1024), (28, 2048, 2048),
                                (28, 2048, 6144), (28, 6144, 2048)]
+    # and one attention call over the whole stack, in blocks
+    (attend,) = _attention_calls(calls)
+    assert attend["shapes"] == {"q": (8, 16, 128), "k": (28, 8, 1281, 8, 128)}
+    assert attend["effective"].block_kv == ksched.default_schedule("decode_attention").block_kv
 
 
 @pytest.mark.parametrize("spec,dtype,platform,want", [
-    (ALIGNED, jnp.float32, "tpu", 5),
+    (ALIGNED, jnp.float32, "tpu", 6),  # five decode_matmul shapes, one decode_attention
     (ALIGNED, jnp.float32, "cpu", 0),  # traced, not lowered
-    (ALIGNED, jnp.bfloat16, "tpu", 0),
+    (ALIGNED, jnp.bfloat16, "tpu", 1),  # decode_attention over the f32 cache
     (get_arch("qwen3-1.7b").smoke_spec_fn(), jnp.float32, "tpu", 0),  # under a lane
 ], ids=["aligned-f32-tpu", "aligned-f32-cpu", "aligned-bf16", "qwen3-smoke"])
 def test_engine_records_decode_kernel_calls(spec, dtype, platform, want, monkeypatch):
@@ -204,6 +217,14 @@ def test_engine_platform_is_its_cache_devices():
     assert engine._platform() == jax.default_backend()
 
 
+def _tpu_branches(monkeypatch):
+    """Lower every ``lax.platform_dependent`` branch as on a TPU, with the
+    kernels in the Pallas interpreter."""
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *operands, tpu, default: tpu(*operands))
+    monkeypatch.setattr(kops, "_interpret", lambda requested, platform=None: True)
+
+
 @pytest.mark.parametrize("pos", [(0, 3, 6, 9), (31, 0, 17, 5)], ids=["early", "late"])
 def test_tpu_branch_matches_xla_path_within_bf16_operands(pos, monkeypatch):
     """The TPU branch of ``dense`` (the kernel, the layer index, the row
@@ -215,13 +236,11 @@ def test_tpu_branch_matches_xla_path_within_bf16_operands(pos, monkeypatch):
     args = (params, cache, tokens, jnp.asarray(pos, jnp.int32))
     want_logits, want_cache = jax.jit(model.decode)(*args)
 
-    monkeypatch.setattr(jax.lax, "platform_dependent",
-                        lambda *operands, tpu, default: tpu(*operands))
-    monkeypatch.setattr(kops, "_interpret", lambda requested, platform=None: True)
+    _tpu_branches(monkeypatch)
     with ksched.record_kernel_calls({}) as sink:
         logits, cache = jax.jit(model.decode)(*args)
     assert {c["effective"].interpret for c in sink.values()} == {True}
-    assert len(sink) == 5
+    assert len(sink) == 6
 
     def close(got, want):
         got, want = np.asarray(got), np.asarray(want)
@@ -244,9 +263,7 @@ def test_tpu_branch_matches_xla_path_for_a_hybrid_model(monkeypatch):
     args = (params, cache, tokens, jnp.asarray((0, 3, 9, 5), jnp.int32))
     want_logits, want_cache = jax.jit(model.decode)(*args)
 
-    monkeypatch.setattr(jax.lax, "platform_dependent",
-                        lambda *operands, tpu, default: tpu(*operands))
-    monkeypatch.setattr(kops, "_interpret", lambda requested, platform=None: True)
+    _tpu_branches(monkeypatch)
     with ksched.record_kernel_calls({}) as sink:
         logits, cache = jax.jit(model.decode)(*args)
     assert {c["effective"].interpret for c in sink.values()} == {True}
@@ -257,6 +274,48 @@ def test_tpu_branch_matches_xla_path_for_a_hybrid_model(monkeypatch):
         np.testing.assert_allclose(got, want, rtol=0, atol=0.025 * np.abs(want).max())
 
     close(logits, want_logits)
+    for got, want in zip(jax.tree_util.tree_leaves(cache),
+                         jax.tree_util.tree_leaves(want_cache)):
+        close(got, want)
+
+
+def test_tpu_attention_branch_matches_xla_path_over_decode_steps(monkeypatch):
+    """Six decode steps with the TPU branches lowered on the CPU (the
+    attention kernel reading blocks of 8 positions, the projections
+    through ``decode_matmul``) against the XLA path, from a cache whose
+    every row holds K/V: the slots start at positions 0, 7, 14 and 25 of
+    32, so each step reads a different number of blocks a slot and the
+    slots cross blocks' edges as they go.  Each step's logits and the
+    final cache agree within the rounding of bf16 operands."""
+    model = LM(ALIGNED)
+    params = _params(ALIGNED)
+    empty = model.init_cache(params, 4, 32, dtype=jnp.float32)
+    keys = iter(jax.random.split(jax.random.PRNGKey(5), len(jax.tree_util.tree_leaves(empty))))
+    full = jax.tree_util.tree_map(lambda a: jax.random.normal(next(keys), a.shape), empty)
+    start = jnp.asarray((0, 7, 14, 25), jnp.int32)
+
+    def run():
+        step, cache, logits = jax.jit(model.decode), full, []
+        for i in range(6):
+            tokens = ((jnp.arange(4, dtype=jnp.int32) * 5 + i) % ALIGNED.vocab)[:, None]
+            out, cache = step(params, cache, tokens, start + i)
+            logits.append(out)
+        return jnp.concatenate(logits, axis=1), cache
+
+    want_logits, want_cache = run()
+    _tpu_branches(monkeypatch)
+    with ksched.record_kernel_calls({}) as sink, \
+            ksched.use_schedules({"decode_attention": {"block_kv": 8}}):
+        logits, cache = run()
+    (attend,) = _attention_calls(sink)
+    assert attend["effective"].block_kv == 8 and attend["effective"].interpret
+
+    def close(got, want):
+        got, want = np.asarray(got), np.asarray(want)
+        np.testing.assert_allclose(got, want, rtol=0, atol=0.025 * np.abs(want).max())
+
+    for i in range(6):
+        close(logits[:, i], want_logits[:, i])
     for got, want in zip(jax.tree_util.tree_leaves(cache),
                          jax.tree_util.tree_leaves(want_cache)):
         close(got, want)
@@ -274,7 +333,74 @@ CACHE_SPECS = {
     "whisper-smoke": get_arch("whisper-medium").smoke_spec_fn(),
     "window": ModelSpec(name="window", d_model=64, vocab=256,
                         layers=(transformer_layer(64, 4, 2, 128, window=5),) * 3),
+    "aligned-window": ModelSpec(name="aligned-window", d_model=128, vocab=256,
+                                layers=(transformer_layer(128, 2, 1, 256, window=5,
+                                                          d_head=128),) * 2),
 }
+
+
+@pytest.mark.parametrize("name,context,want", [
+    ("aligned", None, 1),
+    ("qwen3-smoke", None, 0),  # 16-wide heads
+    ("zamba2-smoke", None, 0),  # narrow heads, at the `high` precision
+    ("whisper-smoke", None, 0),
+    ("aligned-window", None, 0),
+    ("aligned", "highest", 0),
+    ("aligned", "mesh", 0),
+])
+def test_attention_kernel_engages_by_shape_and_spec(name, context, want):
+    """Decode reads a stacked cache through the attention kernel, one call
+    a segment, where the heads are lane-aligned; narrow heads, a sliding
+    window, a precision the kernel's one bf16 pass would change and a
+    mesh that shards the cache keep XLA's masked read of the layer."""
+    import contextlib
+
+    from repro.distributed.api import sharding_context
+    from repro.distributed.sharding import default_rules
+
+    model = LM(CACHE_SPECS[name])
+    params = _params(model.spec)
+    args = (params, model.init_cache(params, 2, 16, enc_out=_enc_out(model, params, 2),
+                                     dtype=jnp.float32),
+            jnp.ones((2, 1), jnp.int32), jnp.zeros((2,), jnp.int32))
+    if context == "highest":
+        scope = jax.default_matmul_precision("highest")
+    elif context == "mesh":
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("data",))
+        scope = sharding_context(mesh, default_rules(mesh))
+    else:
+        scope = contextlib.nullcontext()
+    with scope:
+        calls = _attention_calls(_calls(model.decode, args))
+    assert len(calls) == want
+
+
+@pytest.mark.parametrize("name,platform,want", [
+    # a 300-position cache read in blocks of 128: slots at positions 4
+    # and 255, then 5 and 256 (one, two, one and three blocks, the third
+    # holding the cache's last 44 positions), and two idle slots, one
+    # block each
+    ("aligned", "tpu", (128 + 256 + 2 * 128) + (128 + 300 + 2 * 128)),
+    ("aligned", "cpu", 2 * 4 * 300),  # XLA reads the whole layer
+    ("zamba2-smoke", "tpu", 2 * 4 * 300),  # no kernel for its heads
+])
+def test_engine_counts_attended_positions(name, platform, want, monkeypatch):
+    """``attended_positions`` adds, each step, the K/V positions each
+    slot's decode attention reads: its length rounded up to the kernel's
+    block where the kernel runs, else ``max_context``."""
+    from repro.launch.traffic import Request
+
+    monkeypatch.setattr(ServingEngine, "_platform", lambda self: platform)
+    spec = CACHE_SPECS[name]
+    engine = ServingEngine(LM(spec), _params(spec), max_batch=4, queue_limit=4,
+                           max_context=300)
+    assert engine._attention_block == (128 if (name, platform) == ("aligned", "tpu") else None)
+    for i, n in enumerate((4, 255)):
+        engine._join(Request(id=i, arrival_s=0.0, prompt_len=n, gen_len=8, token_seed=i))
+    engine._decode_step()
+    engine._decode_step()
+    assert engine.valid_positions == (5 + 256) + (6 + 257)
+    assert engine.attended_positions == want
 
 
 def _enc_out(model, params, batch):

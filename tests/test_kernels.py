@@ -224,3 +224,56 @@ def test_decode_matmul_follows_a_scanned_layer_index(proj):
         np.testing.assert_allclose(np.asarray(out[layer]),
                                    np.asarray(ref.decode_matmul_ref(x, w, layer)),
                                    atol=1e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# decode attention (one token over one layer of a stacked K/V cache)
+# ---------------------------------------------------------------------------
+
+# 4 slots at positions 0, block - 1, block and T - 1 of a 37-position
+# cache read in blocks of 8: one block, one whole block, one row into the
+# second, and the last block, which runs past the stack
+DECODE_BLOCK, DECODE_T = 8, 37
+DECODE_POS = (0, DECODE_BLOCK - 1, DECODE_BLOCK, DECODE_T - 1)
+
+
+def _poison(stack, layer, lengths):
+    """``stack`` with every layer but ``layer`` NaN, and in ``layer`` each
+    slot's blocks wholly past its last valid one."""
+    start = -(-lengths // DECODE_BLOCK) * DECODE_BLOCK
+    past = jnp.arange(stack.shape[2])[None, :] >= start[:, None]  # (B, T)
+    poisoned = past[None, :, :, None, None] | (jnp.arange(stack.shape[0]) != layer)[
+        :, None, None, None, None]
+    return jnp.where(poisoned, jnp.nan, stack).astype(stack.dtype)
+
+
+@pytest.mark.parametrize("poisoned", [False, True], ids=["clean", "poisoned"])
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("group", [1, 2])
+def test_decode_attention_reads_each_slots_valid_blocks(group, dtype, layer, poisoned):
+    """Ragged per-slot lengths against the masked grouped attention of
+    the same bf16 operands.  Poisoned, the blocks no slot may read hold
+    NaN (so does every other layer): the output is finite and the same to
+    the bit, so the kernel neither fetched nor summed them."""
+    kheads, dh = 2, 128
+    ks = jax.random.split(KEY, 3)
+    q = _rand(ks[0], (len(DECODE_POS), kheads * group, dh), jnp.float32)
+    k = _rand(ks[1], (3, len(DECODE_POS), DECODE_T, kheads, dh), dtype)
+    v = _rand(ks[2], (3, len(DECODE_POS), DECODE_T, kheads, dh), dtype)
+    lengths = jnp.asarray(DECODE_POS, jnp.int32) + 1
+    schedule = {"block_kv": DECODE_BLOCK}
+
+    def run(k, v):
+        return ops.decode_attention(q, k, v, jnp.int32(layer), lengths, scale=dh ** -0.5,
+                                    schedule=schedule)
+
+    out = run(k, v)
+    want = ref.decode_attention_ref(q, k.astype(jnp.float32), v.astype(jnp.float32), layer,
+                                    lengths, scale=dh ** -0.5)
+    assert out.shape == q.shape and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=5e-3, rtol=0)
+    if poisoned:
+        bad = run(_poison(k, layer, lengths), _poison(v, layer, lengths))
+        assert np.isfinite(np.asarray(bad)).all()
+        np.testing.assert_array_equal(np.asarray(bad), np.asarray(out))

@@ -85,12 +85,22 @@ def _decode_matmul_inputs():
     return _rand(13, (8, 256)), _rand(14, (2, 256, 384)), jnp.int32(1)
 
 
+def _decode_attention_inputs():
+    # 4 slots of a 2-layer stack of 40 positions, 2 K/V heads of 128
+    return (_rand(15, (4, 4, 128)), _rand(16, (2, 4, 40, 2, 128)),
+            _rand(17, (2, 4, 40, 2, 128)), jnp.int32(1),
+            jnp.asarray((1, 16, 17, 40), jnp.int32))
+
+
 def _call(kernel, schedule=None, **kwargs):
     """Run one schedulable op on the shared inputs; returns the primary
     output array."""
     if kernel == "decode_matmul":
         return ops.decode_matmul(*_decode_matmul_inputs(), schedule=schedule,
                                  **kwargs)
+    if kernel == "decode_attention":
+        return ops.decode_attention(*_decode_attention_inputs(), scale=128 ** -0.5,
+                                    schedule=schedule, **kwargs)
     if kernel == "flash_attention":
         return ops.flash_attention(*_flash_inputs(), causal=True,
                                    schedule=schedule, **kwargs)
@@ -105,6 +115,8 @@ def _call(kernel, schedule=None, **kwargs):
 def _oracle(kernel):
     if kernel == "decode_matmul":
         return ref.decode_matmul_ref(*_decode_matmul_inputs())
+    if kernel == "decode_attention":
+        return ref.decode_attention_ref(*_decode_attention_inputs(), scale=128 ** -0.5)
     if kernel == "flash_attention":
         return _flash_ref(*_flash_inputs())
     if kernel == "ssm_scan":
@@ -139,8 +151,8 @@ def test_default_schedule_is_bit_identical_to_legacy_path(kernel):
     plain = _call(kernel)  # no schedule anywhere -> named default
     explicit = _call(kernel, schedule=default_schedule(kernel))
     assert np.array_equal(np.asarray(plain), np.asarray(explicit))
-    if kernel == "decode_matmul":
-        return  # came after schedules: it has no legacy kwargs
+    if kernel in ("decode_matmul", "decode_attention"):
+        return  # came after schedules: they have no legacy kwargs
     if kernel == "flash_attention":
         legacy = _call(kernel, block_q=128, block_kv=128)
     else:
@@ -233,6 +245,20 @@ def test_effective_decode_tiles_divide_and_stay_lane_aligned():
     eff = effective_schedule("decode_matmul", KernelSchedule(block_k=1024, block_n=32),
                              seq_len=384, kv_len=640)
     assert (eff.block_k, eff.block_n) == (384, 128)  # the whole K; lifted to a lane
+
+
+def test_effective_decode_attention_block_fits_the_cache():
+    eff = effective_schedule("decode_attention", None, seq_len=1281)
+    assert eff.block_kv == default_schedule("decode_attention").block_kv
+    eff = effective_schedule("decode_attention", KernelSchedule(block_kv=512), seq_len=40)
+    assert eff.block_kv == 40  # the whole cache, one block
+
+
+@pytest.mark.parametrize("block_kv", [8, 16, 32, 64])
+def test_decode_attention_schedules_match_reference(block_kv):
+    out = _call("decode_attention", schedule=KernelSchedule(block_kv=block_kv))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_oracle("decode_attention")),
+                               atol=5e-3, rtol=0)
 
 
 @pytest.mark.parametrize("block_k,block_n", [(128, 128), (256, 128), (1024, 1024)])

@@ -49,6 +49,11 @@ CASES = [
     # three bf16 passes
     ("decode", dict(m=8, k=2560, n=10240, passes=3), F32),
     ("decode", dict(m=8, k=2560, n=10448, cols=10240, passes=3), F32),
+    # qwen3-1.7b's decode attention: 8 slots, 16 query heads over 8 K/V
+    # heads of 128, its 28 stacked layers of 1281 positions, whose last
+    # block runs past the stack
+    ("attend", dict(b=8, h=16, kh=8, d=128, t=1281), F32),
+    ("attend", dict(b=8, h=16, kh=8, d=128, t=1281), BF16),
 ]
 
 
@@ -102,6 +107,13 @@ def _lower(kernel, w, dtype, chip):
             n=w.get("cols"), k_minor=k_minor, passes=w.get("passes", 1)))
         stack = (28, w["n"], w["k"]) if k_minor else (28, w["k"], w["n"])
         return fn.lower(arg((w["m"], w["k"])), arg(stack), arg((), jnp.int32))
+    if kernel == "attend":
+        block = ksched.default_schedule("decode_attention").block_kv
+        fn = jax.jit(lambda q, k, v, layer, lengths: ops.decode_attention_stacked(
+            q, k, v, layer, lengths, scale=w["d"] ** -0.5, block_t=block))
+        stack = arg((28, w["b"], w["t"], w["kh"], w["d"]))
+        return fn.lower(arg((w["b"], w["h"], w["d"]), F32), stack, stack,
+                        arg((), jnp.int32), arg((w["b"],), jnp.int32))
     if kernel == "ssm":
         b, l, h, p, n, g = w["b"], w["l"], w["h"], w["p"], w["n"], w["g"]
         fn = jax.jit(lambda *a: ops._ssm_scan_impl(
@@ -137,7 +149,8 @@ def test_qwen3_decode_reads_f32_stacks_without_whole_stack_converts(
     compiles it, the cache donated: no weight stack is rounded to bf16
     outside the layer loop, and the temp bytes that rounding needed are
     gone; the cache is updated in place (aliased whole, and no K/V stack
-    copied)."""
+    copied); attention reads the stack through its kernel, with no layer's
+    whole K/V sliced or copied out of it."""
     import re
 
     from repro.configs import get_arch
@@ -156,12 +169,13 @@ def test_qwen3_decode_reads_f32_stacks_without_whole_stack_converts(
         jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)).compile()
     text = compiled.as_text()
     assert not re.findall(r"= bf16\[28,[^\]]*\]\S* convert\(", text)
-    assert text.count("tpu_custom_call") >= 7
+    assert text.count("tpu_custom_call") >= 8
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes <= HOISTED_TEMP_BYTES - 2_500_000_000, memory
     assert memory.alias_size_in_bytes == sum(
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(cache))
     assert not re.findall(r"= f32\[28,8,1281,8,128\]\S* copy\(", text)
+    assert not re.findall(r"= \w+\[(1,)?8,1281,8,128\]\S* (dynamic-slice|copy|fusion)\(", text)
 
 
 def test_zamba2_decode_fits_one_chip_without_whole_stack_converts(
